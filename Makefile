@@ -19,7 +19,7 @@ TIER1 = set -o pipefail; rm -f /tmp/_t1.log; \
 	ingest-smoke faults-smoke trace-smoke cache-smoke multichip-smoke \
 	continual-smoke costmodel-smoke roofline-smoke slo-smoke \
 	parse-smoke router-smoke pod-smoke autopilot-smoke fleetobs-smoke \
-	test check
+	chip-rehearsal test check
 
 lint:
 	$(PY) -m transmogrifai_tpu.lint transmogrifai_tpu/
@@ -77,8 +77,9 @@ fleet-smoke:
 # DISPATCHES-asserted), int8 scoring agrees with f32 within the stated
 # wire tolerance and never adopts the f32 programs, two same-shaped
 # linear tenants share one compiled program set (zero traces on the
-# second, bit-identical vs solo), and scoring_hbm_frac is present and
-# nonzero. See transmogrifai_tpu/serving/roofline_smoke.py.
+# second, bit-identical vs solo), and scoring_bytes_per_sec is present
+# and nonzero (scoring_hbm_frac only off the CPU). See
+# transmogrifai_tpu/serving/roofline_smoke.py.
 roofline-smoke:
 	env JAX_PLATFORMS=cpu $(PY) -m transmogrifai_tpu.serving.roofline_smoke
 
@@ -201,10 +202,19 @@ router-smoke:
 fleetobs-smoke:
 	env JAX_PLATFORMS=cpu $(PY) -m transmogrifai_tpu.serving.fleetobs_smoke
 
+# CPU rehearsal of the chip check: the exact chip_smoke.py command at a
+# tiny size (train -> fused score -> score_stream -> cli serve f32 +
+# int8-calibrated as separate processes -> 4-device host-mesh train),
+# so the command is debugged here and chip time is spent on the real
+# size only. Without the flag and without a TPU chip_smoke.py exits
+# non-zero (tests/test_bringup.py). See chip_smoke.py.
+chip-rehearsal:
+	$(PY) chip_smoke.py --cpu-rehearsal
+
 test:
 	@$(TIER1)
 
 check: lint conc-check serve-smoke parse-smoke fleet-smoke chaos-smoke \
 	autopilot-smoke roofline-smoke ingest-smoke cache-smoke faults-smoke \
 	trace-smoke slo-smoke multichip-smoke pod-smoke continual-smoke \
-	costmodel-smoke router-smoke fleetobs-smoke test
+	costmodel-smoke router-smoke fleetobs-smoke chip-rehearsal test

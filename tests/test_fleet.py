@@ -428,7 +428,7 @@ def test_cold_warmup_writes_manifest_and_warm_start_claims_savings(
     # compilation-cache config from a unit test would leak into every
     # later compile in the suite
     monkeypatch.setattr(cc, "enable_compile_cache",
-                        lambda path=None, min_compile_s=0.5:
+                        lambda min_compile_s=0.5:
                         str(tmp_path / "cache"))
     svc = ScoringService.from_path(
         model_dirs["m3"], config=ServingConfig(max_batch=4))
@@ -451,7 +451,7 @@ def test_manifest_ladder_mismatch_reads_as_cold(model_dirs, monkeypatch,
                                                 tmp_path):
     import transmogrifai_tpu.utils.compile_cache as cc
     monkeypatch.setattr(cc, "enable_compile_cache",
-                        lambda path=None, min_compile_s=0.5:
+                        lambda min_compile_s=0.5:
                         str(tmp_path / "cache"))
     save_warmup_manifest(model_dirs["m3"], {
         "fingerprint": "not-the-fingerprint", "ladder": [1, 2, 4],
@@ -476,7 +476,7 @@ def test_adoption_warmed_member_claims_no_compile_cache_savings(
     recovery belongs to program sharing, not the persistent cache."""
     import transmogrifai_tpu.utils.compile_cache as cc
     monkeypatch.setattr(cc, "enable_compile_cache",
-                        lambda path=None, min_compile_s=0.5:
+                        lambda min_compile_s=0.5:
                         str(tmp_path / "cache"))
     # give m2 a plausible manifest matching its fingerprint + ladder
     from transmogrifai_tpu.workflow.serialization import model_fingerprint
@@ -632,13 +632,12 @@ def test_serving_params_fleet_and_compile_cache_roundtrip():
     from transmogrifai_tpu.workflow.params import ServingParams
     sp = ServingParams.from_json({
         "max_batch": 16, "compile_cache": True,
-        "compile_cache_dir": "/tmp/x", "warmup_manifest": False,
+        "warmup_manifest": False,
         "fleet": {"models": {"a": "dir_a"},
                   "tenants": {"t": {"rate": 5, "priority": 1}}}})
     assert sp.to_json()["compile_cache"] is True
     cfg = sp.to_config()
     assert cfg.compile_cache is True
-    assert cfg.compile_cache_dir == "/tmp/x"
     assert cfg.warmup_manifest is False
     fc = sp.to_fleet_config()
     assert isinstance(fc, FleetConfig)

@@ -428,8 +428,8 @@ def test_sharded_journal_glob_metachar_dir(tmp_path):
 
 
 def test_scheduled_block_retries_transient_error(cols):
-    """A transient error INSIDE a scheduled block (the remote-compile
-    RPC drop class) retries via the selector's RetryPolicy instead of
+    """An error flagged `transient=True` INSIDE a scheduled block
+    retries via the selector's RetryPolicy instead of
     dropping the family — distribution must not be less fault-tolerant
     than the single-device path."""
     _need_devices(8)

@@ -51,11 +51,13 @@ class TestConfig:
         monkeypatch.setenv("TRANSMOGRIFAI_STORE_DIR", str(tmp_path))
         assert resolve_dir("perf", explicit="/mine") == "/mine"
 
-    def test_default_is_home_cache(self, monkeypatch):
+    def test_default_is_inside_the_checkout(self, monkeypatch):
+        # never $HOME: learned state must not pass between two
+        # checkouts on one machine
         monkeypatch.delenv("TRANSMOGRIFAI_STORE_DIR", raising=False)
         assert not store_configured()
-        assert cache_root() == os.path.expanduser(
-            "~/.cache/transmogrifai_tpu")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cache_root() == os.path.join(repo, ".transmogrifai_store")
 
     def test_consumers_follow_store_root(self, tmp_path, monkeypatch):
         monkeypatch.setenv("TRANSMOGRIFAI_STORE_DIR", str(tmp_path))

@@ -40,8 +40,7 @@ silent slowness or nondeterminism once XLA is in the loop:
   ``jax.device_put`` inside a Python ``for`` loop that iterates a chunk
   stream (an ``iter_chunks(...)``/``stream(...)`` call, or a plain
   ``chunks``/``batches`` iterable). One synchronous host→device
-  transfer per loop body serializes host prep against the wire — the
-  r5 bench burned 63% of its big-mode budget in exactly this pattern —
+  transfer per loop body serializes host prep against the wire,
   and an un-depth-bounded ``device_put`` loop also lets dispatch run
   arbitrarily far ahead of real transfer, breaking deadline math.
   Route bulk uploads through ``data/pipeline.run_chunk_pipeline``
@@ -71,8 +70,8 @@ silent slowness or nondeterminism once XLA is in the loop:
   (``device_matrix`` / ``device_binned`` / ``dual_device_matrices``)
   on the SAME store variable inside one function scope with none of
   them carrying a ``cache=`` policy. Each uncached call re-streams the
-  whole store host→device — at 10M×500 that is ~635 s per repeat
-  (BENCH_r05) — while the content-addressed feature cache
+  whole store host→device — at 10M×500 a ~10 GB wire per repeat —
+  while the content-addressed feature cache
   (`data/feature_cache.py`) replays the wire artifact with zero store
   reads. Pass ``cache=`` (a policy string or `FeatureCacheParams`) so
   the rebuild is a deliberate choice, not an accident.
@@ -141,8 +140,7 @@ silent slowness or nondeterminism once XLA is in the loop:
   The converted array is a closure constant of the compiled scoring
   program: megabyte-scale fitted state gets value-baked into the XLA
   executable (every fleet tenant then compiles its own bucket programs
-  instead of sharing one) and re-staged host→device on every dispatch
-  through the serving tunnel. Route fitted arrays through
+  instead of sharing one). Route fitted arrays through
   ``device_constants()``/``device_apply_with`` — the known-small
   scalar/index sites are allowlisted in ``_L016_ALLOW``.
 
@@ -1317,8 +1315,7 @@ def _check_closure_constants(tree: ast.AST, path: str) -> List[LintFinding]:
     `device_constants()`: the converted array is a closure constant of
     the compiled scoring program — megabyte-scale fitted state gets
     value-baked into the XLA executable (every tenant compiles its own
-    program, serving/fleet.py) and re-staged host→device per dispatch
-    through the serving tunnel. Route big fitted arrays through
+    program, serving/fleet.py). Route big fitted arrays through
     `device_constants()`/`device_apply_with` so they flow as traced jit
     arguments instead."""
     parts = os.path.normpath(path).split(os.sep)

@@ -179,8 +179,14 @@ def instrumented_jit(fn: Callable, label: Optional[str] = None,
         import time as _time
 
         from transmogrifai_tpu.obs import trace as _obs_trace
+        from transmogrifai_tpu.obs.metrics import get_registry
 
         n = mon.record(lbl, inst)
+        # process-wide series on the `/metrics` scrape surface: a serving
+        # process whose count moves after ladder warmup is recompiling
+        get_registry().counter(
+            "runtime_jit_traces_total",
+            "instrumented-jit traces (each one is an XLA compile)").inc()
         t0 = _time.perf_counter()
         try:
             return fn(*args, **kwargs)

@@ -537,11 +537,11 @@ import numpy as np  # noqa: E402  (used by cmd_gen)
 
 
 def cmd_warmup(args) -> int:
-    """Pre-warm the persistent compile cache (VERDICT r3 #9: a cold full
-    train pays minutes of remote-AOT compiles, dominated by the sweep
-    programs). Runs the DEFAULT selector sweep once on synthetic data of
-    the target shape so those programs land in
-    `~/.cache/transmogrifai_tpu/xla-cache`. The winner's refit and the
+    """Pre-warm the persistent compile cache (a cold full train pays
+    minutes of XLA compiles, dominated by the sweep programs). Runs the
+    DEFAULT selector sweep once on synthetic data of the target shape so
+    those programs land in the cache directory
+    (utils/compile_cache.py). The winner's refit and the
     fused scorer still compile on the first real train (their shapes
     depend on the winning config and the real pipeline), and a real
     train's sweep runs on the post-splitter row count — pass `--rows`
@@ -627,8 +627,6 @@ def cmd_serve(args) -> int:
         sp.compile_cache = args.compile_cache == "on"
     elif sp.compile_cache is None:
         sp.compile_cache = True
-    if args.compile_cache_dir:
-        sp.compile_cache_dir = args.compile_cache_dir
     if args.resilience:
         sp.resilience = {**(sp.resilience or {}),
                          "enabled": args.resilience == "on"}
@@ -668,8 +666,6 @@ def cmd_serve(args) -> int:
         fleet_cfg = FleetConfig.load(args.fleet_config)
         if fleet_cfg.compile_cache is None:
             fleet_cfg.compile_cache = sp.compile_cache
-        if fleet_cfg.compile_cache_dir is None:
-            fleet_cfg.compile_cache_dir = sp.compile_cache_dir
     elif sp.fleet:
         fleet_cfg = sp.to_fleet_config()
 
@@ -760,7 +756,7 @@ def main(argv: Optional[list] = None) -> int:
     run_p.add_argument(
         "--feature-cache-dir",
         help="artifact directory for --feature-cache (default "
-             "~/.cache/transmogrifai_tpu/feature_cache); implies "
+             "<store root>/feature_cache); implies "
              "readwrite when --feature-cache is not given")
     run_p.add_argument(
         "--perf-model", choices=["on", "off"],
@@ -773,7 +769,7 @@ def main(argv: Optional[list] = None) -> int:
         "--perf-corpus-dir",
         help="profile-corpus directory for --perf-model (default "
              "TRANSMOGRIFAI_PERF_CORPUS_DIR or "
-             "~/.cache/transmogrifai_tpu/perf)")
+             "<store root>/perf)")
     run_p.add_argument(
         "--perf-model-path",
         help="fitted cost-model JSON (perf.model.CostModel.save) to "
@@ -857,12 +853,10 @@ def main(argv: Optional[list] = None) -> int:
         "--compile-cache", choices=["on", "off"],
         help="persistent XLA compilation cache at startup (default on "
              "for this command): a replica or same-shaped swap warms "
-             "on cache hits instead of recompiling the bucket ladder")
-    serve_p.add_argument(
-        "--compile-cache-dir",
-        help="cache directory for --compile-cache (default "
-             "TRANSMOGRIFAI_TPU_CACHE or "
-             "~/.cache/transmogrifai_tpu/xla-cache)")
+             "on cache hits instead of recompiling the bucket ladder. "
+             "JAX_COMPILATION_CACHE_DIR places the directory; unset, "
+             "it is <store root>/xla-cache (default store root: "
+             "<checkout>/.transmogrifai_store)")
     serve_p.add_argument(
         "--resilience", choices=["on", "off"],
         help="serving resilience layer (health state machine, circuit "
@@ -896,7 +890,7 @@ def main(argv: Optional[list] = None) -> int:
         "--flight-dir",
         help="crash-flight-recorder dump directory (default "
              "TRANSMOGRIFAI_FLIGHT_DIR or "
-             "~/.cache/transmogrifai_tpu/flight)")
+             "<store root>/flight)")
     serve_p.set_defaults(fn=cmd_serve)
 
     lint_p = sub.add_parser(

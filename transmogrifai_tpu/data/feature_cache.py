@@ -1,8 +1,8 @@
 """Persistent, content-addressed cache for built device feature matrices.
 
 The upload wall: re-streaming a 10M×500 ColumnarStore from host memmaps
-to the device dominates big-mode wall time (`big_bin_upload_s` was 634.9s
-of a 1006.3s run in BENCH_r05 even with the PR-3 overlapped pipeline),
+to the device is a full pass over a ~10 GB wire (its share of big-mode
+wall time: not measured on a directly attached chip — PERF.md §7),
 and EVERY repeat sweep, resumed run, and serving warmup pays the whole
 transfer again. tf.data (arxiv 2101.12127) names the standard fix —
 reusable cached materializations of the input pipeline — and the goodput

@@ -3,9 +3,8 @@
 Every out-of-core upload in this codebase used to be a serial loop:
 memmap read → host dtype cast → donated `dynamic_update_slice`, one
 chunk at a time, with the host idle during each transfer and the device
-idle during each read/cast. The r5 bench measured that loop at 634.9 s
-for the 10M×500 binned upload — 63% of the whole big-mode budget — the
-textbook input-bound pattern tf.data solves with pipelined prefetch.
+idle during each read/cast — the textbook input-bound pattern tf.data
+solves with pipelined prefetch.
 
 This module is the reusable fix: `run_chunk_pipeline` drives any
 host→device bulk transfer as a two-stage pipeline,

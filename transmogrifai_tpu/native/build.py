@@ -3,11 +3,13 @@
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
 import threading
-from typing import Optional
+from typing import Dict, Optional
 
 log = logging.getLogger(__name__)
 
@@ -18,31 +20,56 @@ _DIR = os.path.dirname(__file__)
 
 
 def _build(src: str, so_path: str) -> bool:
-    for cc in ("cc", "gcc", "clang"):
-        try:
-            res = subprocess.run(
-                [cc, "-O3", "-shared", "-fPIC", src, "-o", so_path],
-                capture_output=True, timeout=120)
+    """Compile `src` beside `so_path`, then rename into place: a
+    concurrent process never loads a half-written library."""
+    tmp = f"{so_path}.{os.getpid()}.tmp"
+    try:
+        for cc in ("cc", "gcc", "clang"):
+            try:
+                res = subprocess.run(
+                    [cc, "-O3", "-shared", "-fPIC", src, "-o", tmp],
+                    capture_output=True, timeout=120)
+            except (OSError, subprocess.TimeoutExpired):
+                continue
             if res.returncode == 0:
+                os.replace(tmp, so_path)
                 return True
-            log.debug("%s failed: %s", cc, res.stderr.decode()[:500])
-        except (OSError, subprocess.TimeoutExpired):
-            continue
-    return False
+            log.warning("%s failed on %s: %s", cc, src,
+                        res.stderr.decode()[:500])
+        return False
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+
+
+def _so_path(stem: str) -> str:
+    """The artifact is keyed by a hash of the committed `.c` source, so a
+    library built from another revision (or copied in with a newer
+    mtime) is never loaded."""
+    with open(os.path.join(_DIR, stem + ".c"), "rb") as fh:
+        digest = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"_{stem}-{digest}.so")
 
 
 def _load(stem: str, signatures) -> Optional[ctypes.CDLL]:
-    """Compile-on-first-use + bind; None → callers use the python path."""
+    """Compile-on-first-use + bind; None → callers use the python path
+    (`native_active()` reports which one is live)."""
     with _lock:
         if stem in _libs:
             return _libs[stem]
         _libs[stem] = None
-        src = os.path.join(_DIR, stem + ".c")
-        so_path = os.path.join(_DIR, f"_{stem}.so")
+        # the lock deliberately covers the one-time hash + build: two
+        # threads racing the first use must not both compile
         try:
-            if not os.path.exists(so_path) or \
-                    os.path.getmtime(so_path) < os.path.getmtime(src):
-                if not _build(src, so_path):
+            # conc-ok: C003 (one-time build under the load lock)
+            so_path = _so_path(stem)
+            if not os.path.exists(so_path):
+                for stale in glob.glob(os.path.join(_DIR, f"_{stem}*.so")):
+                    if stale != so_path:  # a racing process's fresh build
+                        # conc-ok: C003 (one-time build under the load lock)
+                        os.remove(stale)
+                # conc-ok: C003 (one-time build under the load lock)
+                if not _build(os.path.join(_DIR, stem + ".c"), so_path):
                     return None
             lib = ctypes.CDLL(so_path)
             for name, argtypes, restype in signatures:
@@ -53,6 +80,12 @@ def _load(stem: str, signatures) -> Optional[ctypes.CDLL]:
         except Exception:
             log.exception("native %s unavailable; using python path", stem)
         return _libs[stem]
+
+
+def native_active() -> Dict[str, bool]:
+    """Build/load every native kernel now and say which are live."""
+    return {"murmur3": get_murmur3() is not None,
+            "csv_parse": get_csv_parser() is not None}
 
 
 def get_murmur3() -> Optional[ctypes.CDLL]:

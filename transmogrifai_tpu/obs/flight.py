@@ -61,10 +61,8 @@ DEFAULT_MIN_INTERVAL_S = 5.0
 
 
 def default_dump_dir() -> str:
-    return os.environ.get(
-        "TRANSMOGRIFAI_FLIGHT_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache",
-                     "transmogrifai_tpu", "flight"))
+    from transmogrifai_tpu.store.config import resolve_dir
+    return resolve_dir("flight", env="TRANSMOGRIFAI_FLIGHT_DIR")
 
 
 class FlightRecorder:

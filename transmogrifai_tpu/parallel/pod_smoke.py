@@ -25,6 +25,11 @@ against one shared store dir and proves the cross-host contracts:
 The parent process never initializes JAX — it orchestrates child
 processes (``--child``), reads their JSON payloads, and inspects the
 shared store. Run: ``python -m transmogrifai_tpu.parallel.pod_smoke``.
+
+This is a CPU test tool: every child forces its own CPU host mesh, and
+several children run at once. It says nothing about a chip and must not
+be pointed at one (a chip belongs to one process at a time; the chip
+check is ``python chip_smoke.py``).
 """
 
 from __future__ import annotations

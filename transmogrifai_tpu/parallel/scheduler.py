@@ -82,7 +82,7 @@ class SweepJob:
     journal: Any = None        # ShardedSweepJournal (or None)
     name: str = ""
     # optional run_sweep-signature callable wrapping the block execution
-    # (the selector passes run_sweep behind its transient-RPC
+    # (the selector passes run_sweep behind its transient-error
     # RetryPolicy, so distribution keeps the single-device path's
     # fault tolerance); None = plain run_sweep
     run: Any = None

@@ -50,7 +50,7 @@ class PerfModelParams:
     and every consumer falls back to today's heuristics exactly."""
 
     enabled: bool = True
-    corpus_dir: Optional[str] = None      # default: env / ~/.cache/...
+    corpus_dir: Optional[str] = None      # default: env / <store root>/perf
     model_path: Optional[str] = None      # fitted model JSON to load
     target_block_s: Optional[float] = None  # scheduler width sizing
     hbm_budget_gb: Optional[float] = None   # pre-dispatch OOM gate

@@ -373,7 +373,6 @@ class FleetConfig:
     shed_watermark: float = 0.5
     serving: Dict[str, Any] = field(default_factory=dict)
     compile_cache: Optional[bool] = None
-    compile_cache_dir: Optional[str] = None
     # serving/resilience.ResilienceParams JSON: shared default for every
     # member's health machine / breaker / watchdog (a member spec's
     # serving overrides may still pin its own `resilience` block)
@@ -402,8 +401,7 @@ class FleetConfig:
     obs: Optional[Dict[str, Any]] = None
 
     _FIELDS = ("models", "tenants", "default_tenant", "shed_watermark",
-               "serving", "compile_cache", "compile_cache_dir",
-               "resilience", "slo", "store_dir", "replica",
+               "serving", "compile_cache", "resilience", "slo", "store_dir", "replica",
                "shared_quota", "autopilot", "obs")
 
     @staticmethod
@@ -597,9 +595,6 @@ class FleetService:
             base.setdefault("resilience", self.config.resilience)
         if self.config.compile_cache is not None:
             base.setdefault("compile_cache", self.config.compile_cache)
-        if self.config.compile_cache_dir is not None:
-            base.setdefault("compile_cache_dir",
-                            self.config.compile_cache_dir)
         known = {f for f in ServingConfig.__dataclass_fields__}
         unknown = set(base) - known
         if unknown:

@@ -15,10 +15,13 @@ prove the acceptance surface of the fleet subsystem
 4. a rolling swap of one model under live traffic drops ZERO in-flight
    requests on the untouched models — and the same-shaped replacement
    itself warms with zero new compiles;
-5. cold-start-to-first-score measured WITHOUT (fresh cache dir, cold
-   XLA compiles, warmup manifest written) and WITH the persistent
-   compile cache (second service instance over the same artifacts:
-   manifest hit, `serving_compile_cache_saved_s` recorded).
+5. first boot over fresh artifacts writes the warmup manifest; a
+   second service instance over the same artifacts hits the manifest
+   and the persistent compile cache and records
+   `serving_compile_cache_saved_s`. Both boots' time-to-first-score is
+   printed; the compile cache sits at its one fixed directory
+   (utils/compile_cache.py), so the first boot is XLA-cold only on a
+   fresh checkout.
 
 Run: ``JAX_PLATFORMS=cpu python -m transmogrifai_tpu.serving.fleet_smoke``
 """
@@ -97,7 +100,6 @@ def main() -> int:  # noqa: C901 (one linear acceptance script)
 
     with tempfile.TemporaryDirectory(prefix="fleet-smoke-") as tmp:
         _train_models(tmp)
-        cache_dir = f"{tmp}/xla-cache"
 
         def config() -> FleetConfig:
             return FleetConfig(
@@ -106,7 +108,7 @@ def main() -> int:  # noqa: C901 (one linear acceptance script)
                                    "priority": 0}},
                 serving={"max_batch": 8, "batch_wait_ms": 1.0,
                          "max_queue": 256},
-                compile_cache=True, compile_cache_dir=cache_dir)
+                compile_cache=True)
 
         # -- 1+2: three models, shared programs, COLD start ------------- #
         t0 = time.perf_counter()
@@ -240,8 +242,8 @@ def main() -> int:  # noqa: C901 (one linear acceptance script)
           f"{delta_c} own compiles for the odd one); quota shed "
           f"{counts['trial_429']} trial vs 0 gold under load; rolling "
           f"swap dropped 0 in-flight (b={served['b']}, c={served['c']} "
-          f"served); cold-start-to-first-score {cold_s:.2f}s uncached "
-          f"vs {warm_s:.2f}s with persistent cache + manifest")
+          f"served); time-to-first-score {cold_s:.2f}s first boot "
+          f"vs {warm_s:.2f}s with manifest + persistent cache")
     return 0
 
 

@@ -26,6 +26,12 @@ Run: ``python -m transmogrifai_tpu.serving.fleetobs_smoke`` (the
 ``--replica`` flag is the internal worker entry). Also wired as
 ``make fleetobs-smoke`` and ``python -m transmogrifai_tpu.serving.chaos
 --fleet``.
+
+This is a CPU test tool: the orchestrator trains with JAX and then
+starts two replica processes beside itself, which is only possible on
+the CPU backend the replicas default to (``JAX_PLATFORMS=cpu``). A chip
+belongs to one process at a time; the chip check is
+``python chip_smoke.py``.
 """
 
 from __future__ import annotations

@@ -88,9 +88,10 @@ class ServingConfig:
     # ladder is MANY small programs; a replica's cold start is their
     # compile-time sum). None = read TRANSMOGRIFAI_SERVING_COMPILE_CACHE
     # (off when unset — tests and embedded callers stay hermetic);
-    # `cli serve` defaults it ON.
+    # `cli serve` defaults it ON. The directory is not a knob here:
+    # JAX_COMPILATION_CACHE_DIR places it, else <store root>/xla-cache
+    # (utils/compile_cache.py).
     compile_cache: Optional[bool] = None
-    compile_cache_dir: Optional[str] = None
     # write/read the AOT warmup manifest beside each model artifact
     # (workflow/serialization.save_warmup_manifest): a cold warmup
     # records its wall seconds + ladder; a later replica (or same-shaped
@@ -367,7 +368,7 @@ class ScoringService:
             from transmogrifai_tpu.utils.compile_cache import (
                 enable_compile_cache)
             self._compile_cache_path = enable_compile_cache(
-                self.config.compile_cache_dir, min_compile_s=0.0)
+                min_compile_s=0.0)
         self._init_metrics()
         if self.config.feature_cache:
             # device-matrix cache policy for this serving process: warm

@@ -1,12 +1,13 @@
 """One resolution point for every shared on-disk location.
 
-Before the store existed, each subsystem hardcoded its own corner of
-`~/.cache/transmogrifai_tpu` (feature cache, perf corpus, XLA compile
-cache, sweep calibration), so pointing a K-replica fleet at shared
-storage meant chasing N env vars and still missing the hardcoded
-fallbacks. Now: `TRANSMOGRIFAI_STORE_DIR` moves the WHOLE root (every
-subsystem follows), while each subsystem's existing env var still wins
-for its own subtree — nothing previously configurable got less so.
+`TRANSMOGRIFAI_STORE_DIR` moves the WHOLE root (feature cache, perf
+corpus, sweep calibration, warm-up manifests, XLA compile cache all
+follow), while each subsystem's own env var still wins for its subtree
+(for the compile cache that is the standard `JAX_COMPILATION_CACHE_DIR`,
+utils/compile_cache.py). Unset, the root is
+`DEFAULT_ROOT`: a fixed, git-ignored directory INSIDE the checkout, so
+state a run learns (dispatch-width calibration feeds compiled shapes)
+never passes between two checkouts on one machine through `$HOME`.
 """
 
 from __future__ import annotations
@@ -14,6 +15,7 @@ from __future__ import annotations
 import os
 
 __all__ = [
+    "DEFAULT_ROOT",
     "ENV_STORE",
     "cache_root",
     "resolve_dir",
@@ -23,10 +25,15 @@ __all__ = [
 ENV_STORE = "TRANSMOGRIFAI_STORE_DIR"
 
 # subsystem env overrides, kept here so callers and docs agree on the
-# precedence order: explicit arg > subsystem env > store root env > HOME
+# precedence order: explicit arg > subsystem env > store root env >
+# DEFAULT_ROOT
 ENV_FEATURE_CACHE = "TRANSMOGRIFAI_FEATURE_CACHE_DIR"
 ENV_PERF_CORPUS = "TRANSMOGRIFAI_PERF_CORPUS_DIR"
-ENV_COMPILE_CACHE = "TRANSMOGRIFAI_TPU_CACHE"
+
+# <checkout>/.transmogrifai_store (listed in .gitignore/.chiprunignore)
+DEFAULT_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".transmogrifai_store")
 
 
 def store_configured() -> bool:
@@ -37,10 +44,7 @@ def store_configured() -> bool:
 
 
 def cache_root() -> str:
-    env = os.environ.get(ENV_STORE)
-    if env:
-        return env
-    return os.path.expanduser("~/.cache/transmogrifai_tpu")
+    return os.environ.get(ENV_STORE) or DEFAULT_ROOT
 
 
 def resolve_dir(kind: str, env: str | None = None,
@@ -49,7 +53,7 @@ def resolve_dir(kind: str, env: str | None = None,
 
     Precedence: explicit caller arg, then the subsystem's own env var,
     then `<store root>/<kind>` (where the store root itself honors
-    `TRANSMOGRIFAI_STORE_DIR` before falling back to the home cache).
+    `TRANSMOGRIFAI_STORE_DIR` before falling back to `DEFAULT_ROOT`).
     """
     if explicit:
         return explicit

@@ -1,11 +1,25 @@
-"""Persistent XLA compilation cache.
+"""Persistent XLA compilation cache: the one place its directory is chosen.
 
-The sweep engine's cost on a fresh process is compile-dominated (each
-tree-family program takes 15-50s through the remote AOT compile service;
-warm executions are sub-second). JAX's persistent compilation cache works
-with this backend, so enabling it makes every run after the first start
-warm. Called by bench.py, __graft_entry__, the WorkflowRunner/CLI, and the
-examples; tests keep the default (CPU compiles are cheap and hermetic).
+A fresh process pays every sweep/scoring program's XLA compile; JAX's
+persistent compilation cache makes every run after the first start warm.
+The cache directory is part of each entry's key, so it must not move:
+
+- `JAX_COMPILATION_CACHE_DIR` set: JAX already has its directory. This
+  module writes NO `jax_compilation_cache_dir` (logged once) — whoever
+  launched the process placed the cache.
+- unset: `<store root>/xla-cache`, resolved like every other kind of
+  shared state (store/config.py): fixed inside the checkout by default
+  (never `$HOME`, a temp name, a pid or a timestamp), and following
+  `TRANSMOGRIFAI_STORE_DIR` so replicas on one shared store replay each
+  other's compiles.
+
+The directory is chosen on the FIRST call in a process and later calls
+return it: JAX opens its cache once per process and reads the directory
+into every key, so re-pointing it mid-process would write entries no
+later process can find.
+
+Called by bench.py, __graft_entry__, the WorkflowRunner/CLI, the serving
+layer and the test suite's conftest.
 """
 
 from __future__ import annotations
@@ -13,50 +27,51 @@ from __future__ import annotations
 import logging
 import os
 
+from transmogrifai_tpu.store.config import resolve_dir
+
 log = logging.getLogger(__name__)
 
-def _default_dir() -> str:
-    # resolved through the shared store config: pointing
-    # TRANSMOGRIFAI_STORE_DIR at shared storage moves the compile cache
-    # there too (a second replica replays this replica's compiles)
-    from transmogrifai_tpu.store.config import resolve_dir
-    return resolve_dir("xla-cache")
+ENV_JAX_CACHE = "JAX_COMPILATION_CACHE_DIR"
 
-# the JAX compilation cache is PROCESS-GLOBAL config: remember what was
-# applied so a second caller asking for a different dir/threshold gets a
-# loud warning instead of silently re-pointing every other subsystem's
-# compiles (e.g. a serving member reconfiguring under a training run)
-_applied: "tuple | None" = None
+_dir: str | None = None  # this process's cache directory, once chosen
 
 
-def enable_compile_cache(path: str | None = None,
-                         min_compile_s: float = 0.5) -> str | None:
-    """Best-effort: an unwritable HOME/cache dir must never break startup
-    (returns None and leaves JAX's default config in place).
+def enable_compile_cache(min_compile_s: float = 0.5) -> str | None:
+    """Turn the persistent cache on and return its directory (None when
+    the store directory cannot be created — an unwritable checkout
+    must never break startup).
 
     `min_compile_s` is the persistence threshold: the 0.5s default skips
     throwaway programs during training, while the serving layer passes
     0.0 — a bucket ladder is MANY small programs, and a replica's
     cold-start-to-first-score is their compile-time SUM, so each one is
-    worth persisting even where a single compile is cheap."""
-    global _applied
+    worth persisting even where a single compile is cheap. The threshold
+    is process-global; the last caller wins."""
+    global _dir
     import jax
 
-    path = path or os.environ.get("TRANSMOGRIFAI_TPU_CACHE") \
-        or _default_dir()
-    try:
-        os.makedirs(path, exist_ok=True)
-        if _applied is not None and _applied != (path, float(min_compile_s)):
-            # explicit wins (last caller), but never silently: the config
-            # is process-global, so everyone's compiles move with it
-            log.warning(
-                "compile cache reconfigured process-wide: %s (min %.2fs) "
-                "-> %s (min %.2fs)", _applied[0], _applied[1], path,
-                float(min_compile_s))
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_s))
-        _applied = (path, float(min_compile_s))
-        return path
-    except OSError:
-        return None
+    if _dir is None:
+        path = os.environ.get(ENV_JAX_CACHE)
+        if path:
+            log.info("%s=%s places the XLA compile cache; no in-code "
+                     "directory is applied", ENV_JAX_CACHE, path)
+        else:
+            path = resolve_dir("xla-cache")
+            try:
+                os.makedirs(path, exist_ok=True)
+            except OSError:
+                return None
+            jax.config.update("jax_compilation_cache_dir", path)
+        _dir = path
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_s))
+    return _dir
+
+
+def compile_cache_entries() -> int:
+    """Entries in this process's cache directory right now (0 while the
+    directory does not exist yet). The directory is fixed, so a boot is
+    XLA-cold only if this read 0 before it — any earlier run in the same
+    place has already warmed the cache."""
+    path = _dir or enable_compile_cache()
+    return len(os.listdir(path)) if path and os.path.isdir(path) else 0

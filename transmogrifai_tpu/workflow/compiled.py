@@ -378,9 +378,9 @@ class CompiledScorer:
             for s in stages]
         # megabyte-scale fitted arrays (tree tables, lifted linear/GLM
         # weights) flow into the jitted segments as ARGUMENTS: closure
-        # constants are re-staged host→device on every execution through
-        # the serving tunnel, and value-baked weights would force every
-        # tenant onto its own compiled program (serving/fleet.py). In
+        # constants are baked into the executable, and value-baked
+        # weights would force every tenant onto its own compiled
+        # program (serving/fleet.py). In
         # quantized mode the stage may narrow its tables (shape-gated
         # dtype rules only, so same-signature tenants narrow alike).
         self._consts: Dict[str, Any] = {}

@@ -72,7 +72,6 @@ class ServingParams:
     # bucket ladder; None = TRANSMOGRIFAI_SERVING_COMPILE_CACHE env
     # (cli `serve` defaults it on)
     compile_cache: Optional[bool] = None
-    compile_cache_dir: Optional[str] = None
     # write/read the AOT warmup manifest beside each model artifact so
     # warm starts report `serving_compile_cache_saved_s`
     warmup_manifest: bool = True
@@ -107,7 +106,7 @@ class ServingParams:
     _FIELDS = ("host", "port", "max_batch", "min_bucket", "buckets",
                "max_queue", "batch_wait_ms", "default_deadline_ms",
                "warm_on_load", "keep_versions", "auto_ladder",
-               "feature_cache", "compile_cache", "compile_cache_dir",
+               "feature_cache", "compile_cache",
                "warmup_manifest", "fleet", "resilience", "quantize",
                "tracing", "slo", "flight", "autopilot")
 
@@ -133,7 +132,6 @@ class ServingParams:
             auto_ladder=self.auto_ladder,
             feature_cache=self.feature_cache,
             compile_cache=self.compile_cache,
-            compile_cache_dir=self.compile_cache_dir,
             warmup_manifest=self.warmup_manifest,
             resilience=self.resilience,
             quantize=self.quantize,
@@ -165,7 +163,6 @@ class ServingParams:
         if self.flight is not None:
             serving.setdefault("flight", self.flight)
         block.setdefault("compile_cache", self.compile_cache)
-        block.setdefault("compile_cache_dir", self.compile_cache_dir)
         if self.resilience is not None:
             block.setdefault("resilience", self.resilience)
         if self.slo is not None:
