@@ -273,6 +273,7 @@ def run(platform: str) -> dict:
     sweep_compile_s = None
     if smoke or os.environ.get("BENCH_WARM") == "1" or (
             t_train < 300 and _remaining() > t_train + 600):
+        from transmogrifai_tpu.obs.trace import TRACER
         from transmogrifai_tpu.parallel.sweep import SWEEP_STATS
         from transmogrifai_tpu.stages.base import FitContext
         sel_stage = pf.origin_stage
@@ -280,16 +281,20 @@ def run(platform: str) -> dict:
         sel_inputs = [model.train_columns[f.uid]
                       for f in sel_stage.input_features]
         SWEEP_STATS.reset()
+        mark = max((sp.span_id for sp in TRACER.spans()), default=0)
         t0 = time.perf_counter()
         sel_est.fit(sel_inputs, FitContext(n_rows=n_rows, seed=43))
         t_sweep_warm = time.perf_counter() - t0
-        # device-dispatch occupancy of the sweep wall-clock + estimated
-        # compile/first-exec overhead (SURVEY §6 "measure instead")
+        # device-dispatch occupancy of the sweep wall-clock + the XLA
+        # compile seconds its dispatches asked for, measured (the
+        # `compile:*` spans of utils/compile_cache.py; thread-seconds)
         # can exceed 1.0: dispatch seconds SUM across the family thread
         # pool while t_sweep_warm is wall-clock, so >1 simply means
         # families overlapped (the reference's Parallelism=8 analogue)
         sweep_dispatch_fraction = SWEEP_STATS.dispatch_s / t_sweep_warm
-        sweep_compile_s = SWEEP_STATS.compile_estimate_s()
+        sweep_compile_s = sum(
+            sp.duration_s for sp in TRACER.spans() if sp.span_id > mark
+            and sp.name.startswith("compile:sweep:dispatch:"))
 
     # fused scoring: warm up (compile), then measure
     t0 = time.perf_counter()
@@ -450,8 +455,8 @@ def run(platform: str) -> dict:
         "sweep_dispatch_fraction": (round(sweep_dispatch_fraction, 3)
                                     if sweep_dispatch_fraction is not None
                                     else None),
-        "sweep_compile_est_s": (round(sweep_compile_s, 1)
-                                if sweep_compile_s is not None else None),
+        "sweep_compile_s": (round(sweep_compile_s, 1)
+                            if sweep_compile_s is not None else None),
         # headline roofline fields; scoring_flops is secondary context
         # absent (not null) where the device's peak is unknown: the CPU
         **({"scoring_hbm_frac": roofline["scoring_hbm_frac"]}

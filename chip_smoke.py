@@ -173,46 +173,28 @@ def _device_report(rehearsal: bool) -> dict:
     return device
 
 
-def _count_xla_compiles() -> dict:
-    """Live counters fed by JAX's own monitoring events: compile
-    requests that consulted the persistent cache, how many it answered,
-    and the seconds the backend spent compiling the rest."""
-    from jax import monitoring
-    counts = {"requests": 0, "cache_hits": 0, "backend_compile_s": 0.0}
-
-    def on_event(event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            counts["requests"] += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            counts["cache_hits"] += 1
-
-    def on_duration(event, duration_secs, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            counts["backend_compile_s"] += duration_secs
-
-    monitoring.register_event_listener(on_event)
-    monitoring.register_event_duration_secs_listener(on_duration)
-    return counts
-
-
 def _train_breakdown() -> dict:
     """Where the train wall went, from the spans and counters the
     program already keeps: per-stage fit walls, per-family sweep walls
     (families overlap on a thread pool, so they do not sum to the
-    selector's wall) and the sweep's dispatch accounting."""
+    selector's wall), the sweep's dispatch accounting, and the XLA
+    compiles the sweep's dispatches asked for (`compile:*` spans,
+    measured thread-seconds)."""
     from transmogrifai_tpu.obs.trace import TRACER
     from transmogrifai_tpu.parallel.sweep import SWEEP_STATS
     walls: dict = {}
+    sweep_compiles = []
     for sp in TRACER.spans():
         if sp.name.startswith(("stage:fit:", "sweep:family:")):
             walls[sp.name] = round(walls.get(sp.name, 0.0)
                                    + sp.duration_s, 1)
+        elif sp.name.startswith("compile:sweep:dispatch:"):
+            sweep_compiles.append(sp.duration_s)
     return {"span_wall_s": walls,
             "sweep_dispatches": SWEEP_STATS.dispatches,
             "sweep_dispatch_s": round(SWEEP_STATS.dispatch_s, 1),
-            "sweep_first_dispatches": SWEEP_STATS.firsts,
-            "sweep_compile_est_s": round(
-                SWEEP_STATS.compile_estimate_s(), 1)}
+            "sweep_compiles": len(sweep_compiles),
+            "sweep_compile_s": round(sum(sweep_compiles), 1)}
 
 
 def _prob1(tree):
@@ -251,7 +233,7 @@ def child_train_score(work: str, rehearsal: bool) -> dict:
     from transmogrifai_tpu.perf.params import hbm_budget_bytes
     from transmogrifai_tpu.readers import DataReaders
     from transmogrifai_tpu.utils.compile_cache import (
-        compile_cache_entries, enable_compile_cache)
+        COMPILE_STATS, compile_cache_entries, enable_compile_cache)
 
     res: dict = {"device": _device_report(rehearsal),
                  "compile_cache": enable_compile_cache()}
@@ -276,13 +258,14 @@ def child_train_score(work: str, rehearsal: bool) -> dict:
     if shutil.which("cc") and not all(res["native"].values()):
         raise PhaseFailed("cc exists but a native kernel did not build")
 
-    xla = _count_xla_compiles()
+    xla0 = dict(COMPILE_STATS)  # fed by the program's own listeners
     events_path = os.path.join(work, "events.jsonl")
     install_event_log(EventLog(events_path, run_id="chip-smoke"))
     model, ds, pf, fitted, n_fits, wall = _train(rehearsal)
     summary = fitted.summary
     res["train_wall_s"] = round(wall, 1)
     res["train_breakdown"] = _train_breakdown()
+    xla = {k: COMPILE_STATS[k] - xla0[k] for k in xla0}
     res["train_xla"] = {**xla, "backend_compile_s":
                         round(xla["backend_compile_s"], 1)}
     _say(f"[train] breakdown: {res['train_breakdown']}; XLA compile "

@@ -1,0 +1,474 @@
+"""One timeline for a train pass: program spans on the profiler's clock,
+XLA compiles as spans under the span that asked for them, the sweep's
+dispatches and the selector's phases spanned, kernels under stable
+scope names, and the benchmark's readers of those spans.
+
+Everything that starts the JAX profiler lives in this file, so one
+xdist worker owns it.
+"""
+
+import glob
+import importlib.util
+import os
+import re
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from transmogrifai_tpu.automl import transmogrify
+from transmogrifai_tpu.data import Dataset
+from transmogrifai_tpu.evaluators import (
+    BinaryClassificationEvaluator, RegressionEvaluator)
+from transmogrifai_tpu.evaluators.device_metrics import make_device_metric
+from transmogrifai_tpu.features import FeatureBuilder
+from transmogrifai_tpu.models import (
+    OpLogisticRegression, OpRandomForestClassifier, OpXGBoostClassifier)
+from transmogrifai_tpu.models import trees
+from transmogrifai_tpu.models.linear import fit_linreg_enet
+from transmogrifai_tpu.models.logistic import fit_logreg_enet
+from transmogrifai_tpu.obs import goodput as obsg
+from transmogrifai_tpu.obs.trace import TRACER, RequestTrace, Tracer, now_s
+from transmogrifai_tpu.parallel.sweep import SWEEP_STATS, run_sweep
+from transmogrifai_tpu.selector import (
+    BinaryClassificationModelSelector, DataSplitter)
+from transmogrifai_tpu.stages.base import FitContext
+from transmogrifai_tpu.utils import compile_cache
+from transmogrifai_tpu.workflow import Workflow
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+FAMILIES = {  # estimator -> (sweep family name, grid)
+    "logistic": (OpLogisticRegression(max_iter=8),
+                 [{"reg_param": 0.01, "elastic_net_param": 0.1},
+                  {"reg_param": 0.1, "elastic_net_param": 0.5}]),
+    "forest": (OpRandomForestClassifier(n_trees=1, max_bins=8),
+               [{"max_depth": 2}]),
+    "gbt": (OpXGBoostClassifier(n_estimators=2, max_bins=8),
+            [{"max_depth": 2}]),
+}
+
+
+def _children(spans, parent):
+    return sorted((s for s in spans if s.parent_id == parent.span_id),
+                  key=lambda s: (s.start_s, s.span_id))
+
+
+# --------------------------------------------------------------------- #
+# A. one clock                                                          #
+# --------------------------------------------------------------------- #
+
+def test_span_sits_in_the_profilers_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with TRACER.span("timeline:probe"):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    host = [ev for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events
+            if ev.name == "timeline:probe"]
+    assert len(host) == 1
+    assert host[0].duration_ns >= 2e6
+
+
+def test_span_at_backdates_a_finished_span_under_the_current_one():
+    tr = Tracer()
+    with tr.span("owner") as owner:
+        t1 = now_s()
+        sp = tr.span_at("late", t1 - 0.5, t1, category="compile", k=1)
+    assert sp.parent_id == owner.span_id and sp.trace_id == owner.trace_id
+    assert sp.duration_s == pytest.approx(0.5)
+    assert sp.category == "compile" and sp.attributes == {"k": 1}
+    assert [s.name for s in tr.spans()] == ["late", "owner"]
+    # an end before the start clamps to an empty span, never negative
+    assert tr.span_at("odd", 2.0, 1.0).duration_s == 0.0
+    # the request buffer backdates through the same code and stays out
+    # of the ring until the tail sampler keeps it
+    rt = RequestTrace()
+    child = rt.child_at("serving:pad", 1.0, 1.25, error="boom")
+    assert child.duration_s == pytest.approx(0.25)
+    assert child.error == "boom" and child.parent_id == rt.root.span_id
+    assert child in rt.spans and child not in TRACER.spans()
+
+
+# --------------------------------------------------------------------- #
+# B. every XLA compile is a span where it happens                       #
+# --------------------------------------------------------------------- #
+
+def test_compile_is_one_span_under_the_workers_current_span():
+    def timeline_probe_fn(x):
+        return (x * 3.0 + 1.0).sum()
+
+    compile_cache.register_compile_listeners()
+    compile_cache.register_compile_listeners()  # once per process
+    prog = jax.jit(timeline_probe_fn)
+    x = np.ones((7, 3), np.float32)
+    seen = {}
+
+    def work():
+        with TRACER.span("timeline:owner") as owner:
+            seen["owner"] = owner
+            before = dict(compile_cache.COMPILE_STATS)
+            mark = max((s.span_id for s in TRACER.spans()), default=0)
+            prog(x).block_until_ready()
+            seen["first"] = [s for s in TRACER.spans() if s.span_id > mark]
+            mark = max((s.span_id for s in TRACER.spans()), default=mark)
+            prog(x).block_until_ready()
+            seen["second"] = [s for s in TRACER.spans() if s.span_id > mark]
+            seen["stats"] = {k: compile_cache.COMPILE_STATS[k] - before[k]
+                             for k in before}
+
+    worker = threading.Thread(target=work, name="timeline-worker")
+    worker.start()
+    worker.join(timeout=120)
+    assert not worker.is_alive()
+    name = "compile:timeline:owner/jit(timeline_probe_fn)"
+    mine = [s for s in seen["first"] if s.name == name]
+    assert len(mine) == 1
+    sp = mine[0]
+    assert sp.category == "compile"
+    assert sp.parent_id == seen["owner"].span_id
+    assert sp.thread_name == "timeline-worker"
+    assert sp.end_s is not None and sp.duration_s > 0.0
+    assert seen["owner"].start_s <= sp.start_s <= sp.end_s
+    assert not [s for s in seen["second"] if s.category == "compile"]
+    assert seen["stats"]["backend_compile_s"] >= sp.duration_s
+    assert seen["stats"]["requests"] >= 1
+
+
+def test_compile_outside_any_span_is_named_for_no_owner():
+    mark = max((s.span_id for s in TRACER.spans()), default=0)
+    assert TRACER.current() is None
+    compile_cache._on_duration(
+        "/jax/core/compile/backend_compile_duration", 0.25,
+        fun_name="jit(f)")
+    compile_cache._on_duration("/jax/core/compile/jaxpr_trace_duration",
+                               9.0, fun_name="f")
+    new = [s for s in TRACER.spans() if s.span_id > mark]
+    assert [(s.name, s.parent_id) for s in new] == [("compile:-/jit(f)",
+                                                     None)]
+    assert new[0].duration_s == pytest.approx(0.25)
+
+
+def test_persistent_cache_hit_is_an_event_on_the_current_span():
+    hits = compile_cache.COMPILE_STATS["cache_hits"]
+    with TRACER.span("timeline:hit") as sp:
+        compile_cache._on_event("/jax/compilation_cache/cache_hits")
+        compile_cache._on_event("/jax/compilation_cache/cache_misses")
+    assert [e[0] for e in sp.events] == ["compile_cache_hit"]
+    assert compile_cache.COMPILE_STATS["cache_hits"] == hits + 1
+
+
+def test_goodput_counts_compile_spans_as_recompile_seconds():
+    tr = Tracer()
+    with tr.span("run", new_trace=True) as root:
+        root.event("recompile", trace_s=0.003)
+        t1 = now_s()
+        tr.span_at("compile:run/jit(f)", t1 - 0.01, t1, category="compile")
+        time.sleep(0.03)
+    report = obsg.build_report(root, tr.trace_spans(root.trace_id))
+    assert report.buckets["recompile_s"] == pytest.approx(0.013)
+    assert report.counts["recompiles"] == 1
+    assert sum(report.buckets.values()) == pytest.approx(
+        report.wall_s, rel=1e-6)
+
+
+# --------------------------------------------------------------------- #
+# C. every sweep dispatch is a span                                     #
+# --------------------------------------------------------------------- #
+
+def _sweep_inputs(n=160, seed=3):
+    rng = np.random.default_rng(seed)
+    X = jnp.asarray(rng.normal(size=(n, 5)).astype(np.float32))
+    y = jnp.asarray((rng.normal(size=n) > 0).astype(np.float32))
+    folds = [((np.arange(n) % 2 != f).astype(np.float32),
+              (np.arange(n) % 2 == f).astype(np.float32))
+             for f in range(2)]
+    return X, y, folds
+
+
+@pytest.mark.parametrize("family", sorted(FAMILIES))
+def test_sweep_dispatches_are_spans_under_the_block(family):
+    est, grids = FAMILIES[family]
+    X, y, folds = _sweep_inputs()
+    d0 = SWEEP_STATS.dispatches
+    with TRACER.span("run:sweep", new_trace=True) as root:
+        rows = run_sweep(est, grids, X, y, folds,
+                         BinaryClassificationEvaluator(),
+                         FitContext(n_rows=int(X.shape[0]), seed=7))
+    assert all(np.isfinite(m) for row in rows for m in row)
+    spans = TRACER.trace_spans(root.trace_id)
+    by_id = {s.span_id: s for s in spans}
+    dispatches = [s for s in spans if s.category == "sweep_dispatch"]
+    assert dispatches
+    assert {s.name for s in dispatches} == {f"sweep:dispatch:{family}"}
+    assert {by_id[s.parent_id].name for s in dispatches} == {"sweep:block"}
+    fetches = [s for s in spans if s.name == f"sweep:fetch:{family}"]
+    assert fetches
+    assert {by_id[s.parent_id].name for s in fetches} == {"sweep:block"}
+    # the tree families time every dispatch into SWEEP_STATS; the
+    # logistic block is spanned and not counted there
+    counted = SWEEP_STATS.dispatches - d0
+    assert counted == (0 if family == "logistic" else len(dispatches))
+    # a first dispatch's compile is a child of the dispatch
+    compiles = [s for s in spans if s.name.startswith(
+        f"compile:sweep:dispatch:{family}/")]
+    assert {by_id[s.parent_id].name for s in compiles} <= {
+        f"sweep:dispatch:{family}"}
+
+
+# --------------------------------------------------------------------- #
+# D. the selector's phases and the entry's remainder                    #
+# --------------------------------------------------------------------- #
+
+@pytest.fixture(scope="module")
+def train_spans():
+    """One tiny LR + RF + GBT train under a root span: (root, spans,
+    tree-family dispatches SWEEP_STATS counted)."""
+    rng = np.random.default_rng(0)
+    n = 240
+    x1, x2 = rng.normal(size=n), rng.normal(size=n)
+    y = (x1 + 0.5 * x2 + rng.normal(0, 0.5, n) > 0).astype(int)
+    ds = Dataset.from_rows([{"x1": float(x1[i]), "x2": float(x2[i]),
+                             "y": int(y[i])} for i in range(n)])
+    preds, label = FeatureBuilder.from_dataset(ds, response="y")
+    sel = BinaryClassificationModelSelector.with_cross_validation(
+        models=[FAMILIES[f] for f in ("logistic", "forest", "gbt")],
+        n_folds=2, splitter=DataSplitter(reserve_test_fraction=0.2))
+    pf = sel.set_input(label, transmogrify(preds)).get_output()
+    d0 = SWEEP_STATS.dispatches
+    with TRACER.span("run:train", new_trace=True) as root:
+        Workflow().set_result_features(pf, label) \
+            .set_input_dataset(ds).train()
+    return (root, TRACER.trace_spans(root.trace_id),
+            SWEEP_STATS.dispatches - d0)
+
+
+def test_selector_phases_in_order_under_the_stage_under_the_train(
+        train_spans):
+    root, spans, _ = train_spans
+    train, = [s for s in spans if s.name == "workflow:train"]
+    assert train.parent_id == root.span_id
+    under_train = _children(spans, train)
+    assert under_train[0].name == "workflow:materialize"
+    assert [s.name for s in under_train].count("workflow:materialize") == 1
+    stage, = [s for s in under_train
+              if s.name.startswith("stage:fit:") and "ModelSelector" in s.name]
+    phases = [s for s in _children(spans, stage)
+              if s.name.startswith("selector:")]
+    assert [s.name for s in phases] == [
+        "selector:prepare", "selector:sweep", "selector:refit",
+        "selector:evaluate"]
+    assert all(a.end_s <= b.start_s for a, b in zip(phases, phases[1:]))
+    # the phases leave next to nothing of the selector's fit unnamed
+    assert stage.duration_s - sum(s.duration_s for s in phases) < 0.05
+    # no new span takes a name the benchmark's older readers sum
+    new = [s for s in spans if s.name.startswith(
+        ("selector:", "workflow:", "sweep:dispatch:", "sweep:fetch:",
+         "compile:"))]
+    assert not [s for s in new if s.name.startswith(
+        ("stage:fit:", "stage:transform:", "sweep:family:"))]
+
+
+@pytest.mark.parametrize("family,est", [
+    ("logistic", "OpLogisticRegression"),
+    ("forest", "OpRandomForestClassifier"),
+    ("gbt", "OpXGBoostClassifier")])
+def test_train_nests_dispatch_under_block_under_family_under_sweep(
+        train_spans, family, est):
+    _, spans, _ = train_spans
+    by_id = {s.span_id: s for s in spans}
+    dispatches = [s for s in spans if s.name == f"sweep:dispatch:{family}"]
+    assert dispatches
+    for sp in dispatches:
+        chain = []
+        while sp.parent_id is not None:
+            sp = by_id[sp.parent_id]
+            chain.append(sp.name)
+        assert chain[:3] == ["sweep:block", f"sweep:family:{est}",
+                             "selector:sweep"]
+        assert chain[-2:] == ["workflow:train", "run:train"]
+
+
+def test_tree_family_dispatch_spans_equal_the_sweeps_own_count(train_spans):
+    _, spans, counted = train_spans
+    tree = [s for s in spans if s.name in ("sweep:dispatch:forest",
+                                           "sweep:dispatch:gbt")]
+    assert counted > 0 and len(tree) == counted
+
+
+def test_refit_and_evaluate_compiles_are_named_for_their_phase(train_spans):
+    _, spans, _ = train_spans
+    by_id = {s.span_id: s for s in spans}
+    compiles = [s for s in spans if s.category == "compile"]
+    assert compiles
+    for sp in compiles:  # the name carries the owner the parent link has
+        owner = by_id[sp.parent_id].name
+        assert sp.name.startswith(f"compile:{owner}/jit(")
+
+
+# --------------------------------------------------------------------- #
+# E. stable kernel names                                                #
+# --------------------------------------------------------------------- #
+
+def _tree_inputs(n=64, d=3, n_bins=4):
+    rng = np.random.default_rng(1)
+    Xb = jnp.asarray(rng.integers(0, n_bins, size=(n, d)), jnp.int8)
+    G = jnp.asarray(rng.normal(size=(n, 1)).astype(np.float32))
+    H = jnp.ones((n,), jnp.float32)
+    return Xb, G, H
+
+
+def _lower_grow_tree():
+    Xb, G, H = _tree_inputs()
+    return jax.jit(lambda a, g, h: trees.grow_tree(a, g, h, 2, 4)) \
+        .lower(Xb, G, H)
+
+
+def _lower_forest():
+    Xb, G, H = _tree_inputs()
+    return trees.fit_forest.lower(Xb, G, H, n_trees=2, max_depth=2,
+                                  n_bins=4, n_outputs=1, seed=0)
+
+
+def _lower_bin():
+    X = jnp.ones((16, 3), jnp.float32)
+    edges = jnp.asarray(np.linspace(-1, 1, 3 * 3).reshape(3, 3), jnp.float32)
+    return jax.jit(trees.bin_features).lower(X, edges)
+
+
+def _lower_predict():
+    Xb, G, H = _tree_inputs()
+    tree = trees.grow_tree(Xb, G, H, 2, 4)
+    return jax.jit(trees.predict_tree).lower(tree, Xb)
+
+
+def _lower_logreg():
+    X = jnp.ones((16, 3), jnp.float32)
+    y = jnp.asarray(np.arange(16) % 2, jnp.float32)
+    return jax.jit(lambda a, b, w: fit_logreg_enet(
+        a, b, w, 0.01, 0.01, 2, max_iter=3)).lower(X, y, jnp.ones(16))
+
+
+def _lower_linreg():
+    X = jnp.ones((16, 3), jnp.float32)
+    y = jnp.asarray(np.arange(16), jnp.float32)
+    return jax.jit(lambda a, b, w: fit_linreg_enet(
+        a, b, w, 0.01, 0.01, max_iter=3)).lower(X, y, jnp.ones(16))
+
+
+def _lower_metric(evaluator, pred_key):
+    fn = make_device_metric(evaluator)
+    y = jnp.asarray(np.arange(16) % 2, jnp.float32)
+    return jax.jit(lambda s, m: fn(y, {pred_key: s}, m)) \
+        .lower(jnp.linspace(0.0, 1.0, 16), jnp.ones(16))
+
+
+SCOPES = [
+    ("tree:hist", _lower_grow_tree), ("tree:split", _lower_grow_tree),
+    ("tree:route", _lower_grow_tree), ("tree:bootstrap", _lower_forest),
+    ("tree:hist", _lower_forest), ("tree:bin", _lower_bin),
+    ("tree:predict", _lower_predict), ("linear:fista", _lower_logreg),
+    ("linear:fista", _lower_linreg),
+    ("metric:aupr", lambda: _lower_metric(
+        BinaryClassificationEvaluator("AuPR"), "prediction")),
+    ("metric:auroc", lambda: _lower_metric(
+        BinaryClassificationEvaluator("AuROC"), "prediction")),
+    ("metric:rmse", lambda: _lower_metric(
+        RegressionEvaluator(), "prediction")),
+]
+
+
+@pytest.mark.parametrize(
+    "scope,lower", SCOPES,
+    ids=[f"{scope}-{lower.__name__.strip('_<>')}-{i}"
+         for i, (scope, lower) in enumerate(SCOPES)])
+def test_kernel_scope_is_in_the_lowered_programs_op_names(scope, lower):
+    # op_name reads `jit(f)/tree:hist/dot_general`; under a vmap
+    # `jit(f)/vmap(tree:hist)/dot_general`; inside a nested jit the
+    # callee's own locations start at the scope
+    text = lower().as_text(debug_info=True)
+    assert re.search(rf'["/(]{re.escape(scope)}[/)]', text)
+
+
+# --------------------------------------------------------------------- #
+# the benchmark's readers of these spans                                #
+# --------------------------------------------------------------------- #
+
+def _reader(name):
+    path = os.path.join(ROOT, "benchmark", "layer_metrics", name + ".py")
+    spec = importlib.util.spec_from_file_location(f"timeline_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+PASS_A = {"wall_s": 20.0, "spans": [
+    ("workflow:train", 19.6), ("workflow:materialize", 1.0),
+    ("stage:fit:RealVectorizer", 0.5), ("stage:transform:RealVectorizer", 0.25),
+    ("stage:fit:ModelSelector", 17.0), ("stage:transform:ModelSelector", 0.75),
+    ("selector:prepare", 1.0), ("selector:sweep", 12.0),
+    ("selector:refit", 3.0), ("selector:evaluate", 0.5),
+    ("sweep:family:OpXGBoostClassifier", 12.0), ("sweep:block", 11.0),
+    ("sweep:dispatch:gbt", 6.0), ("sweep:dispatch:gbt", 1.0),
+    ("sweep:dispatch:logistic", 3.0), ("sweep:fetch:gbt", 0.5),
+    ("compile:sweep:dispatch:gbt/jit(chunk_pair)", 4.0),
+    ("compile:sweep:dispatch:logistic/jit(one_cfg)", 2.0),
+    ("compile:selector:refit/jit(fit_gbt)", 2.5),
+    ("compile:sweep:fetch:gbt/jit(<lambda>)", 0.25)]}
+PASS_B = {"wall_s": 10.0, "spans": [
+    ("workflow:train", 9.9), ("workflow:materialize", 1.0),
+    ("stage:fit:ModelSelector", 8.0), ("stage:transform:ModelSelector", 0.5),
+    ("selector:prepare", 1.0), ("selector:sweep", 5.0),
+    ("selector:refit", 1.0), ("selector:evaluate", 0.5),
+    ("sweep:dispatch:gbt", 2.0), ("sweep:dispatch:logistic", 1.0)]}
+# a pass of a program from before these spans existed
+PASS_OLD = {"wall_s": 20.0, "spans": [
+    ("stage:fit:RealVectorizer", 0.5), ("stage:fit:ModelSelector", 17.0),
+    ("sweep:family:OpXGBoostClassifier", 12.0), ("sweep:block", 11.0)]}
+
+READINGS = {
+    # (one pass, mean of two passes)
+    "train_compile_span_s": (8.75, 4.375),
+    "train_sweep_wait_s": (4.0, 3.5),
+    "train_refit_s": (3.0, 2.0),
+    "train_evaluate_s": (0.5, 0.5),
+    # A: (20 - 18.5 - 1) + (17 - 16.5); B: (10 - 8.5 - 1) + (8 - 7.5)
+    "train_unspanned_s": (1.0, 1.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(READINGS))
+def test_layer_metric_reader_on_a_hand_made_window(name):
+    read = _reader(name)
+    one, two = READINGS[name]
+    assert read({"window": {"passes": [PASS_A]}}) == pytest.approx(one)
+    assert read({"window": {"passes": [PASS_A, PASS_B]}}) \
+        == pytest.approx(two)
+    assert read({"window": {"passes": []}}) is None
+    assert read({"window": {}}) is None
+    assert read({"window": {"passes": [PASS_OLD]}}) is None
+
+
+def test_compile_reader_reads_zero_once_the_program_spans_compiles():
+    read = _reader("train_compile_span_s")
+    assert read({"window": {"passes": [PASS_B]}}) == 0.0
+
+
+def test_unspanned_reader_takes_the_traced_window_for_the_traced_pass():
+    read = _reader("train_unspanned_s")
+    # the profiler's start and stop sit in the first pass's host wall
+    # (20 s) and not in the annotated window (19.5 s)
+    obs = {"window": {"passes": [PASS_A, PASS_B]},
+           "trace": {"window_s": 19.5}}
+    assert read(obs) == pytest.approx((0.5 + 1.0) / 2)
+    assert read({"window": {"passes": [PASS_A]}, "trace": None}) \
+        == pytest.approx(1.0)

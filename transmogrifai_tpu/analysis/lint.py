@@ -1362,16 +1362,18 @@ def _check_closure_constants(tree: ast.AST, path: str) -> List[LintFinding]:
 # bare/dotted function names whose FIRST argument is an event name
 _L017_FUNCS = ("record_event", "emit_event", "add_event")
 # method names whose first argument is a span/event name (Tracer.span,
-# Span.event, RequestTrace.child/child_at, RunProfile.phase)
-_L017_METHODS = ("span", "event", "child", "child_at")
+# Tracer.span_at, Span.event, RequestTrace.child/child_at,
+# RunProfile.phase)
+_L017_METHODS = ("span", "span_at", "event", "child", "child_at")
 # bounded-by-construction dynamic name families: the interpolated part
-# is a worker index, run type, retry/ingest site label, or profile
-# phase — closed sets fixed at build time, not wire-derived values.
+# is a worker index, run type, retry/ingest site label, profile phase,
+# model family, or (compile:) another span's name plus the jitted
+# function's — closed sets fixed at build time, not wire-derived values.
 # Everything NEW must either use a literal name (variability goes in
 # attributes) or extend this list with a justified prefix.
 _L017_ALLOW_PREFIXES = (
-    "retry:", "sweep:worker:", "sweep:family:", "ingest:", "run:",
-    "phase:", "stage:",
+    "retry:", "sweep:worker:", "sweep:family:", "sweep:dispatch:",
+    "sweep:fetch:", "compile:", "ingest:", "run:", "phase:", "stage:",
 )
 
 

@@ -54,7 +54,6 @@ class RetraceMonitor:
         self._lock = threading.Lock()
         self._counts: Dict[str, int] = {}
         self._instance: Dict[tuple, int] = {}  # (label, instance) -> traces
-        self._trace_s: Dict[str, float] = {}   # label -> summed trace time
         self.warn_after = warn_after
 
     def record(self, label: str, instance: Optional[int] = None) -> int:
@@ -70,22 +69,6 @@ class RetraceMonitor:
                 "XLA compile; check for per-call shape drift or unstable "
                 "static args (pad batches to a fixed shape)", label, n_inst)
         return n
-
-    def note_trace_s(self, label: str, seconds: float) -> None:
-        """Account measured trace (Python body re-execution) time per
-        label — the honest, directly measurable slice of recompile cost
-        the goodput report can attribute (XLA backend compile time hides
-        behind the first dispatch and is not separable here)."""
-        with self._lock:
-            self._trace_s[label] = self._trace_s.get(label, 0.0) + seconds
-
-    def trace_seconds(self) -> Dict[str, float]:
-        with self._lock:
-            return dict(self._trace_s)
-
-    def total_trace_s(self) -> float:
-        with self._lock:
-            return sum(self._trace_s.values())
 
     def counts(self) -> Dict[str, int]:
         with self._lock:
@@ -128,7 +111,6 @@ class RetraceMonitor:
         with self._lock:
             self._counts.clear()
             self._instance.clear()
-            self._trace_s.clear()
 
     def report(self) -> str:
         counts = self.counts()
@@ -175,7 +157,8 @@ def instrumented_jit(fn: Callable, label: Optional[str] = None,
         # recompile accounting: count the trace, time the body
         # re-execution, and drop a `recompile` event on the current obs
         # span so the unified timeline and the goodput report both see
-        # where compile churn happened
+        # where compile churn happened (the XLA compile that follows is
+        # a `compile:*` span of its own: utils/compile_cache.py)
         import time as _time
 
         from transmogrifai_tpu.obs import trace as _obs_trace
@@ -192,7 +175,6 @@ def instrumented_jit(fn: Callable, label: Optional[str] = None,
             return fn(*args, **kwargs)
         finally:
             dt = _time.perf_counter() - t0
-            mon.note_trace_s(lbl, dt)
             _obs_trace.add_event("recompile", label=lbl, n=n,
                                  trace_s=round(dt, 6))
 

@@ -185,10 +185,14 @@ def make_device_metric(evaluator, n_classes: int | None = None):
         RegressionEvaluator)
 
     metric = evaluator.default_metric
+    # a stable kernel name in the compiled program and the device trace:
+    # `metric:aupr`, `metric:auroc`, `metric:f1`, `metric:rmse`, ...
+    scope = jax.named_scope(f"metric:{metric.lower()}")
 
     if isinstance(evaluator, BinaryClassificationEvaluator):
         threshold = evaluator.threshold
 
+        @scope
         def fn(y, pred, mask):
             s = _binary_scores(pred)
             if metric == "AuPR":
@@ -202,11 +206,13 @@ def make_device_metric(evaluator, n_classes: int | None = None):
         if n_classes is None:
             return None
 
+        @scope
         def fn(y, pred, mask):
             return multiclass_dev(y, pred["prediction"], mask, n_classes)[metric]
         return fn
 
     if isinstance(evaluator, RegressionEvaluator):
+        @scope
         def fn(y, pred, mask):
             return regression_dev(y, pred["prediction"], mask)[metric]
         return fn

@@ -77,8 +77,9 @@ def fit_linreg_enet(X: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
         b0 = jnp.zeros((X.shape[1],), jnp.float32)
     else:  # warm start from existing coefficients (continual refit)
         b0 = jnp.asarray(init_params["beta"], jnp.float32)
-    (beta, _, _), _ = jax.lax.scan(
-        fista_step, (b0, b0, jnp.float32(1.0)), None, length=max_iter)
+    with jax.named_scope("linear:fista"):
+        (beta, _, _), _ = jax.lax.scan(
+            fista_step, (b0, b0, jnp.float32(1.0)), None, length=max_iter)
     return {"beta": beta, "intercept": y_mean - x_mean @ beta}
 
 

@@ -140,8 +140,10 @@ def fit_logreg_enet(X: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray,
     else:  # warm start: FISTA momentum restarts from the given weights
         W0 = jnp.asarray(init_params["W"], jnp.float32)
         b0 = jnp.asarray(init_params["b"], jnp.float32)
-    (W, b, _, _, _), _ = jax.lax.scan(
-        fista_step, (W0, b0, W0, b0, jnp.float32(1.0)), None, length=max_iter)
+    with jax.named_scope("linear:fista"):
+        (W, b, _, _, _), _ = jax.lax.scan(
+            fista_step, (W0, b0, W0, b0, jnp.float32(1.0)), None,
+            length=max_iter)
     return {"W": W, "b": b}
 
 

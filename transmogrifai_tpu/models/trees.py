@@ -60,6 +60,7 @@ def quantile_bin_edges(X: np.ndarray, max_bins: int = DEFAULT_MAX_BINS) -> np.nd
     return np.ascontiguousarray(edges, dtype=np.float32)
 
 
+@jax.named_scope("tree:bin")
 def bin_features(X: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
     """(n, d) int8 bin ids in [0, max_bins) (int32 above 127 bins).
 
@@ -123,6 +124,7 @@ def bins_onehot(Xb: jnp.ndarray, n_bins: int) -> jnp.ndarray:
 HIST_PRECISION = os.environ.get("TRANSMOGRIFAI_HIST_PRECISION", "bf16")
 
 
+@jax.named_scope("tree:hist")
 def _histograms(B, node_idx, G, H, n_nodes: int):
     """hist_G: (m, nodes, d, bins); hist_H: (nodes, d, bins).
 
@@ -166,6 +168,7 @@ def _histograms(B, node_idx, G, H, n_nodes: int):
     return hg, hh
 
 
+@jax.named_scope("tree:split")
 def split_from_histograms(hg, hh, n_bins: int, reg_lambda,
                           min_child_weight, min_gain, min_gain_norm,
                           feature_mask, level: int, active_depth
@@ -268,13 +271,14 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
             min_gain_norm, feature_mask, level, active_depth)
         feats = feats.at[level, :n_nodes].set(bf)
         bins = bins.at[level, :n_nodes].set(bb)
-        if n_nodes <= _ONEHOT_LOOKUP_MAX:
-            sample_feat, split_bin = _table_lookup2(bf, bb, node_idx)
-        else:
-            sample_feat, split_bin = bf[node_idx], bb[node_idx]
-        sample_bin = _select_bin(Xb, sample_feat)
-        go_right = sample_bin > split_bin
-        node_idx = node_idx * 2 + go_right.astype(jnp.int32)
+        with jax.named_scope("tree:route"):
+            if n_nodes <= _ONEHOT_LOOKUP_MAX:
+                sample_feat, split_bin = _table_lookup2(bf, bb, node_idx)
+            else:
+                sample_feat, split_bin = bf[node_idx], bb[node_idx]
+            sample_bin = _select_bin(Xb, sample_feat)
+            go_right = sample_bin > split_bin
+            node_idx = node_idx * 2 + go_right.astype(jnp.int32)
         if subtract and level + 1 < max_depth:
             right = go_right.astype(jnp.float32)
             hg_r, hh_r = _histograms(B, node_idx >> 1, G * right[:, None],
@@ -330,6 +334,7 @@ def _leaf_lookup(col: jnp.ndarray, node: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(oh, col[None, :], 0.0).sum(1)
 
 
+@jax.named_scope("tree:predict")
 def _tree_walk(tree: Dict, Xb: jnp.ndarray, select_fn=None) -> jnp.ndarray:
     """(n,) leaf index for binned samples — the shared routing walk.
     Gather-free at every level up to `_ONEHOT_LOOKUP_MAX`-wide tables.
@@ -431,7 +436,9 @@ def fit_forest(Xb, Y, w, n_trees: int, max_depth: int, n_bins: int,
     def one_tree(key):
         k1, k2 = jax.random.split(key)
         if bootstrap:
-            boot = jax.random.poisson(k1, 1.0, (n,)).astype(jnp.float32) * w
+            with jax.named_scope("tree:bootstrap"):
+                boot = jax.random.poisson(
+                    k1, 1.0, (n,)).astype(jnp.float32) * w
         else:  # deterministic single tree (OpDecisionTree* parity)
             boot = w
         if subsample_features:

@@ -12,7 +12,9 @@ events the rest of the codebase already emits:
   backoff);
 - ``recompile_s``      — time spent re-tracing jitted programs
   (`analysis/retrace.py` emits a ``recompile`` event with the measured
-  trace duration on every jit cache miss);
+  trace duration on every jit cache miss) plus the XLA backend compiles
+  that followed (the ``compile`` spans `utils/compile_cache.py` records
+  from `jax.monitoring`, thread-seconds);
 - ``ingest_wait_s``    — main-thread time blocked on device completion
   tokens during pipelined ingest (the `IngestStats.upload_wait_s`
   attribute on each ingest span);
@@ -259,6 +261,8 @@ def build_report(root: Span, spans: Iterable[Span]) -> GoodputReport:
             elif sp.category == "ingest":
                 b["ingest_wait_s"] += float(
                     sp.attributes.get("upload_wait_s", 0.0) or 0.0)
+            elif sp.category == "compile":
+                b["recompile_s"] += sp.duration_s
         # events count wherever they landed — INCLUDING the root (a
         # sweep invoked directly under the root attaches its
         # journal_resume / oom_redo events there)

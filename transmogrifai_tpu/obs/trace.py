@@ -26,6 +26,13 @@ Design constraints this module answers:
   carries an epoch `start_at` for humans. Export timestamps derive from
   the perf clock against one process epoch, so they are monotonic and
   non-negative by construction.
+- **the profiler's clock**: `Tracer.span` also holds a
+  `jax.profiler.TraceAnnotation` of the same name open for its
+  lifetime, on the thread that opened it. While the JAX profiler
+  records, every program span therefore sits in the trace's host plane
+  beside the device's operations, and an idle gap on the device can be
+  attributed to the program phase open when it began; while it does
+  not record, the annotation costs well under a microsecond.
 - **bounded memory**: finished spans collect in a ring (default 64k);
   a long-lived serving process drops the oldest and counts the drops
   instead of growing without bound.
@@ -62,6 +69,22 @@ _EPOCH_PERF = time.perf_counter()
 _EPOCH_TIME = time.time()
 
 _span_ids = itertools.count(1)
+
+# `jax.profiler.TraceAnnotation`, imported on the first span (this
+# module stays importable without JAX); `contextlib.nullcontext` where
+# the profiler cannot be imported
+_annotation: Optional[Callable[[str], Any]] = None
+
+
+def _profiler_annotation(name: str):
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except Exception:
+            _annotation = contextlib.nullcontext
+    return _annotation(name)
 
 
 def new_run_id() -> str:
@@ -201,6 +224,21 @@ class Span:
                 f"parent={self.parent_id}, {self.duration_s:.4f}s)")
 
 
+def _span_at(name: str, start_s: float, end_s: float, category: str,
+             parent: Optional[Span], trace_id: Optional[str] = None,
+             error: Optional[str] = None,
+             attributes: Optional[Dict[str, Any]] = None) -> Span:
+    """A backdated, already finished span from measured boundaries
+    (perf offsets from `now_s()`), on the calling thread."""
+    sp = Span(name, category=category, parent=parent, trace_id=trace_id,
+              attributes=attributes)
+    sp.start_s = float(start_s)
+    sp.start_at = _EPOCH_TIME + sp.start_s
+    sp.end_s = max(float(end_s), sp.start_s)
+    sp.error = error
+    return sp
+
+
 class Tracer:
     """Process span collector + contextvar-based current-span tracking.
 
@@ -247,7 +285,8 @@ class Tracer:
             self._live[sp.span_id] = sp
         token = self._current.set(sp)
         try:
-            yield sp
+            with _profiler_annotation(name):
+                yield sp
         except BaseException as e:
             sp.error = f"{type(e).__name__}: {e}"
             raise
@@ -260,6 +299,21 @@ class Tracer:
                     self.dropped += 1
                 self._finished.append(sp)
             self._notify(sp)
+
+    def span_at(self, name: str, start_s: float, end_s: float,
+                parent: Optional[Span] = None, category: str = "span",
+                **attributes: Any) -> Span:
+        """Record a backdated, already finished span (boundaries are
+        perf offsets from `now_s()`) under `parent`, or under the
+        calling context's current span: how a duration that is only
+        known once it is over — an XLA compile reported by
+        `jax.monitoring` — lands in the timeline where it happened."""
+        if parent is None:
+            parent = self._current.get()
+        sp = _span_at(name, start_s, end_s, category, parent,
+                      attributes=attributes)
+        self.collect([sp])
+        return sp
 
     def current(self) -> Optional[Span]:
         return self._current.get()
@@ -447,12 +501,9 @@ class RequestTrace:
         """Backdated phase child from measured boundaries (perf offsets
         from `now_s()`): how the scoring thread attributes one batch's
         pad/dispatch/demux wall to every request it carried."""
-        sp = Span(name, category="serving", parent=self.root,
-                  trace_id=self.root.trace_id, attributes=attributes)
-        sp.start_s = float(start_s)
-        sp.start_at = _EPOCH_TIME + sp.start_s
-        sp.end_s = max(float(end_s), sp.start_s)
-        sp.error = error
+        sp = _span_at(name, start_s, end_s, "serving", self.root,
+                      trace_id=self.root.trace_id, error=error,
+                      attributes=attributes)
         self.spans.append(sp)
         return sp
 

@@ -342,7 +342,8 @@ def _sweep_generic(est, grids: List[Dict], X, y, folds, evaluator,
                 clone._bin_cache = bin_cache
             row = []
             for tr, va in folds:
-                with _DispatchSpan():  # visible to tree-family calib timing
+                # visible to tree-family calib timing
+                with _DispatchSpan(type(est).__name__):
                     model = clone.fit_arrays(X, y, jnp.asarray(tr), ctx)
                     pred = model.predict_arrays(X)
                 row.append(_metric(
@@ -395,7 +396,8 @@ def _shard_dyn(dyn: Dict[str, jnp.ndarray],
 
 
 def _run_block(one_cfg: Callable, dyn: Dict[str, jnp.ndarray], sharding,
-               grid_vmap: bool, label: str = "sweep:block"):
+               grid_vmap: bool, label: str = "sweep:block",
+               family: str = "generic"):
     """Execute one grid block: one_cfg(dyn_slice) over the grid axis.
 
     vmap → parallel over grids (sharded across the mesh's sweep axis when
@@ -414,7 +416,7 @@ def _run_block(one_cfg: Callable, dyn: Dict[str, jnp.ndarray], sharding,
     # span-wrapped (even though THIS site never feeds calibration) so a
     # tree family timing a dispatch on another thread sees the overlap —
     # a linear-family execution queues tree dispatches just the same
-    with _DispatchSpan():
+    with _DispatchSpan(family):
         out = jax.block_until_ready(prog(dyn))
     return jax.tree_util.tree_map(lambda a: a[:g], out)  # drop pad rows
 
@@ -511,13 +513,12 @@ def _sweep_blocks(grids: List[Dict], y, W, V, metric_fn, sharding,
                 gs = [p // n_folds for p in ps]
                 fs = [p % n_folds for p in ps]
                 dchunk = {k: v[jnp.asarray(gs)] for k, v in dyn.items()}
-                with _DispatchSpan() as span:
+                with _DispatchSpan(family) as span:
                     t0 = _time.perf_counter()
                     out = jax.block_until_ready(
                         prog(dchunk, W[jnp.asarray(fs)], V[jnp.asarray(fs)]))
                     dt = _time.perf_counter() - t0
-                SWEEP_STATS.record((id(prog), static, width), dt,
-                                   clean=span.clean)
+                SWEEP_STATS.record(dt)
                 if host:
                     out_np = jax.tree_util.tree_map(np.asarray, out)
                     for t in range(min(width, n_pairs - s)):
@@ -542,8 +543,10 @@ def _sweep_blocks(grids: List[Dict], y, W, V, metric_fn, sharding,
                                  "%d -> %d (measured %.1fs)", width, new_w, dt)
                         width = new_w
             if pend:
-                flat = np.asarray(jnp.concatenate(
-                    [jnp.asarray(o, jnp.float32) for _, _, o in pend]))
+                with TRACER.span(f"sweep:fetch:{family}",
+                                 category="sweep_fetch"):
+                    flat = np.asarray(jnp.concatenate(
+                        [jnp.asarray(o, jnp.float32) for _, _, o in pend]))
                 off = 0
                 for s0, w0, _ in pend:
                     for t in range(min(w0, n_pairs - s0)):
@@ -562,7 +565,7 @@ def _sweep_blocks(grids: List[Dict], y, W, V, metric_fn, sharding,
             return jax.vmap(one_fold)(W, V)
 
         gk = _run_block(one_cfg, dyn, sharding, grid_vmap(static, idxs),
-                        label=f"sweep:{family}:{static!r}")
+                        label=f"sweep:{family}:{static!r}", family=family)
         if host:
             pred_np = jax.tree_util.tree_map(np.asarray, gk)
             for row_i, grid_i in enumerate(idxs):
@@ -572,7 +575,9 @@ def _sweep_blocks(grids: List[Dict], y, W, V, metric_fn, sharding,
                             V_np[fold_j])
                     for fold_j in range(V_np.shape[0])]
         else:
-            gk = np.asarray(gk)
+            with TRACER.span(f"sweep:fetch:{family}",
+                             category="sweep_fetch"):
+                gk = np.asarray(gk)
             for row_i, grid_i in enumerate(idxs):
                 metrics[grid_i] = [float(m) for m in gk[row_i]]
 
@@ -852,9 +857,10 @@ _CALIB_LOADED = False
 
 class SweepStats:
     """Per-process dispatch accounting (SURVEY §5.1 'measure instead'):
-    how much of a sweep's wall-clock the device dispatch loop actually
-    occupies, and how much went to first-execution (compile) overhead.
-    `bench.py` resets before a sweep and reports the fractions."""
+    how many timed dispatches the tree families' sweep loops made and
+    the thread-seconds they held (a first dispatch's compile included;
+    the `compile:sweep:dispatch:*` spans say how much of it that was).
+    `bench.py` resets before a sweep and reports the fraction."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -864,42 +870,11 @@ class SweepStats:
         with self._lock:
             self.dispatch_s = 0.0
             self.dispatches = 0
-            # CLEAN = no other dispatch overlapped the measurement; only
-            # clean numbers feed the warm-mean/compile estimate — an
-            # overlapped wall-clock includes another family's queue time
-            # (r4 advisor, medium)
-            self.clean_s = 0.0
-            self.cleans = 0
-            self.first_s = 0.0   # first CLEAN execution of a program shape
-            self.firsts = 0
-            self._seen: set = set()
 
-    def record(self, key, seconds: float, clean: bool = True) -> None:
+    def record(self, seconds: float) -> None:
         with self._lock:
             self.dispatch_s += seconds
             self.dispatches += 1
-            if not clean:
-                # mark seen so a later clean run of the same program is
-                # not miscounted as a first, but keep the contaminated
-                # seconds out of both the first and the warm pools
-                self._seen.add(key)
-                return
-            self.clean_s += seconds
-            self.cleans += 1
-            if key not in self._seen:
-                self._seen.add(key)
-                self.first_s += seconds
-                self.firsts += 1
-
-    def compile_estimate_s(self) -> float:
-        """First-execution seconds minus what those executions would cost
-        warm (estimated from the observed clean warm mean) ≈ compile +
-        cache-lookup overhead. Uses only clean dispatches on both sides."""
-        warm_n = self.cleans - self.firsts
-        if warm_n <= 0:
-            return self.first_s
-        warm_mean = (self.clean_s - self.first_s) / warm_n
-        return max(0.0, self.first_s - warm_mean * self.firsts)
 
 
 SWEEP_STATS = SweepStats()
@@ -922,10 +897,18 @@ _SPAN_STARTS = 0
 
 class _DispatchSpan:
     """Context manager around one timed device dispatch; `.clean` (valid
-    after exit) is True iff no other dispatch overlapped it."""
+    after exit) is True iff no other dispatch overlapped it. For its
+    lifetime it holds a `sweep:dispatch:<family>` span open, so the
+    dispatch — and the XLA compile a first dispatch asks for, as the
+    span's `compile:*` child — sits in the run's timeline."""
+
+    def __init__(self, family: str):
+        self._span = TRACER.span(f"sweep:dispatch:{family}",
+                                 category="sweep_dispatch")
 
     def __enter__(self):
         global _SPAN_ACTIVE, _SPAN_STARTS
+        self._span.__enter__()
         with _SPAN_LOCK:
             _SPAN_ACTIVE += 1
             _SPAN_STARTS += 1
@@ -939,7 +922,7 @@ class _DispatchSpan:
             _SPAN_ACTIVE -= 1
             if _SPAN_STARTS != self._epoch:  # someone started during us
                 self.clean = False
-        return False
+        return bool(self._span.__exit__(*exc))
 
 
 def _calib_path() -> str:
@@ -1366,14 +1349,12 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             done = 0
             while done < n_est:
                 ks = keys_all[done:done + rpd]
-                with _DispatchSpan() as span:
+                with _DispatchSpan("gbt") as span:
                     t0 = _time.perf_counter()
                     margin, best, since = jax.block_until_ready(
                         prog(dchunk, Wsel, Vsel, margin, best, since, ks))
                     dt = _time.perf_counter() - t0
-                SWEEP_STATS.record(
-                    (id(prog), static, width, int(ks.shape[0])), dt,
-                    clean=span.clean)
+                SWEEP_STATS.record(dt)
                 done += int(ks.shape[0])
                 if span.clean:  # overlapped wall-clock never enters calib
                     _record_calib(
@@ -1393,8 +1374,9 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                             V_np[fs[t]])
                     for t in range(width)]
             else:
-                row_metrics = [float(m) for m in
-                               np.asarray(metric_prog(margin, Vsel))]
+                with TRACER.span("sweep:fetch:gbt", category="sweep_fetch"):
+                    row_metrics = [float(m) for m in
+                                   np.asarray(metric_prog(margin, Vsel))]
             for t in range(min(width, n_pairs - s)):
                 row_i, j = divmod(s + t, n_folds)
                 if metrics[idxs[row_i]] is None:

@@ -27,6 +27,7 @@ from transmogrifai_tpu.evaluators import (
     BinaryClassificationEvaluator, MultiClassificationEvaluator,
     RegressionEvaluator)
 from transmogrifai_tpu.models import OpLinearRegression, OpLogisticRegression
+from transmogrifai_tpu.obs.trace import TRACER
 from transmogrifai_tpu.parallel.sweep import run_sweep
 from transmogrifai_tpu.selector.splitters import DataBalancer, DataCutter, DataSplitter
 from transmogrifai_tpu.selector.validators import OpCrossValidation
@@ -116,25 +117,47 @@ class ModelSelector(Estimator):
 
     def fit_model(self, cols: Sequence[Column], ctx: FitContext) -> Transformer:
         label_col, vec_col = cols
-        y_np = np.asarray(label_col.data["value"], dtype=np.float64)
-        X_full = jnp.asarray(vec_col.device_value())
+        # the fit's four phases are sibling spans under the caller's
+        # `stage:fit:*`: prepare, sweep, refit, evaluate
+        with TRACER.span("selector:prepare", category="selector"):
+            y_np = np.asarray(label_col.data["value"], dtype=np.float64)
+            X_full = jnp.asarray(vec_col.device_value())
 
-        # -- data preparation (Splitter.split + preValidationPrepare) ---- #
-        split_summary: Dict[str, Any] = {}
-        if self.splitter is not None:
-            train_idx, test_idx, ssum = self.splitter.split(y_np)
-            train_idx, prep_details = self.splitter.prepare(y_np, train_idx)
-            split_summary = ssum.to_json()
-            split_summary["details"].update(prep_details)
-        else:
-            train_idx = np.arange(len(y_np))
-            test_idx = np.array([], dtype=np.int64)
+            # -- data preparation (Splitter.split + preValidationPrepare) #
+            split_summary: Dict[str, Any] = {}
+            if self.splitter is not None:
+                train_idx, test_idx, ssum = self.splitter.split(y_np)
+                train_idx, prep_details = self.splitter.prepare(
+                    y_np, train_idx)
+                split_summary = ssum.to_json()
+                split_summary["details"].update(prep_details)
+            else:
+                train_idx = np.arange(len(y_np))
+                test_idx = np.array([], dtype=np.int64)
 
-        X = X_full[jnp.asarray(train_idx)]
-        y_train = y_np[train_idx]
-        y_dev = jnp.asarray(y_train.astype(np.float32))
-        folds = self.validator.splits(y_train)
+            X = X_full[jnp.asarray(train_idx)]
+            y_train = y_np[train_idx]
+            y_dev = jnp.asarray(y_train.astype(np.float32))
+            folds = self.validator.splits(y_train)
+            data_digest = (self._data_digest(X, y_dev)
+                           if ctx.cv_refit is None
+                           and self.checkpoint_dir is not None else None)
 
+        with TRACER.span("selector:sweep", category="selector"):
+            results, failures = self._sweep(
+                ctx, X, y_dev, folds, train_idx, data_digest)
+        if not results:
+            raise RuntimeError(
+                f"All {failures} model families failed during validation")
+
+        sign = 1.0 if self.evaluator.is_larger_better else -1.0
+        finite = [r for r in results if np.isfinite(r.mean_metric)]
+        return self._finish(ctx, results, finite, sign, X, X_full, y_np,
+                            y_dev, train_idx, test_idx, split_summary)
+
+    def _sweep(self, ctx, X, y_dev, folds, train_idx, data_digest):
+        """Validate every family's grid over the folds; returns
+        (results, number of families that failed)."""
         # -- the sweep --------------------------------------------------- #
         sharding = None
         use_scheduler = False
@@ -159,20 +182,16 @@ class ModelSelector(Estimator):
         results: List[ValidationResult] = []
         failures = 0
         if ctx.cv_refit is None:
-            data_digest = (self._data_digest(X, y_dev)
-                           if self.checkpoint_dir is not None else None)
-
             # family jobs run on pool threads with no inherited span
             # context: parent each family span explicitly so sweep-block
             # spans nest under the caller's run/stage span
-            from transmogrifai_tpu.obs.trace import TRACER as _TRACER
-            _sweep_parent = _TRACER.current()
+            _sweep_parent = TRACER.current()
 
             def run_family(mi_est_grids):
                 mi, (est, grids) = mi_est_grids
-                with _TRACER.span(f"sweep:family:{type(est).__name__}",
-                                  category="sweep_family",
-                                  parent=_sweep_parent, grids=len(grids)):
+                with TRACER.span(f"sweep:family:{type(est).__name__}",
+                                 category="sweep_family",
+                                 parent=_sweep_parent, grids=len(grids)):
                     sig, ckpt, cached = self._checkpoint_lookup(
                         mi, est, grids, X, data_digest, folds, ctx)
                     if cached is not None:
@@ -235,14 +254,7 @@ class ModelSelector(Estimator):
         else:
             results, failures = self._sweep_with_workflow_cv(
                 ctx, folds, train_idx, y_dev, sharding)
-        if not results:
-            raise RuntimeError(
-                f"All {failures} model families failed during validation")
-
-        sign = 1.0 if self.evaluator.is_larger_better else -1.0
-        finite = [r for r in results if np.isfinite(r.mean_metric)]
-        return self._finish(ctx, results, finite, sign, X, X_full, y_np,
-                            y_dev, train_idx, test_idx, split_summary)
+        return results, failures
 
     def _sweep_scheduled(self, ctx, X, y_dev, folds, data_digest):
         """Distributed sweep: ALL families' grid blocks go into ONE
@@ -537,8 +549,10 @@ class ModelSelector(Estimator):
         kwargs = {k: v for k, v in best_est_proto.params.items() if k != "uid"}
         kwargs.update(best.grid)
         best_est = type(best_est_proto)(**kwargs)
-        model = best_est.fit_arrays(
-            X, y_dev, jnp.ones_like(y_dev), ctx)
+        with TRACER.span("selector:refit", category="selector",
+                         model=best.model):
+            model = best_est.fit_arrays(
+                X, y_dev, jnp.ones_like(y_dev), ctx)
 
         # -- evaluate train + holdout ------------------------------------ #
         def _eval(idx: np.ndarray) -> Dict[str, Any]:
@@ -551,12 +565,15 @@ class ModelSelector(Estimator):
             m = self.evaluator.evaluate(lcol, pcol).to_json()
             return {k: v for k, v in m.items() if not isinstance(v, list)}
 
+        with TRACER.span("selector:evaluate", category="selector"):
+            train_metrics = _eval(train_idx)
+            holdout_metrics = _eval(test_idx)
         summary = ModelSelectorSummary(
             problem_type=self.problem_type,
             metric_name=self.evaluator.default_metric,
             validation_results=results, best_model=best.model,
-            best_grid=best.grid, train_metrics=_eval(train_idx),
-            holdout_metrics=_eval(test_idx), splitter_summary=split_summary,
+            best_grid=best.grid, train_metrics=train_metrics,
+            holdout_metrics=holdout_metrics, splitter_summary=split_summary,
             larger_is_better=self.evaluator.is_larger_better)
         model.summary = summary
         return model

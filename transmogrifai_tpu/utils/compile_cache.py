@@ -1,4 +1,6 @@
-"""Persistent XLA compilation cache: the one place its directory is chosen.
+"""The `device` layer's compile accounting: the persistent XLA
+compilation cache (the one place its directory is chosen) and the
+process's `jax.monitoring` listeners (the one place they are registered).
 
 A fresh process pays every sweep/scoring program's XLA compile; JAX's
 persistent compilation cache makes every run after the first start warm.
@@ -20,13 +22,22 @@ later process can find.
 
 Called by bench.py, __graft_entry__, the WorkflowRunner/CLI, the serving
 layer and the test suite's conftest.
+
+Every XLA compile is also a span where it happened
+(`register_compile_listeners`): JAX reports a backend compile's duration
+on the thread that asked for it, so the listener backdates a
+`compile:<current span>/<fun_name>` span under that thread's current
+`obs.trace` span — a family's sweep dispatch, the winner's refit, a
+feature stage — and `COMPILE_STATS` keeps the process totals.
 """
 
 from __future__ import annotations
 
 import logging
 import os
+import threading
 
+from transmogrifai_tpu.obs.trace import TRACER, now_s
 from transmogrifai_tpu.store.config import resolve_dir
 
 log = logging.getLogger(__name__)
@@ -34,6 +45,56 @@ log = logging.getLogger(__name__)
 ENV_JAX_CACHE = "JAX_COMPILATION_CACHE_DIR"
 
 _dir: str | None = None  # this process's cache directory, once chosen
+
+# Process totals from JAX's own monitoring events: compile requests that
+# consulted the persistent cache, how many it answered, and the seconds
+# the backend spent on the rest (summed over the compiling threads).
+# Readers diff two copies around the interval they care about.
+COMPILE_STATS = {"requests": 0, "cache_hits": 0, "backend_compile_s": 0.0}
+_stats_lock = threading.Lock()
+_listening = False
+
+
+def _on_event(event: str, **_) -> None:
+    if event == "/jax/compilation_cache/compile_requests_use_cache":
+        with _stats_lock:
+            COMPILE_STATS["requests"] += 1
+    elif event == "/jax/compilation_cache/cache_hits":
+        with _stats_lock:
+            COMPILE_STATS["cache_hits"] += 1
+        sp = TRACER.current()
+        if sp is not None:
+            sp.event("compile_cache_hit")
+
+
+def _on_duration(event: str, duration_secs: float, **kw) -> None:
+    if event != "/jax/core/compile/backend_compile_duration":
+        return
+    with _stats_lock:
+        COMPILE_STATS["backend_compile_s"] += duration_secs
+    # called on the compiling thread as the compile returns: the span
+    # ends now and started `duration_secs` ago, under whatever program
+    # span that thread has open. The owner is in the NAME because the
+    # benchmark's readers see (name, duration) pairs only.
+    owner = TRACER.current()
+    end = now_s()
+    TRACER.span_at(
+        f"compile:{owner.name if owner is not None else '-'}"
+        f"/{kw.get('fun_name', '?')}",
+        end - duration_secs, end, parent=owner, category="compile")
+
+
+def register_compile_listeners() -> None:
+    """Register this module's `jax.monitoring` listeners, once per
+    process (JAX keeps every registration for the process's life)."""
+    global _listening
+    with _stats_lock:
+        if _listening:
+            return
+        _listening = True
+    from jax import monitoring
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def enable_compile_cache(min_compile_s: float = 0.5) -> str | None:
@@ -50,6 +111,7 @@ def enable_compile_cache(min_compile_s: float = 0.5) -> str | None:
     global _dir
     import jax
 
+    register_compile_listeners()
     if _dir is None:
         path = os.environ.get(ENV_JAX_CACHE)
         if path:

@@ -2,10 +2,10 @@
 
 Reference parity: `utils/.../spark/OpSparkListener.scala:62-141` (per-phase
 metrics, app duration, custom tags) and `OpStep.scala:35-45` (phase names).
-Here phases are wall-clock scopes; under jax the scope also opens a named
-TraceAnnotation so device traces line up with framework phases when the
-jax profiler is active, and an `obs.trace` span so the phase lands in the
-run's unified timeline (Perfetto export, goodput rollup).
+Here phases are wall-clock scopes; each also opens an `obs.trace` span, so
+the phase lands in the run's unified timeline (Perfetto export, goodput
+rollup) and — the span carries a profiler annotation of its own — beside
+the device's operations whenever the jax profiler records.
 
 Clocks: durations come from `time.perf_counter()` — a wall-clock step
 (NTP, suspend) must not corrupt a measured interval — while `started_at`
@@ -78,23 +78,17 @@ class RunProfile:
 
     @contextlib.contextmanager
     def phase(self, name: str, **extra):
-        """Time a named phase; nests with the jax profiler when tracing
-        and opens an `obs.trace` span in the run's timeline.
+        """Time a named phase and open an `obs.trace` span for it in the
+        run's timeline (and, through the span, in a profiler trace).
 
         A body that raises still records its phase — with an ``error``
         extra naming the exception — and re-raises: a failed run's
         profile must show WHERE the time went before the failure, not
         silently drop the phase that died."""
-        try:
-            import jax.profiler
-            annotation = jax.profiler.TraceAnnotation(name)
-        except Exception:  # profiler unavailable: plain timing
-            annotation = contextlib.nullcontext()
         extra = dict(extra)
         t0 = time.perf_counter()
         try:
-            with TRACER.span(f"phase:{name}", category="phase", **extra), \
-                    annotation:
+            with TRACER.span(f"phase:{name}", category="phase", **extra):
                 yield
         except BaseException as e:  # incl. injected kills/preemptions
             extra["error"] = f"{type(e).__name__}: {e}"

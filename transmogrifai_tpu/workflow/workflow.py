@@ -164,8 +164,14 @@ class Workflow:
         to every scheduler/sweep/ingest decision under this train."""
         from transmogrifai_tpu.data.feature_cache import cache_scope
         from transmogrifai_tpu.perf.params import params_scope
+        from transmogrifai_tpu.utils.compile_cache import (
+            register_compile_listeners)
+        register_compile_listeners()  # every XLA compile below is a span
+        # a child of the caller's current span, so a runner's `run:train`
+        # still roots the trace
         with cache_scope(self.parameters.get("feature_cache")), \
-                params_scope(self.parameters.get("perf_model")):
+                params_scope(self.parameters.get("perf_model")), \
+                TRACER.span("workflow:train", category="workflow"):
             return self._train_impl(dataset, seed, mesh, strict)
 
     def _train_impl(self, dataset: Optional[Dataset], seed: int,
@@ -206,10 +212,12 @@ class Workflow:
         columns: Dict[str, Column] = {}
         fitted: Dict[str, Transformer] = {}
 
-        for gen in layers[0] if layers else []:
-            if not isinstance(gen, FeatureGeneratorStage):
-                raise TypeError(f"Layer-0 stage {gen!r} is not a feature generator")
-            columns[gen.get_output().uid] = gen.materialize(ds)
+        with TRACER.span("workflow:materialize", category="workflow"):
+            for gen in layers[0] if layers else []:
+                if not isinstance(gen, FeatureGeneratorStage):
+                    raise TypeError(
+                        f"Layer-0 stage {gen!r} is not a feature generator")
+                columns[gen.get_output().uid] = gen.materialize(ds)
 
         n_fits = 0
         for li, layer in enumerate(layers[1:], start=1):
