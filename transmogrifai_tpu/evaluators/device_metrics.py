@@ -176,22 +176,37 @@ def _binary_scores(pred: dict) -> jnp.ndarray:
     return pred["prediction"]
 
 
-def make_device_metric(evaluator, n_classes: int | None = None):
-    """metric_fn(y, pred_dict, val_mask) -> scalar for the sweep program, or
-    None when `evaluator` has no device kernel (LambdaEvaluator etc. fall
-    back to the host path in parallel/sweep.py)."""
+def device_metric_key(evaluator, n_classes: int | None = None):
+    """Hashable description of `evaluator`'s device kernel — `(kind,
+    metric, threshold or n_classes)` — or None when it has none
+    (LambdaEvaluator etc. fall back to the host path in
+    parallel/sweep.py). Everything a metric kernel depends on besides
+    its arguments is in the key, so a sweep program built from the key
+    can be held and reused for any evaluator that maps to it."""
     from transmogrifai_tpu.evaluators.evaluators import (
         BinaryClassificationEvaluator, MultiClassificationEvaluator,
         RegressionEvaluator)
 
     metric = evaluator.default_metric
+    if isinstance(evaluator, BinaryClassificationEvaluator):
+        return ("binary", metric, float(evaluator.threshold))
+    if isinstance(evaluator, MultiClassificationEvaluator):
+        return (None if n_classes is None
+                else ("multiclass", metric, int(n_classes)))
+    if isinstance(evaluator, RegressionEvaluator):
+        return ("regression", metric, None)
+    return None
+
+
+def device_metric(key):
+    """metric_fn(y, pred_dict, val_mask) -> scalar for the sweep program,
+    from a `device_metric_key`; the function carries its key as `.key`."""
+    kind, metric, param = key
     # a stable kernel name in the compiled program and the device trace:
     # `metric:aupr`, `metric:auroc`, `metric:f1`, `metric:rmse`, ...
     scope = jax.named_scope(f"metric:{metric.lower()}")
 
-    if isinstance(evaluator, BinaryClassificationEvaluator):
-        threshold = evaluator.threshold
-
+    if kind == "binary":
         @scope
         def fn(y, pred, mask):
             s = _binary_scores(pred)
@@ -199,22 +214,21 @@ def make_device_metric(evaluator, n_classes: int | None = None):
                 return aupr_dev(y, s, mask)
             if metric == "AuROC":
                 return auroc_dev(y, s, mask)
-            return binary_confusion_dev(y, s, mask, threshold)[metric]
-        return fn
-
-    if isinstance(evaluator, MultiClassificationEvaluator):
-        if n_classes is None:
-            return None
-
+            return binary_confusion_dev(y, s, mask, param)[metric]
+    elif kind == "multiclass":
         @scope
         def fn(y, pred, mask):
-            return multiclass_dev(y, pred["prediction"], mask, n_classes)[metric]
-        return fn
-
-    if isinstance(evaluator, RegressionEvaluator):
+            return multiclass_dev(y, pred["prediction"], mask, param)[metric]
+    else:
         @scope
         def fn(y, pred, mask):
             return regression_dev(y, pred["prediction"], mask)[metric]
-        return fn
+    fn.key = key
+    return fn
 
-    return None
+
+def make_device_metric(evaluator, n_classes: int | None = None):
+    """`device_metric` of the evaluator's key, or None when `evaluator`
+    has no device kernel."""
+    key = device_metric_key(evaluator, n_classes)
+    return None if key is None else device_metric(key)

@@ -4,6 +4,10 @@ process's `jax.monitoring` listeners (the one place they are registered).
 
 A fresh process pays every sweep/scoring program's XLA compile; JAX's
 persistent compilation cache makes every run after the first start warm.
+That holds for the sweep too: its programs take the dataset as jit
+arguments (`parallel/sweep.py`), so a module's hash depends on shapes,
+dtypes and static hyper-parameters only — a new table of the same shape
+is the same entry, and no entry carries a dataset's bytes.
 The cache directory is part of each entry's key, so it must not move:
 
 - `JAX_COMPILATION_CACHE_DIR` set: JAX already has its directory. This
