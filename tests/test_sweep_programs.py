@@ -35,8 +35,13 @@ def _table(kind: str, seed: int):
     """One of two different tables of one shape (per `seed`)."""
     rng = np.random.default_rng(seed)
     X = rng.normal(size=(N, D)).astype(np.float32)
+    if kind == "typed":
+        # half the columns 0/1 indicators, at other positions per seed:
+        # the same two block widths, so the same programs
+        ind = rng.permutation(D)[:D // 2]
+        X[:, ind] = X[:, ind] > 0.3
     z = X @ rng.normal(size=D)
-    if kind == "binary":
+    if kind in ("binary", "typed"):
         y = (rng.uniform(size=N) < 1 / (1 + np.exp(-z))).astype(np.float32)
     elif kind == "multiclass":
         y = np.digitize(z + rng.normal(size=N) * 0.3,
@@ -100,6 +105,13 @@ CASES = {
         lambda: OpXGBoostClassifier(n_estimators=2, max_bins=8,
                                     early_stopping_rounds=0),
         [{"max_depth": 2}], "multiclass", MULTI),
+    "forest-classifier-typed": (
+        lambda: OpRandomForestClassifier(n_trees=3, max_bins=8),
+        [{"max_depth": 2}, {"max_depth": 3}], "typed", BINARY),
+    "boosted-binary-chunked-typed": (
+        lambda: OpXGBoostClassifier(n_estimators=3, max_bins=8,
+                                    early_stopping_rounds=0),
+        [{"max_depth": 2}, {"max_depth": 3}], "typed", BINARY),
     "lambda-evaluator-logistic": (
         lambda: OpLogisticRegression(max_iter=8),
         [{"reg_param": 0.001}, {"reg_param": 0.1}], "binary", LAMBDA),
@@ -118,6 +130,10 @@ CASES = {
         lambda: OpXGBoostClassifier(n_estimators=2, max_bins=8,
                                     early_stopping_rounds=0),
         [{"max_depth": 2}, {"max_depth": 3}], "binary", BINARY),
+    "mesh-boosted-binary-typed": (
+        lambda: OpXGBoostClassifier(n_estimators=2, max_bins=8,
+                                    early_stopping_rounds=0),
+        [{"max_depth": 2}, {"max_depth": 3}], "typed", BINARY),
 }
 
 

@@ -316,6 +316,81 @@ def test_refit_and_evaluate_compiles_are_named_for_their_phase(train_spans):
 
 
 # --------------------------------------------------------------------- #
+# D2. a typed table: the pivot's and the checker's phases, the counters #
+# --------------------------------------------------------------------- #
+
+@pytest.fixture(scope="module")
+def typed_spans():
+    """One tiny train over integers with holes and picklists, through
+    the checker: (root, spans)."""
+    from transmogrifai_tpu import types as T
+    from transmogrifai_tpu.automl.sanity_checker import SanityChecker
+    rng = np.random.default_rng(2)
+    n = 300
+    y = (rng.uniform(size=n) < 0.4).astype(np.float64)
+    count = np.floor(np.exp(rng.normal(1.0, 1.0, n))) + 3 * y
+    count[rng.uniform(size=n) < 0.2] = np.nan
+    level = np.asarray(["a", "b", "c", "d"], object)[
+        rng.choice(4, size=n, p=[0.5, 0.3, 0.15, 0.05])]
+    level[rng.uniform(size=n) < 0.1] = None
+    other = np.asarray(["u", "v"], object)[(rng.uniform(size=n) < 0.5) * 1]
+    ds = Dataset({"count": count, "level": level, "other": other, "y": y},
+                 {"count": T.Integral, "level": T.PickList,
+                  "other": T.PickList, "y": T.Integral})
+    preds, label = FeatureBuilder.from_dataset(ds, response="y")
+    checked = SanityChecker().set_input(
+        label, transmogrify(preds)).get_output()
+    sel = BinaryClassificationModelSelector.with_cross_validation(
+        models=[FAMILIES[f] for f in ("forest", "gbt")],
+        n_folds=2, splitter=DataSplitter(reserve_test_fraction=0.2))
+    pf = sel.set_input(label, checked).get_output()
+    with TRACER.span("run:train-typed", new_trace=True) as root:
+        Workflow().set_result_features(pf, label) \
+            .set_input_dataset(ds).train()
+    return root, TRACER.trace_spans(root.trace_id)
+
+
+def test_checker_phases_are_siblings_in_order_under_its_stage(typed_spans):
+    _, spans = typed_spans
+    stage, = [s for s in spans if s.name == "stage:fit:SanityChecker"]
+    phases = [s for s in _children(spans, stage)
+              if s.name.startswith("sanity:")]
+    assert [s.name for s in phases] == [
+        "sanity:moments", "sanity:corr", "sanity:contingency",
+        "sanity:decide"]
+    assert sum(s.duration_s for s in phases) <= stage.duration_s
+    decide = phases[-1]
+    # count + null, 4 + OTHER + null, 2 + OTHER + null; the never-set
+    # columns (two OTHERs, one null) go, and the second of the
+    # two-level column's levels (the first one's complement)
+    assert decide.attributes["encoded_width"] == 2 + 6 + 4
+    assert decide.attributes["selected_width"] == 8
+    assert phases[2].attributes["groups"] == 3
+
+
+def test_pivot_spans_sit_under_the_pivots_stage_spans(typed_spans):
+    _, spans = typed_spans
+    fit, = [s for s in spans if s.name == "pivot:fit"]
+    by_id = {s.span_id: s for s in spans}
+    assert by_id[fit.parent_id].name == "stage:fit:OneHotVectorizer"
+    assert fit.attributes["columns"] == 2
+    encodes = [s for s in spans if s.name == "pivot:encode"]
+    assert encodes and all(
+        by_id[s.parent_id].name == "stage:transform:OneHotVectorizer"
+        for s in encodes)
+    assert encodes[0].attributes["cells"] == 2 * 300
+
+
+def test_binning_is_a_span_with_the_operands_slots(typed_spans):
+    _, spans = typed_spans
+    bins = [s for s in spans if s.name == "sweep:bin"]
+    assert len(bins) == 1       # one family bins, the other finds it done
+    # 8 kept columns: the mode-filled count is wide, 7 are indicators
+    assert {s.attributes["hist_slots"] for s in bins} == {8 + 2 * 7}
+    assert {s.attributes["max_bins"] for s in bins} == {8}
+
+
+# --------------------------------------------------------------------- #
 # E. stable kernel names                                                #
 # --------------------------------------------------------------------- #
 
@@ -337,6 +412,13 @@ def _lower_forest():
     Xb, G, H = _tree_inputs()
     return trees.fit_forest.lower(Xb, G, H, n_trees=2, max_depth=2,
                                   n_bins=4, n_outputs=1, seed=0)
+
+
+def _lower_grow_tree_two_blocks():
+    Xb, G, H = _tree_inputs()
+    layout = trees.hist_layout(np.asarray([False, True, True]))
+    return jax.jit(lambda a, g, h, lay: trees.grow_tree(
+        a, g, h, 2, 4, layout=lay)).lower(Xb, G, H, layout)
 
 
 def _lower_bin():
@@ -376,6 +458,10 @@ SCOPES = [
     ("tree:hist", _lower_grow_tree), ("tree:split", _lower_grow_tree),
     ("tree:route", _lower_grow_tree), ("tree:bootstrap", _lower_forest),
     ("tree:hist", _lower_forest), ("tree:bin", _lower_bin),
+    ("tree:hist:wide", _lower_grow_tree),
+    ("tree:hist:wide", _lower_grow_tree_two_blocks),
+    ("tree:hist:ind", _lower_grow_tree_two_blocks),
+    ("tree:split", _lower_grow_tree_two_blocks),
     ("tree:predict", _lower_predict), ("linear:fista", _lower_logreg),
     ("linear:fista", _lower_linreg),
     ("metric:aupr", lambda: _lower_metric(
@@ -434,6 +520,70 @@ PASS_B = {"wall_s": 10.0, "spans": [
 PASS_OLD = {"wall_s": 20.0, "spans": [
     ("stage:fit:RealVectorizer", 0.5), ("stage:fit:ModelSelector", 17.0),
     ("sweep:family:OpXGBoostClassifier", 12.0), ("sweep:block", 11.0)]}
+
+# a typed pass: the pivot's and the checker's spans, and the typed
+# driver's counters
+PASS_T = {"wall_s": 30.0, "spans": [
+    ("stage:fit:OneHotVectorizer", 6.5), ("pivot:fit", 6.0),
+    ("stage:transform:OneHotVectorizer", 4.5), ("pivot:encode", 4.0),
+    ("stage:fit:SanityChecker", 9.0), ("sanity:moments", 3.0),
+    ("sanity:corr", 1.0), ("sanity:contingency", 4.0),
+    ("sanity:decide", 0.5)],
+    "counters": {"encoded_width": 548, "selected_width": 528,
+                 "hist_slots": 1446, "pivot_cells": 26000000}}
+PASS_U = {"wall_s": 20.0, "spans": [
+    ("pivot:fit", 5.0), ("pivot:encode", 3.0), ("sanity:moments", 2.0),
+    ("sanity:corr", 1.0), ("sanity:contingency", 3.0),
+    ("sanity:decide", 0.5)],
+    "counters": {"encoded_width": 546, "selected_width": 526,
+                 "hist_slots": 1442, "pivot_cells": 26000000}}
+TYPED_READINGS = {
+    "train_pivot_s": (10.0, 9.0),
+    "train_sanity_s": (8.5, 7.5),
+    "train_encoded_width": (548, 547),
+    "train_hist_slots": (1446, 1444),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TYPED_READINGS))
+def test_typed_layer_metric_reader_on_a_hand_made_window(name):
+    read = _reader(name)
+    one, two = TYPED_READINGS[name]
+    assert read({"window": {"passes": [PASS_T]}}) == pytest.approx(one)
+    assert read({"window": {"passes": [PASS_T, PASS_U]}}) \
+        == pytest.approx(two)
+    assert read({"window": {"passes": []}}) is None
+    assert read({"window": {}}) is None
+    # a program from before these spans and counters, and a pass of the
+    # untyped driver (no `counters`), give nothing and do not raise
+    assert read({"window": {"passes": [PASS_OLD]}}) is None
+    assert read({"window": {"passes": [PASS_T, PASS_A]}}) is None
+
+
+@pytest.mark.parametrize("name", ["train_typed_mfu_pct",
+                                  "train_typed_busy_mfu_pct"])
+def test_typed_share_of_the_peak_reads_on_the_chip_only(name, monkeypatch):
+    import json
+    import sys
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "benchmark"))
+    sys.modules.pop("work_typed", None)
+    read = _reader(name)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "criteo.json")) as fh:
+        config = json.load(fh)
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as fh:
+        peaks = json.load(fh)["TPU v5 lite"]
+    obs = {"window": {"passes": [PASS_T], "rows": 1_000_000},
+           "config": config, "peaks": None,
+           "trace": {"n_ops": 5, "busy_s": 12.0}}
+    assert read(obs) is None                         # off the chip
+    share = read(dict(obs, peaks=peaks))
+    # 1.6e12 bytes at 819 GB/s = 1.95 s of a 30 s pass / of 12 s busy
+    least = 1598777600000.0 / 819e9
+    assert share == pytest.approx(
+        100 * least / (30.0 if name == "train_typed_mfu_pct" else 12.0))
+    assert 0 < share < 100
+
 
 READINGS = {
     # (one pass, mean of two passes)

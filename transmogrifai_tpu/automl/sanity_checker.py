@@ -33,6 +33,7 @@ log = logging.getLogger(__name__)
 from transmogrifai_tpu import types as T
 from transmogrifai_tpu.data.columns import Column
 from transmogrifai_tpu.data.metadata import VectorMetadata
+from transmogrifai_tpu.obs.trace import TRACER
 from transmogrifai_tpu.stages.base import Estimator, FitContext, Transformer
 
 # reference defaults (SanityChecker.scala:561-578)
@@ -116,8 +117,10 @@ class SanityCheckerSummary:
         }
 
 
+@jax.jit
 def _column_reductions(X: jnp.ndarray, y: Optional[jnp.ndarray] = None):
-    """One fused pass: per-column moments (+ label terms when y given —
+    """One fused pass (one program: op by op, `X * X` is a buffer the
+    size of X): per-column moments (+ label terms when y given —
     correlations now come from the `_corr_matrix` Gram pass, so the
     checker calls this with y=None).
 
@@ -378,67 +381,77 @@ class SanityChecker(Estimator):
         return np.sort(rng.choice(n, size=target, replace=False))
 
     def fit_model(self, cols: Sequence[Column], ctx: FitContext) -> Transformer:
+        # four phases, each a span under the stage's `stage:fit:*`:
+        # moments (the host pull of the vector, the sample, raw moments),
+        # corr (the Gram pass), contingency (categorical groups against
+        # the label), decide (the drop rules)
         label_col, vec_col = cols
-        y_np = np.asarray(label_col.data["value"], dtype=np.float64)
-        X_np = np.asarray(vec_col.device_value())
-        n_total = X_np.shape[0]
+        with TRACER.span("sanity:moments", category="sanity"):
+            y_np = np.asarray(label_col.data["value"], dtype=np.float64)
+            X_np = np.asarray(vec_col.device_value())
+            n_total = X_np.shape[0]
 
-        sample_idx = self._sample_rows(n_total)
-        if sample_idx is not None:
-            X_np = X_np[sample_idx]
-            y_np = y_np[sample_idx]
-        n, d = X_np.shape
+            sample_idx = self._sample_rows(n_total)
+            if sample_idx is not None:
+                X_np = X_np[sample_idx]
+                y_np = y_np[sample_idx]
+            n, d = X_np.shape
 
-        # Spearman = Pearson over average-tie ranks (host rank transform
-        # feeding the identical device passes); `Cx/cy` are the correlation
-        # inputs, raw-X moments are reported in the stats either way
-        spearman = self.correlation_type == "spearman"
-        X_dev = jnp.asarray(X_np)
-        if spearman:
-            Cx = jnp.asarray(_rank_transform(X_np))
-            cy = jnp.asarray(_rank_transform(y_np[:, None])[:, 0])
-        else:
-            Cx = X_dev
-            cy = jnp.asarray(y_np.astype(np.float32))
+            # Spearman = Pearson over average-tie ranks (host rank
+            # transform feeding the identical device passes); `Cx/cy` are
+            # the correlation inputs, raw-X moments are reported in the
+            # stats either way
+            spearman = self.correlation_type == "spearman"
+            # unsampled, the vector is on the device already: no second copy
+            X_dev = jnp.asarray(vec_col.device_value()
+                                if sample_idx is None else X_np)
+            if spearman:
+                Cx = jnp.asarray(_rank_transform(X_np))
+                cy = jnp.asarray(_rank_transform(y_np[:, None])[:, 0])
+            else:
+                Cx = X_dev
+                cy = jnp.asarray(y_np.astype(np.float32))
 
-        need_ff = self.max_feature_corr < 1.0
-        if need_ff:  # corr comes from the Gram pass; only raw moments here
-            red = {k: np.asarray(v)
-                   for k, v in _column_reductions(X_dev).items()}
-        else:        # label terms ride the same single reduction pass
-            redc = {k: np.asarray(v)
-                    for k, v in _column_reductions(Cx, cy).items()}
-            red = ({k: np.asarray(v)
-                    for k, v in _column_reductions(X_dev).items()}
-                   if spearman else redc)
-        mean = red["sx"] / max(n, 1)
-        var = (red["sxx"] - n * mean ** 2) / max(n - 1, 1)
-        var = np.maximum(var, 0.0)
+            need_ff = self.max_feature_corr < 1.0
+            if need_ff:  # corr comes from the Gram pass; raw moments here
+                red = {k: np.asarray(v)
+                       for k, v in _column_reductions(X_dev).items()}
+            else:        # label terms ride the same single reduction pass
+                redc = {k: np.asarray(v)
+                        for k, v in _column_reductions(Cx, cy).items()}
+                red = ({k: np.asarray(v)
+                        for k, v in _column_reductions(X_dev).items()}
+                       if spearman else redc)
+            mean = red["sx"] / max(n, 1)
+            var = (red["sxx"] - n * mean ** 2) / max(n - 1, 1)
+            var = np.maximum(var, 0.0)
         hit_pairs: Dict[int, List[Tuple[int, float]]] = {}
-        if need_ff and d > _WIDE_D:
-            # wide-X: blocked Gram — label corr + sparse duplicate pairs,
-            # no (d, d) materialization (SURVEY.md §5.7)
-            corr, hit_pairs = _corr_label_and_hits_blocked(
-                Cx, cy, self.max_feature_corr)
-            feat_corr = None
-        elif need_ff:
-            # full corr matrix of [X | y]: ONE Gram matmul on the MXU
-            corr_all = _corr_matrix(jnp.concatenate([Cx, cy[:, None]], 1))
-            corr = corr_all[:d, d]
-            feat_corr = corr_all[:d, :d]
-        else:
-            # duplicates check disabled → O(n·d) label terms suffice
-            cmean = redc["sx"] / max(n, 1)
-            cvar = np.maximum(
-                (redc["sxx"] - n * cmean ** 2) / max(n - 1, 1), 0.0)
-            y_mean = redc["sy"] / max(n, 1)
-            y_var = max(
-                (redc["syy"] - n * y_mean ** 2) / max(n - 1, 1), 0.0)
-            cov = (redc["sxy"] - n * cmean * y_mean) / max(n - 1, 1)
-            denom = np.sqrt(cvar * y_var)
-            with np.errstate(divide="ignore", invalid="ignore"):
-                corr = np.where(denom > 0, cov / denom, 0.0)
-            feat_corr = None
+        with TRACER.span("sanity:corr", category="sanity"):
+            if need_ff and d > _WIDE_D:
+                # wide-X: blocked Gram — label corr + sparse duplicate
+                # pairs, no (d, d) materialization (SURVEY.md §5.7)
+                corr, hit_pairs = _corr_label_and_hits_blocked(
+                    Cx, cy, self.max_feature_corr)
+                feat_corr = None
+            elif need_ff:
+                # full corr matrix of [X | y]: ONE Gram matmul on the MXU
+                corr_all = _corr_matrix(
+                    jnp.concatenate([Cx, cy[:, None]], 1))
+                corr = corr_all[:d, d]
+                feat_corr = corr_all[:d, :d]
+            else:
+                # duplicates check disabled → O(n·d) label terms suffice
+                cmean = redc["sx"] / max(n, 1)
+                cvar = np.maximum(
+                    (redc["sxx"] - n * cmean ** 2) / max(n - 1, 1), 0.0)
+                y_mean = redc["sy"] / max(n, 1)
+                y_var = max(
+                    (redc["syy"] - n * y_mean ** 2) / max(n - 1, 1), 0.0)
+                cov = (redc["sxy"] - n * cmean * y_mean) / max(n - 1, 1)
+                denom = np.sqrt(cvar * y_var)
+                with np.errstate(divide="ignore", invalid="ignore"):
+                    corr = np.where(denom > 0, cov / denom, 0.0)
+                feat_corr = None
 
         meta = vec_col.meta
         names = (meta.column_names() if meta is not None
@@ -447,14 +460,16 @@ class SanityChecker(Estimator):
         # categorical groups → contingency stats vs a categorical label
         group_stats: Dict[int, Tuple[str, Dict]] = {}
         cat_groups: List[CategoricalGroupStats] = []
-        if meta is not None:
-            oh = _label_onehot(y_np, self.categorical_label_max_card,
-                               force=self.categorical_label)
+        with TRACER.span("sanity:contingency", category="sanity") as sp:
+            oh = None if meta is None else _label_onehot(
+                y_np, self.categorical_label_max_card,
+                force=self.categorical_label)
             if oh is not None:
                 groups: Dict[str, List[int]] = {}
                 for i, c in enumerate(meta.columns):
                     if c.indicator_value is not None:
                         groups.setdefault(c.grouping_key(), []).append(i)
+                sp.set(groups=len(groups))
                 Xh = X_np  # the sampled host matrix (no device round-trip)
                 for key, idxs in groups.items():
                     cont = Xh[:, idxs].T.astype(np.float64) @ oh
@@ -472,6 +487,28 @@ class SanityChecker(Estimator):
                             "conf": cs["max_confidences"][li],
                             "support": cs["supports"][li]})
 
+        with TRACER.span("sanity:decide", category="sanity",
+                         encoded_width=d) as sp:
+            kept, stats = self._decide(
+                var, mean, red, corr, feat_corr, hit_pairs, group_stats,
+                names)
+            sp.set(selected_width=len(kept))
+
+        kept_set = set(kept)
+        summary = SanityCheckerSummary(
+            n_rows=n, stats=stats, kept_indices=kept,
+            dropped_indices=[i for i in range(d) if i not in kept_set],
+            correlation_type=self.correlation_type,
+            sample_fraction=n / max(n_total, 1),
+            categorical_stats=cat_groups)
+        sel_meta = meta.select(kept) if meta is not None else None
+        return SanityCheckerModel(kept, meta=sel_meta, summary=summary.to_json())
+
+    def _decide(self, var, mean, red, corr, feat_corr, hit_pairs,
+                group_stats, names) -> Tuple[List[int], List[ColumnStats]]:
+        """The drop rules over the gathered statistics: (kept column
+        indices, every column's stats with its drop reasons)."""
+        d = len(names)
         # feature-feature duplicates: vectorized candidate pairs, then the
         # "later column drops" scan ("dropping the later features",
         # DerivedFeatureFilterUtils:376). The wide path already produced
@@ -527,16 +564,7 @@ class SanityChecker(Estimator):
             kept = list(range(d))
             for s in stats:
                 s.dropped.append("retained: all columns flagged")
-
-        kept_set = set(kept)
-        summary = SanityCheckerSummary(
-            n_rows=n, stats=stats, kept_indices=kept,
-            dropped_indices=[i for i in range(d) if i not in kept_set],
-            correlation_type=self.correlation_type,
-            sample_fraction=n / max(n_total, 1),
-            categorical_stats=cat_groups)
-        sel_meta = meta.select(kept) if meta is not None else None
-        return SanityCheckerModel(kept, meta=sel_meta, summary=summary.to_json())
+        return kept, stats
 
 
 class MinVarianceFilterModel(SanityCheckerModel):

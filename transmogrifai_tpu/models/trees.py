@@ -53,16 +53,76 @@ DEFAULT_MAX_BINS = 32
 # binning                                                                     #
 # --------------------------------------------------------------------------- #
 
-def quantile_bin_edges(X: np.ndarray, max_bins: int = DEFAULT_MAX_BINS) -> np.ndarray:
-    """(d, max_bins-1) ascending bin edges per feature (host, fit-time)."""
-    qs = np.linspace(0, 1, max_bins + 1)[1:-1]
-    edges = np.quantile(np.asarray(X, dtype=np.float64), qs, axis=0).T
-    return np.ascontiguousarray(edges, dtype=np.float32)
+@jax.jit
+def _all_zero_or_one(X):
+    return jnp.all((X == 0) | (X == 1), axis=0)
 
 
+def indicator_columns(X) -> np.ndarray:
+    """(d,) bool: the columns whose every value is 0 or 1 — pivot levels,
+    null indicators, a hand-built 0/1 vector alike. Read off the data
+    (one reduction, on the device when X lives there), never off
+    metadata."""
+    if isinstance(X, jax.Array):
+        return np.asarray(_all_zero_or_one(X))
+    X = np.asarray(X)
+    return np.all((X == 0) | (X == 1), axis=0)
+
+
+def quantile_bin_edges(X, max_bins: int = DEFAULT_MAX_BINS,
+                       indicator: Optional[np.ndarray] = None) -> np.ndarray:
+    """(d, max_bins-1) ascending bin edges per feature (host, fit-time).
+
+    An indicator column (`indicator_columns`) has the one threshold 0.5
+    in every position, so it bins to 0 or max_bins-1 whatever share of
+    its rows is set (a quantile edge cannot tell a level rarer than
+    1/max_bins from a constant). Quantiles are taken over the OTHER
+    columns only, and only those cross to the host: on a pivoted table
+    that is a few columns of hundreds."""
+    if indicator is None:
+        indicator = indicator_columns(X)
+    d = int(X.shape[1])
+    edges = np.full((d, max_bins - 1), 0.5, dtype=np.float32)
+    wide = np.flatnonzero(~indicator)
+    if wide.size:
+        qs = np.linspace(0, 1, max_bins + 1)[1:-1]
+        Xw = np.asarray(X if wide.size == d else X[:, wide],
+                        dtype=np.float64)
+        edges[wide] = np.quantile(Xw, qs, axis=0).T
+    return edges
+
+
+def hist_layout(indicator: np.ndarray) -> Optional[Dict[str, jnp.ndarray]]:
+    """Where the histogram operand's two blocks take their columns from:
+    {"wide": (d_wide,), "ind": (d_ind,)} ascending int32 positions in the
+    binned matrix, or None when no column is an indicator (one block,
+    the whole matrix). The widths are shapes of a compiled program, the
+    positions its arguments: tables with the same two counts share it."""
+    indicator = np.asarray(indicator, bool)
+    if not indicator.any():
+        return None
+    return {"wide": jnp.asarray(np.flatnonzero(~indicator), jnp.int32),
+            "ind": jnp.asarray(np.flatnonzero(indicator), jnp.int32)}
+
+
+def hist_slots(d: int, n_bins: int, layout: Optional[Dict]) -> int:
+    """Histogram operand slots a row: `n_bins` a wide column, 2 an
+    indicator column."""
+    if layout is None:
+        return int(d) * int(n_bins)
+    return (int(layout["wide"].shape[0]) * int(n_bins)
+            + 2 * int(layout["ind"].shape[0]))
+
+
+@jax.jit
 @jax.named_scope("tree:bin")
 def bin_features(X: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
     """(n, d) int8 bin ids in [0, max_bins) (int32 above 127 bins).
+
+    One program, so the compare and its sum fuse: called op by op (as
+    the sweep's and the refit's binning did), the (n, d, max_bins-1)
+    compare is a buffer of its own — 16 GB for a million rows of 528
+    columns.
 
     Broadcast-compare + sum (== searchsorted side="right") instead of an
     actual per-column searchsorted: binary-search gathers serialize on TPU
@@ -70,9 +130,8 @@ def bin_features(X: jnp.ndarray, edges: jnp.ndarray) -> jnp.ndarray:
     int8 storage quarters the HBM slab the predict walk re-reads every
     level (the big-data path stages int8 too)."""
     b = (X[:, :, None] >= edges[None, :, :]).sum(-1, dtype=jnp.int32)
-    if edges.shape[-1] + 1 <= 127:
-        return b.astype(jnp.int8)
-    return b
+    n_bins = edges.shape[-1] + 1        # a shape: static under the jit
+    return b.astype(jnp.int8) if n_bins <= 127 else b
 
 
 def _select_bin(Xb: jnp.ndarray, feat_idx: jnp.ndarray) -> jnp.ndarray:
@@ -91,9 +150,10 @@ def _select_bin(Xb: jnp.ndarray, feat_idx: jnp.ndarray) -> jnp.ndarray:
 # --------------------------------------------------------------------------- #
 
 def bins_onehot(Xb: jnp.ndarray, n_bins: int) -> jnp.ndarray:
-    """(n, d, bins) bf16 one-hot of the binned matrix — the histogram
-    reduction operand, built ONCE per training matrix and reused across
-    every level, tree, round, fold, and grid config. The 0/1 operand is
+    """(n, d, bins) bf16 one-hot of a binned block — one block of the
+    histogram reduction operand (`hist_operand`), built ONCE per fit
+    program and reused across every level, tree and round it grows (and
+    every fold and grid config vmapped over it). The 0/1 operand is
     exact in bf16; the OTHER matmul operand (gradient/hessian values in
     `_histograms`) is bf16-quantized to ~0.4% relative error — a
     deliberate precision/throughput tradeoff (full MXU rate, f32
@@ -101,6 +161,31 @@ def bins_onehot(Xb: jnp.ndarray, n_bins: int) -> jnp.ndarray:
     scatter-add histogram, which changes individual trees but not metric
     quality (split ties are statistically arbitrary anyway)."""
     return jax.nn.one_hot(Xb, n_bins, dtype=jnp.bfloat16)
+
+
+def hist_operand(Xb: jnp.ndarray, n_bins: int,
+                 layout: Optional[Dict] = None) -> List[Tuple]:
+    """The histogram operand as dense blocks [(name, cols, B)], each
+    sized by its columns' OWN number of bins: "wide" (n, d_wide, n_bins)
+    over `layout["wide"]` and "ind" (n, d_ind, 2) over `layout["ind"]`,
+    whose rows sit in bin 0 or above it (`quantile_bin_edges`). The
+    slots a uniform (n, d, n_bins) operand spends on an indicator
+    column's 30 empty bins carry no mass, so the blocks grow the same
+    trees. `cols` is None for the one block of `layout` None (no
+    indicator column: the whole matrix, no gather); a block with no
+    column is left out."""
+    if layout is None:
+        return [("wide", None, bins_onehot(Xb, n_bins))]
+    blocks = []
+    for name in ("wide", "ind"):
+        cols = layout[name]
+        if not cols.shape[0]:
+            continue
+        xb = jnp.take(Xb, cols, axis=1)
+        if name == "ind":           # bin 0, or above it
+            xb, n_bins = (xb > 0).astype(jnp.int8), 2
+        blocks.append((name, cols, bins_onehot(xb, n_bins)))
+    return blocks
 
 
 # Histogram precision (VERDICT r3 #8 — an explicit, documented choice):
@@ -125,8 +210,9 @@ HIST_PRECISION = os.environ.get("TRANSMOGRIFAI_HIST_PRECISION", "bf16")
 
 
 @jax.named_scope("tree:hist")
-def _histograms(B, node_idx, G, H, n_nodes: int):
-    """hist_G: (m, nodes, d, bins); hist_H: (nodes, d, bins).
+def _histograms(B, node_idx, G, H, n_nodes: int, block: str = "wide"):
+    """hist_G: (m, nodes, d, bins); hist_H: (nodes, d, bins) of one block
+    of the operand (`hist_operand`), under the scope `tree:hist:<block>`.
 
     One-hot MATMUL histograms: hist[node, f, b] = Σ_r A[r,node]·B[r,f,b]·v[r]
     computed as (nodes, n) @ (n, d·bins) on the MXU — where the FLOPs live
@@ -137,10 +223,12 @@ def _histograms(B, node_idx, G, H, n_nodes: int):
     the Rabit-allreduce analogue (SURVEY.md §2.9).
 
     Per-value-column matmuls (B read m+1 times) measure FASTER here than
-    stacking [G, H] into one ((m+1)·nodes, n) operand: at in-core shapes
-    (d ≈ 55) the A-side (n, (m+1)·nodes) materialization costs more than
-    the saved B reads — the OPPOSITE tradeoff from the out-of-core path
-    (d=500, B per-chunk rebuilt), where `parallel/bigdata.py` stacks.
+    stacking [G, H] into one ((m+1)·nodes, n) operand: at the in-core
+    shape that was timed (d ≈ 55 columns of 32 bins, 1,760 slots a row;
+    a 13 + 515-column typed table is 1,446) the A-side (n, (m+1)·nodes)
+    materialization costs more than the saved B reads — the OPPOSITE
+    tradeoff from the out-of-core path (d=500, B per-chunk rebuilt),
+    where `parallel/bigdata.py` stacks.
 
     Value precision is governed by HIST_PRECISION (see above)."""
     n, d, nb = B.shape
@@ -163,20 +251,17 @@ def _histograms(B, node_idx, G, H, n_nodes: int):
             out = jnp.matmul(Ag.T, Bf, preferred_element_type=jnp.float32)
         return out.reshape(n_nodes, d, nb)
 
-    hh = red(H)
-    hg = jnp.stack([red(G[:, c]) for c in range(m)])
+    with jax.named_scope(f"tree:hist:{block}"):
+        hh = red(H)
+        hg = jnp.stack([red(G[:, c]) for c in range(m)])
     return hg, hh
 
 
-@jax.named_scope("tree:split")
-def split_from_histograms(hg, hh, n_bins: int, reg_lambda,
-                          min_child_weight, min_gain, min_gain_norm,
-                          feature_mask, level: int, active_depth
-                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-node best (feature, bin) from (m, nodes, d, bins) gradient and
-    (nodes, d, bins) weight histograms — shared by the in-core level loop
-    and the chunked big-data path (`parallel/bigdata.py`)."""
-    n_nodes = hh.shape[0]
+def _best_split(hg, hh, reg_lambda, min_child_weight, feature_mask):
+    """Per node of one block: (gain, column, bin) of its best split — the
+    FIRST maximum in (column, bin) order — and the node's total weight as
+    the block's column 0 sums it."""
+    n_nodes, _, n_bins = hh.shape
     cg = jnp.cumsum(hg, axis=-1)          # left sums at split-bin b
     ch = jnp.cumsum(hh, axis=-1)          # (nodes, d, bins)
     tg = cg[..., -1:]
@@ -190,8 +275,14 @@ def split_from_histograms(hg, hh, n_bins: int, reg_lambda,
     flat = gain.reshape(n_nodes, -1)      # (nodes, d*bins)
     best = jnp.argmax(flat, axis=1)
     best_gain = jnp.take_along_axis(flat, best[:, None], 1)[:, 0]
-    bf = (best // n_bins).astype(jnp.int32)
-    bb = (best % n_bins).astype(jnp.int32)
+    return (best_gain, (best // n_bins).astype(jnp.int32),
+            (best % n_bins).astype(jnp.int32), th[:, 0, 0])
+
+
+def _split_or_not(best_gain, bb, total, n_bins: int, min_gain,
+                  min_gain_norm, level: int, active_depth):
+    """The split bin, or `n_bins` ("no split") where the best gain does
+    not clear the thresholds or the level is past `active_depth`."""
     # a node with no usable gain "splits" at bin >= n_bins-1 → all left.
     # Two threshold scales coexist: `min_gain` compares raw (XGBoost
     # gamma), while `min_gain_norm` scales by the node's total weight —
@@ -200,11 +291,58 @@ def split_from_histograms(hg, hh, n_bins: int, reg_lambda,
     # impurity improvement, so the normalized threshold is EXACTLY
     # MLlib's minInfoGain scale ({0.001, 0.01, 0.1} in
     # DefaultSelectorParams.scala:39). Both may be traced grid values.
-    splits = best_gain > jnp.maximum(min_gain, min_gain_norm * th[:, 0, 0])
+    splits = best_gain > jnp.maximum(min_gain, min_gain_norm * total)
     if active_depth is not None:
         splits = splits & (level < active_depth)
-    bb = jnp.where(splits, bb, n_bins)
-    return bf, bb
+    return jnp.where(splits, bb, n_bins)
+
+
+@jax.named_scope("tree:split")
+def split_from_histograms(hg, hh, n_bins: int, reg_lambda,
+                          min_child_weight, min_gain, min_gain_norm,
+                          feature_mask, level: int, active_depth
+                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Per-node best (feature, bin) from (m, nodes, d, bins) gradient and
+    (nodes, d, bins) weight histograms — shared by the in-core level loop
+    and the chunked big-data path (`parallel/bigdata.py`)."""
+    best_gain, bf, bb, total = _best_split(
+        hg, hh, reg_lambda, min_child_weight, feature_mask)
+    return bf, _split_or_not(best_gain, bb, total, n_bins, min_gain,
+                             min_gain_norm, level, active_depth)
+
+
+@jax.named_scope("tree:split")
+def _split_from_blocks(B, hists, n_bins: int, reg_lambda, min_child_weight,
+                       min_gain, min_gain_norm, feature_mask, level: int,
+                       active_depth) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """`split_from_histograms` over the operand's blocks: each block's
+    best split, the better one taken in the order one argmax over the
+    whole (column, bin) table has — higher gain, then lower column of
+    the combined vector, then lower bin — and the node's weight from
+    the block that holds column 0, so that the two-block layout grows
+    the uniform layout's tree split for split."""
+    best = None
+    for (_, cols, _), (hg, hh) in zip(B, hists):
+        mask = feature_mask
+        if mask is not None and cols is not None:
+            mask = mask[cols]
+        gain, bf, bb, total = _best_split(
+            hg, hh, reg_lambda, min_child_weight, mask)
+        first = 0                   # the block's first column, combined
+        if cols is not None:        # (None: the uniform layout's one block)
+            bf, first = cols[bf], cols[0]
+        if best is None:
+            best = (gain, bf, bb, total, first)
+            continue
+        g0, f0, b0, t0, first0 = best
+        take = (gain > g0) | ((gain == g0) & (bf < f0))
+        best = (jnp.where(take, gain, g0), jnp.where(take, bf, f0),
+                jnp.where(take, bb, b0),
+                jnp.where(first < first0, total, t0),
+                jnp.minimum(first, first0))
+    gain, bf, bb, total, _ = best
+    return bf, _split_or_not(gain, bb, total, n_bins, min_gain,
+                             min_gain_norm, level, active_depth)
 
 
 # Depth at which sibling subtraction starts paying (see grow_tree doc)
@@ -216,8 +354,8 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
               min_child_weight: float = 1.0, min_gain: float = 0.0,
               feature_mask: Optional[jnp.ndarray] = None,
               active_depth=None, alpha: float = 0.0,
-              B: Optional[jnp.ndarray] = None,
-              min_gain_norm=0.0) -> Dict:
+              B: Optional[List[Tuple]] = None,
+              min_gain_norm=0.0, layout: Optional[Dict] = None) -> Dict:
     """Grow one fixed-depth tree. Returns dense arrays:
 
     {"feat": (depth, 2^depth) int32, "bin": (depth, 2^depth) int32,
@@ -229,6 +367,9 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
     the padded tree predicts exactly like a tree grown to that depth — this
     lets the sweep engine vmap a {max_depth: 3, 6, 12} grid in ONE compiled
     program padded to 12 instead of one compile per depth.
+
+    `B`: the histogram operand (`hist_operand`), built here from `layout`
+    when the caller does not share one across trees or rounds.
 
     Deep trees (max_depth ≥ `_SUBTRACT_MIN_DEPTH`) in EXACT-histogram
     mode (TRANSMOGRIFAI_HIST_PRECISION=f32) use HISTOGRAM SUBTRACTION —
@@ -257,17 +398,22 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
     feats = jnp.zeros((max_depth, max_nodes), jnp.int32)
     bins = jnp.full((max_depth, max_nodes), n_bins, jnp.int32)  # n_bins = "no split"
     if B is None:
-        B = bins_onehot(Xb, n_bins)
+        B = hist_operand(Xb, n_bins, layout)
+
+    def histograms(node, Gv, Hv, n_nodes):
+        return [_histograms(Bk, node, Gv, Hv, n_nodes, name)
+                for name, _, Bk in B]
+
     subtract = max_depth >= _SUBTRACT_MIN_DEPTH and HIST_PRECISION == "f32"
     if subtract:
-        hg, hh = _histograms(B, node_idx, G, H, 1)
+        hists = histograms(node_idx, G, H, 1)
 
     for level in range(max_depth):
         n_nodes = 2 ** level
         if not subtract:
-            hg, hh = _histograms(B, node_idx, G, H, n_nodes)
-        bf, bb = split_from_histograms(
-            hg, hh, n_bins, reg_lambda, min_child_weight, min_gain,
+            hists = histograms(node_idx, G, H, n_nodes)
+        bf, bb = _split_from_blocks(
+            B, hists, n_bins, reg_lambda, min_child_weight, min_gain,
             min_gain_norm, feature_mask, level, active_depth)
         feats = feats.at[level, :n_nodes].set(bf)
         bins = bins.at[level, :n_nodes].set(bb)
@@ -281,14 +427,15 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
             node_idx = node_idx * 2 + go_right.astype(jnp.int32)
         if subtract and level + 1 < max_depth:
             right = go_right.astype(jnp.float32)
-            hg_r, hh_r = _histograms(B, node_idx >> 1, G * right[:, None],
-                                     H * right, n_nodes)
             # interleave children: node k → (left 2k = parent − right,
             # right 2k+1)
-            hg = jnp.stack([hg - hg_r, hg_r], axis=2).reshape(
-                m, 2 * n_nodes, d, n_bins)
-            hh = jnp.stack([hh - hh_r, hh_r], axis=1).reshape(
-                2 * n_nodes, d, n_bins)
+            hists = [
+                (jnp.stack([hg - hg_r, hg_r], axis=2).reshape(
+                    m, 2 * n_nodes, *hg.shape[2:]),
+                 jnp.stack([hh - hh_r, hh_r], axis=1).reshape(
+                     2 * n_nodes, *hh.shape[1:]))
+                for (hg, hh), (hg_r, hh_r) in zip(hists, histograms(
+                    node_idx >> 1, G * right[:, None], H * right, n_nodes))]
 
     leaf_g = jnp.zeros((max_nodes, m), G.dtype).at[node_idx].add(G)
     leaf_h = jnp.zeros((max_nodes,), H.dtype).at[node_idx].add(H)
@@ -427,11 +574,11 @@ def fit_forest(Xb, Y, w, n_trees: int, max_depth: int, n_bins: int,
                n_outputs: int, seed, subsample_features: bool = True,
                min_child_weight: float = 1.0, active_depth=None,
                bootstrap: bool = True, tree_budget_divisor: int = 1,
-               min_gain=0.0):
+               min_gain=0.0, layout: Optional[Dict] = None):
     n, d = Xb.shape
     keys = jax.random.split(jax.random.PRNGKey(seed), n_trees)
     n_sub = max(int(np.sqrt(d)), 1) if subsample_features else d
-    B = bins_onehot(Xb, n_bins)  # shared across all trees
+    B = hist_operand(Xb, n_bins, layout)  # shared across all trees
 
     def one_tree(key):
         k1, k2 = jax.random.split(key)
@@ -592,7 +739,8 @@ def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
               max_depth: int, n_bins: int, learning_rate, reg_lambda,
               objective: str, min_child_weight, active_depth, gamma, alpha,
               subsample, colsample, early_stopping_rounds: int,
-              min_gain_norm=0.0, eval_metric: str = "logloss"):
+              min_gain_norm=0.0, eval_metric: str = "logloss",
+              layout: Optional[Dict] = None):
     """Shared traced boosting loop. Carry = (margin, best_val, since);
     with `early_stopping_rounds` > 0, a round whose start state has
     `since >= early_stopping_rounds` grows a ZEROED tree (leaf *= 0), so
@@ -602,7 +750,7 @@ def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
     metric hasn't improved for N rounds,
     `XGBoostParams.scala numEarlyStoppingRounds`)."""
     n, d = Xb.shape
-    B = bins_onehot(Xb, n_bins)  # shared across all boosting rounds
+    B = hist_operand(Xb, n_bins, layout)  # shared across all rounds
     esr = int(early_stopping_rounds)
 
     def grads(margin):
@@ -648,7 +796,7 @@ def fit_gbt(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
             min_child_weight: float = 1.0, active_depth=None,
             gamma=0.0, alpha=0.0, subsample=1.0, colsample=1.0, seed=0,
             val_w=None, early_stopping_rounds: int = 0, min_gain_norm=0.0,
-            eval_metric: str = "logloss"):
+            eval_metric: str = "logloss", layout: Optional[Dict] = None):
     """Returns (trees, final_margin): the scan carry already holds the full
     training-matrix margin, so sweep callers need not re-walk the forest.
 
@@ -666,7 +814,8 @@ def fit_gbt(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
         Xb, y, w, val_w, jnp.zeros(n, jnp.float32), jnp.float32(jnp.inf),
         jnp.int32(0), keys, max_depth, n_bins, learning_rate, reg_lambda,
         objective, min_child_weight, active_depth, gamma, alpha, subsample,
-        colsample, early_stopping_rounds, min_gain_norm, eval_metric)
+        colsample, early_stopping_rounds, min_gain_norm, eval_metric,
+        layout)
     return trees, margin
 
 
@@ -678,7 +827,8 @@ def fit_gbt_chunk(Xb, y, w, val_w, margin, best, since, keys,
                   learning_rate, reg_lambda, objective: str,
                   min_child_weight, active_depth, gamma, alpha,
                   subsample, colsample, early_stopping_rounds: int,
-                  min_gain_norm=0.0, eval_metric: str = "logloss"):
+                  min_gain_norm=0.0, eval_metric: str = "logloss",
+                  layout: Optional[Dict] = None):
     """One host-dispatched chunk of boosting rounds carrying the
     early-stopping state. The sweep engine calls this per
     `rounds_per_dispatch` slice of the key array and stops dispatching
@@ -690,7 +840,7 @@ def fit_gbt_chunk(Xb, y, w, val_w, margin, best, since, keys,
                      max_depth, n_bins, learning_rate, reg_lambda, objective,
                      min_child_weight, active_depth, gamma, alpha,
                      subsample, colsample, early_stopping_rounds,
-                     min_gain_norm, eval_metric)
+                     min_gain_norm, eval_metric, layout)
 
 
 def _pick_rounds_per_dispatch(n_estimators: int, ideal: int) -> int:
@@ -702,16 +852,17 @@ def _pick_rounds_per_dispatch(n_estimators: int, ideal: int) -> int:
     return best if best * 2 >= ideal else ideal
 
 
-def _default_rounds_per_dispatch(n: int, d: int, n_estimators: int,
-                                 max_depth: int, n_bins: int) -> int:
+def _default_rounds_per_dispatch(n: int, slots: int, n_estimators: int,
+                                 max_depth: int) -> int:
     """Rounds between early-stopping checks: the unit budget targets a
     few seconds per dispatch at ~1e-12 s/unit on 90k×55×32×2^10 (a
     guess that predates PR 21's first direct chip run; not re-measured).
     Chunked dispatch is what lets early stopping SKIP the remaining
     dispatches. PR 21's chip run neither needed nor contradicted it: no
     early stop fired in the default sweep (all 200 rounds ran), so what
-    it buys is unmeasured (ROADMAP, unre-measured defaults)."""
-    unit = n * (2 ** min(max_depth, 14)) * d * n_bins
+    it buys is unmeasured (ROADMAP, unre-measured defaults). `slots` is
+    the histogram operand's width a row (`hist_slots`)."""
+    unit = n * (2 ** min(max_depth, 14)) * slots
     return _pick_rounds_per_dispatch(
         n_estimators, max(1, int(2.5e13 // max(unit, 1))))
 
@@ -722,7 +873,8 @@ def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
                    subsample=1.0, colsample=1.0, seed=0, val_w=None,
                    early_stopping_rounds: int = 0,
                    rounds_per_dispatch: Optional[int] = None,
-                   min_gain_norm=0.0, eval_metric: str = "logloss"):
+                   min_gain_norm=0.0, eval_metric: str = "logloss",
+                   layout: Optional[Dict] = None):
     """Host-chunked boosting: bitwise-identical trees/margin to `fit_gbt`
     (same key stream, same scan body) but dispatched `rounds_per_dispatch`
     rounds at a time so early stopping SKIPS the remaining dispatches
@@ -734,7 +886,7 @@ def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
         val_w = jnp.zeros(n, jnp.float32)
     if rounds_per_dispatch is None:
         rounds_per_dispatch = _default_rounds_per_dispatch(
-            n, d, n_estimators, max_depth, n_bins)
+            n, hist_slots(d, n_bins, layout), n_estimators, max_depth)
     keys = jax.random.split(jax.random.PRNGKey(seed), n_estimators)
     margin = jnp.zeros(n, jnp.float32)
     best = jnp.float32(jnp.inf)
@@ -747,7 +899,7 @@ def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
             Xb, y, w, val_w, margin, best, since, ks, int(ks.shape[0]),
             max_depth, n_bins, learning_rate, reg_lambda, objective,
             min_child_weight, None, gamma, alpha, subsample, colsample, esr,
-            min_gain_norm, eval_metric)
+            min_gain_norm, eval_metric, layout)
         chunks.append(trees)
         done += int(ks.shape[0])
         if esr and int(since) >= esr:
@@ -763,14 +915,14 @@ def fit_gbt_multiclass(Xb, y, w, n_estimators: int, max_depth: int,
                        reg_lambda, min_child_weight: float = 1.0,
                        active_depth=None, gamma=0.0, alpha=0.0,
                        subsample=1.0, colsample=1.0, seed=0,
-                       min_gain_norm=0.0):
+                       min_gain_norm=0.0, layout: Optional[Dict] = None):
     """Softmax boosting: K one-vs-rest trees per round grown from the
     multinomial gradients (the reference's XGBoost multi:softprob —
     OpXGBoostClassifier.scala:47 supports multiclass; the r1 facade was
     binary-only). Returns (trees with (T, K, ...) leaves, (n, K) margin)."""
     n, d = Xb.shape
     Y = jax.nn.one_hot(y.astype(jnp.int32), n_classes)
-    B = bins_onehot(Xb, n_bins)  # shared across rounds and classes
+    B = hist_operand(Xb, n_bins, layout)  # shared across rounds and classes
 
     def round_(margin, key):
         k1, k2 = jax.random.split(key)
@@ -1076,21 +1228,26 @@ class GBTMulticlassModel(GBTClassificationModel):
 
 
 class _TreeEstimatorBase(PredictorEstimator):
-    # Optional shared binning cache (max_bins → (edges, Xb)) used by the
+    # Optional shared binning cache (max_bins → (edges, Xb, layout)) used by the
     # sweep engine's HOST-loop fallback (`parallel/sweep.py:_sweep_generic`)
     # so repeated grid×fold fits bin the training matrix once. The batched
     # sweep path keeps its own per-family cache (`parallel/sweep.py:_binned`).
     _bin_cache: Optional[Dict] = None
 
     def _edges_binned(self, X, ctx):
+        """(edges, binned matrix, histogram layout) of a training matrix;
+        the matrix stays where it is (on the device in a workflow's
+        refit) but for its non-indicator columns' quantiles."""
         cache = self._bin_cache
         if cache is not None and self.max_bins in cache:
             return cache[self.max_bins]
-        edges = quantile_bin_edges(np.asarray(X), self.max_bins)
-        Xb = bin_features(jnp.asarray(X), jnp.asarray(edges))
+        indicator = indicator_columns(X)
+        edges = quantile_bin_edges(X, self.max_bins, indicator)
+        out = (edges, bin_features(jnp.asarray(X), jnp.asarray(edges)),
+               hist_layout(indicator))
         if cache is not None:
-            cache[self.max_bins] = (edges, Xb)
-        return edges, Xb
+            cache[self.max_bins] = out
+        return out
 
 
 class OpRandomForestClassifier(_TreeEstimatorBase):
@@ -1134,12 +1291,13 @@ class OpRandomForestClassifier(_TreeEstimatorBase):
                                       classification=True)
             return ForestClassificationModel(
                 np.asarray(warm["edges"], np.float32), trees)
-        edges, Xb = self._edges_binned(X, ctx)
+        edges, Xb, layout = self._edges_binned(X, ctx)
         Y = jax.nn.one_hot(y.astype(jnp.int32), k)
         trees = fit_forest(Xb, Y, w, self.n_trees, self.max_depth,
                            self.max_bins, k, ctx.seed,
                            self.subsample_features, self._effective_mcw(),
-                           min_gain=jnp.float32(self.min_info_gain))
+                           min_gain=jnp.float32(self.min_info_gain),
+                           layout=layout)
         return ForestClassificationModel(edges, {k2: np.asarray(v)
                                                  for k2, v in trees.items()})
 
@@ -1153,11 +1311,12 @@ class OpRandomForestRegressor(OpRandomForestClassifier):
                                       classification=False)
             return ForestRegressionModel(
                 np.asarray(warm["edges"], np.float32), trees)
-        edges, Xb = self._edges_binned(X, ctx)
+        edges, Xb, layout = self._edges_binned(X, ctx)
         trees = fit_forest(Xb, y[:, None], w, self.n_trees, self.max_depth,
                            self.max_bins, 1, ctx.seed,
                            self.subsample_features, self._effective_mcw(),
-                           min_gain=jnp.float32(self.min_info_gain))
+                           min_gain=jnp.float32(self.min_info_gain),
+                           layout=layout)
         return ForestRegressionModel(edges, {k: np.asarray(v)
                                              for k, v in trees.items()})
 
@@ -1183,12 +1342,13 @@ class OpDecisionTreeClassifier(OpRandomForestClassifier):
 
     def fit_arrays(self, X, y, w, ctx: FitContext):
         k = self.n_classes or infer_n_classes(np.asarray(y))
-        edges, Xb = self._edges_binned(X, ctx)
+        edges, Xb, layout = self._edges_binned(X, ctx)
         Y = jax.nn.one_hot(y.astype(jnp.int32), k)
         tree = grow_tree(Xb, Y * w[:, None], w, self.max_depth, self.max_bins,
                          reg_lambda=1e-6,
                          min_child_weight=self._effective_mcw(),
-                         min_gain_norm=jnp.float32(self.min_info_gain))
+                         min_gain_norm=jnp.float32(self.min_info_gain),
+                         layout=layout)
         trees = jax.tree.map(lambda a: a[None], tree)  # (1, ...) forest shape
         return ForestClassificationModel(edges, {k2: np.asarray(v)
                                                  for k2, v in trees.items()})
@@ -1210,11 +1370,12 @@ class OpDecisionTreeRegressor(OpRandomForestRegressor):
                        "min_instances_per_node": min_instances_per_node}
 
     def fit_arrays(self, X, y, w, ctx: FitContext):
-        edges, Xb = self._edges_binned(X, ctx)
+        edges, Xb, layout = self._edges_binned(X, ctx)
         tree = grow_tree(Xb, (y * w)[:, None], w, self.max_depth, self.max_bins,
                          reg_lambda=1e-6,
                          min_child_weight=self._effective_mcw(),
-                         min_gain_norm=jnp.float32(self.min_info_gain))
+                         min_gain_norm=jnp.float32(self.min_info_gain),
+                         layout=layout)
         trees = jax.tree.map(lambda a: a[None], tree)
         return ForestRegressionModel(edges, {k: np.asarray(v)
                                              for k, v in trees.items()})
@@ -1305,7 +1466,7 @@ class OpGBTClassifier(_TreeEstimatorBase):
                 return self._model_cls(
                     np.asarray(warm["edges"], np.float32), trees,
                     float(warm.get("learning_rate", self.learning_rate)))
-        edges, Xb = self._edges_binned(X, ctx)
+        edges, Xb, layout = self._edges_binned(X, ctx)
         seed = ctx.seed if ctx is not None else 0
         if self._objective == "logistic" and k > 2:
             trees, _ = fit_gbt_multiclass(
@@ -1315,7 +1476,7 @@ class OpGBTClassifier(_TreeEstimatorBase):
                 gamma=jnp.float32(self.gamma), alpha=jnp.float32(self.alpha),
                 subsample=jnp.float32(self.subsample),
                 colsample=jnp.float32(self.colsample_bytree), seed=seed,
-                min_gain_norm=jnp.float32(self.min_info_gain))
+                min_gain_norm=jnp.float32(self.min_info_gain), layout=layout)
             return GBTMulticlassModel(
                 edges, {k2: np.asarray(v) for k2, v in trees.items()},
                 self.learning_rate)
@@ -1343,7 +1504,7 @@ class OpGBTClassifier(_TreeEstimatorBase):
                 colsample=jnp.float32(self.colsample_bytree),
                 seed=seed, val_w=hold * w, early_stopping_rounds=esr,
                 min_gain_norm=jnp.float32(self.min_info_gain),
-                eval_metric=self.eval_metric)
+                eval_metric=self.eval_metric, layout=layout)
             # stopped rounds grow ZEROED trees, so the probe's stopping
             # round is the LAST live tree's index + 1 — counting live
             # trees instead would undercount when a mid-sequence tree is
@@ -1358,8 +1519,9 @@ class OpGBTClassifier(_TreeEstimatorBase):
             # rounds match XGBoost's default of
             # predicting with post-best-iteration trees included
             rpd = _default_rounds_per_dispatch(
-                Xb.shape[0], Xb.shape[1], self.n_estimators,
-                self.max_depth, self.max_bins)
+                Xb.shape[0],
+                hist_slots(Xb.shape[1], self.max_bins, layout),
+                self.n_estimators, self.max_depth)
             n_rounds = min(-(-n_live // rpd) * rpd, self.n_estimators)
             rpd_refit = rpd
         else:
@@ -1376,7 +1538,7 @@ class OpGBTClassifier(_TreeEstimatorBase):
             subsample=jnp.float32(self.subsample),
             colsample=jnp.float32(self.colsample_bytree),
             seed=seed, rounds_per_dispatch=rpd_refit,
-            min_gain_norm=jnp.float32(self.min_info_gain))
+            min_gain_norm=jnp.float32(self.min_info_gain), layout=layout)
         return self._model_cls(edges, {k2: np.asarray(v) for k2, v in trees.items()},
                                self.learning_rate)
 

@@ -23,6 +23,7 @@ from transmogrifai_tpu import types as T
 from transmogrifai_tpu.data.columns import Column
 from transmogrifai_tpu.data.metadata import (
     NULL_INDICATOR, OTHER_INDICATOR, VectorColumnMetadata, VectorMetadata)
+from transmogrifai_tpu.obs.trace import TRACER
 from transmogrifai_tpu.stages.base import Estimator, FitContext, Transformer
 
 
@@ -87,10 +88,15 @@ class OneHotModel(Transformer):
         return [len(v) + 1 + (1 if self.track_nulls else 0) for v in self.vocabs]
 
     def host_prepare(self, cols: Sequence[Optional[Column]]):
-        return [
-            pivot_encode_ids(c.data, self._lookups[i], len(self.vocabs[i]))
-            for i, c in enumerate(cols)
-        ]
+        # the text cells' host pass, named in the timeline under the
+        # stage's `stage:transform:*` span with the cells it read
+        with TRACER.span("pivot:encode", category="pivot",
+                         cells=sum(len(c.data) for c in cols)):
+            return [
+                pivot_encode_ids(c.data, self._lookups[i],
+                                 len(self.vocabs[i]))
+                for i, c in enumerate(cols)
+            ]
 
     def device_apply(self, enc, dev):
         outs = []
@@ -139,9 +145,11 @@ class OneHotVectorizer(Estimator):
 
     def fit_model(self, cols: Sequence[Column], ctx: FitContext) -> Transformer:
         vocabs = []
-        for c in cols:
-            counter = Counter(s for s in c.data if s is not None)
-            vocabs.append(top_k_levels(counter, self.top_k, self.min_support))
+        with TRACER.span("pivot:fit", category="pivot", columns=len(cols)):
+            for c in cols:
+                counter = Counter(s for s in c.data if s is not None)
+                vocabs.append(
+                    top_k_levels(counter, self.top_k, self.min_support))
         return OneHotModel(vocabs, self.track_nulls)
 
 

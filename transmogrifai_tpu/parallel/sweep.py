@@ -70,7 +70,7 @@ from transmogrifai_tpu.models.trees import (
     bin_features, fit_forest, fit_gbt, fit_gbt_multiclass,
     forest_classification_pred, forest_regression_pred,
     gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
-    quantile_bin_edges)
+    hist_layout, hist_slots, indicator_columns, quantile_bin_edges)
 from transmogrifai_tpu.runtime.faults import (
     SITE_RUN_BLOCK, fault_point, is_oom_error)
 
@@ -838,7 +838,10 @@ def _fp_mlp(static, n_features, n_classes, seed):
 
 
 def _fp_forest(static, pad_depth, divisor, n_out, seed, bootstrap,
-               regression):
+               regression, blocks):
+    """`blocks`: the histogram operand's (d_wide, d_ind), or None for the
+    one uniform block; the columns' positions arrive in `data["layout"]`
+    (`_binned_cache`), so same-count tables share the program."""
     n_trees, max_bins, subsample = static[:3]
     pred_fn = forest_regression_pred if regression \
         else forest_classification_pred
@@ -848,16 +851,19 @@ def _fp_forest(static, pad_depth, divisor, n_out, seed, bootstrap,
                            max_bins, n_out, seed, subsample, d["mcw"],
                            active_depth=d["depth"], bootstrap=bootstrap,
                            tree_budget_divisor=divisor,
-                           min_gain=d["min_gain"])
+                           min_gain=d["min_gain"],
+                           layout=data["layout"] if blocks else None)
         # small predict chunk: the dispatch vmaps `divisor` pairs, so
         # the per-chunk (c, n, m->128) slab multiplies by the width
         return pred_fn(trees, data["Xb"], chunk=8)
     return fit_predict
 
 
-def _fp_gbt(static, pad_depth, n_classes, seed, objective, eval_metric):
+def _fp_gbt(static, pad_depth, n_classes, seed, objective, eval_metric,
+            blocks):
     """The boosted family's single-program path (a mesh, or multiclass):
-    the whole fit, with in-scan early-stop masking for binary/squared."""
+    the whole fit, with in-scan early-stop masking for binary/squared.
+    `blocks` as in `_fp_forest`."""
     n_estimators, max_bins, esr = static[:3]
 
     def fit_predict(data, d, w, v):
@@ -865,7 +871,7 @@ def _fp_gbt(static, pad_depth, n_classes, seed, objective, eval_metric):
         common = dict(min_child_weight=d["mcw"], active_depth=d["depth"],
                       gamma=d["gamma"], alpha=d["alpha"],
                       subsample=d["subsample"], colsample=d["colsample"],
-                      seed=seed)
+                      seed=seed, layout=data["layout"] if blocks else None)
         if objective == "logistic" and n_classes > 2:
             _, margin = fit_gbt_multiclass(
                 Xb, y, w, n_estimators, pad_depth, max_bins, n_classes,
@@ -969,14 +975,15 @@ def _sweep_mlp(est, grids, X, y, W, V, metric_fn, ctx, sharding):
 # --------------------------------------------------------------------------- #
 
 # host-dispatch batching model: how many grid×fold pairs fit in one
-# dispatch. The work unit is learners × rows × nodes × features × bins —
-# the histogram-matmul FLOP shape. The INITIAL per-family constants are
+# dispatch. The work unit is learners × rows × nodes × operand slots
+# (`hist_slots`: bins a wide column, 2 an indicator column) — the
+# histogram-matmul FLOP shape. The INITIAL per-family constants are
 # guesses; every real dispatch is then timed and the measured sec/unit
 # (EMA) replaces the guess for the rest of the process — a different TPU
 # generation or feature width recalibrates itself after one dispatch.
 # The exec target bounds one dispatch's wall (GBT early stopping is only
 # checked between dispatches); the memory bound caps the simultaneous
-# bin one-hots (n·d·bins bf16) plus deepest-level routing one-hots
+# bin one-hots (n·slots bf16) plus deepest-level routing one-hots
 # (n·2^depth bf16). Both values predate PR 21's first direct chip run
 # and have not been re-measured since (ROADMAP).
 _PAIR_EXEC_TARGET_S = 25.0
@@ -1154,12 +1161,17 @@ def _pow2_floor(x: int) -> int:
     return 1 << max(0, int(x).bit_length() - 1)
 
 
-def _tree_pair_width(n: int, d: int, n_bins: int, learners: int,
+def _tree_pair_width(n: int, slots: int, learners: int,
                      sec_per_unit: float, pad_depth: int) -> int:
+    """Grid×fold pairs a tree dispatch vmaps; `slots` is the histogram
+    operand's width a row (`hist_slots`: bins a wide column, 2 an
+    indicator column)."""
     nodes = 2 ** min(pad_depth, 14)
-    est_s = max(0.05, float(learners) * n * nodes * d * n_bins
-                * sec_per_unit)
-    mem_per_pair = n * (d * n_bins + nodes) * 2  # bf16 bytes
+    est_s = max(0.05, float(learners) * n * nodes * slots * sec_per_unit)
+    # bf16 bytes of the bin one-hots and the deepest level's routing
+    # one-hot, as if every pair held its own: the bin one-hots are built
+    # once a dispatch and shared by its pairs, so this over-counts them
+    mem_per_pair = n * (slots + nodes) * 2
     w_exec = int(_PAIR_EXEC_TARGET_S / est_s)
     w_mem = int(_PAIR_MEM_BYTES // max(mem_per_pair, 1))
     # power-of-2 width: small calibration drift between runs must not
@@ -1167,15 +1179,20 @@ def _tree_pair_width(n: int, d: int, n_bins: int, learners: int,
     # compile that misses the persistent cache)
     return _pow2_floor(max(1, min(w_exec, w_mem)))
 
-def _binned_cache(est, grids, X, ctx) -> Dict[int, jnp.ndarray]:
+def _binned_cache(est, grids, X, ctx) -> Tuple[
+        Dict[int, jnp.ndarray], Optional[Dict], Optional[Tuple[int, int]]]:
     """Bin X once per distinct max_bins ACROSS tree families in a sweep:
     the cache lives on the FitContext, so RF and XGB in the same selector
     share the quantile binning of the identical training matrix. (The eager
-    fallback path has its own per-estimator `_bin_cache`.)
+    fallback path has its own per-estimator `_bin_cache`.) Returns the
+    binned matrices by max_bins, the histogram layout (`hist_layout`:
+    which columns are indicators, read off X once) and its two block
+    widths (None with no indicator column: a program's key).
 
     Quantile edges come from the UNPADDED rows (`ctx._sweep_n_rows`): mesh
     padding appends zero-weight rows which must not shift bin edges, or
-    sharded sweeps would silently deviate from unsharded ones.
+    sharded sweeps would silently deviate from unsharded ones. Only the
+    non-indicator columns cross to the host for them.
 
     Guarded by a lock: tree families now sweep on a thread pool, and two
     families hitting the same max_bins must not double-build the (n, d)
@@ -1188,19 +1205,30 @@ def _binned_cache(est, grids, X, ctx) -> Dict[int, jnp.ndarray]:
             if ctx is not None:
                 ctx._sweep_bin_cache = out
         n = getattr(ctx, "_sweep_n_rows", None) if ctx is not None else None
-        X_edges = None  # device→host gather only on a cache miss
         for g in grids:
             mb = int(_grid_param(est, g, "max_bins"))
-            if mb not in out:
-                if X_edges is None:
-                    X_host = np.asarray(X)
-                    X_edges = X_host if n is None else X_host[:n]
-                edges = quantile_bin_edges(X_edges, mb)
+            if mb in out:
+                continue
+            # a miss: the one family that bins, the others wait on the lock
+            with TRACER.span("sweep:bin", category="sweep",
+                             max_bins=mb) as sp:
+                X_edges = X if n is None else X[:n]
+                if "indicator" not in out:      # no shared context
+                    out["indicator"] = indicator_columns(X_edges)
+                if "layout" not in out:
+                    out["layout"] = hist_layout(out["indicator"])
+                edges = quantile_bin_edges(X_edges, mb, out["indicator"])
                 out[mb] = bin_features(jnp.asarray(X), jnp.asarray(edges))
-        return out
+                sp.set(hist_slots=hist_slots(int(X.shape[1]), mb,
+                                             out["layout"]))
+        layout = out.get("layout")
+        blocks = None if layout is None else tuple(
+            int(layout[b].shape[0]) for b in ("wide", "ind"))
+        return out, layout, blocks
 
 
 _BIN_CACHE_LOCK = threading.Lock()
+_SWEEP_DATA_LOCK = threading.Lock()
 
 
 _DEPTH_BUCKETS = (4, 6, 8, 10, 12, 14)
@@ -1228,7 +1256,7 @@ def _pad_depth_of(est, grids, idxs) -> int:
 
 def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
                   regression: bool):
-    xb_by_bins = _binned_cache(est, grids, X, ctx)
+    xb_by_bins, layout, blocks = _binned_cache(est, grids, X, ctx)
     if regression:
         Y = jnp.asarray(y)[:, None]
         n_out = 1
@@ -1250,22 +1278,24 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
         # real dispatch width never exceeds the pair count — keep the
         # fit_forest chunk budget in step with actual live instances
         return min(len(idxs) * n_folds,
-                   _tree_pair_width(n_rows, d_feat, max_bins, n_trees,
-                                    _sec_per_unit("forest"), pad_depth))
+                   _tree_pair_width(n_rows,
+                                    hist_slots(d_feat, max_bins, layout),
+                                    n_trees, _sec_per_unit("forest"),
+                                    pad_depth))
 
     def calibrate(st, idxs, seconds, width, remaining, clean):
         n_trees, max_bins, _ = st[:3]
         pad_depth = _pad_depth_of(est, grids, idxs)
+        slots = hist_slots(d_feat, max_bins, layout)
         units = (float(width) * n_trees * n_rows
-                 * (2 ** min(pad_depth, 14)) * d_feat * max_bins)
+                 * (2 ** min(pad_depth, 14)) * slots)
         # an overlapped wall-clock includes another family's queue time,
         # one that traced or compiled includes XLA's — never let either
         # reach the persisted calibration or GROW compiled dispatch shapes
         if not clean:
             return width
         spu = _record_calib("forest", seconds, units)
-        ideal = _tree_pair_width(n_rows, d_feat, max_bins, n_trees, spu,
-                                 pad_depth)
+        ideal = _tree_pair_width(n_rows, slots, n_trees, spu, pad_depth)
         # a resize recompiles: grow only when the
         # dispatch badly underfills the exec target AND enough pairs
         # remain to amortize the new program
@@ -1282,7 +1312,7 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
         divisor = (width_of(st, idxs) if sharding is None
                    else max(1, len(idxs) * n_folds))
         return (_pad_depth_of(est, grids, idxs), divisor, n_out, seed,
-                bootstrap, regression)
+                bootstrap, regression, blocks)
 
     def dyn_of(g):
         mcw = max(float(_grid_param(est, g, "min_child_weight") or 1.0),
@@ -1299,7 +1329,8 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
         grids, W, V, metric_fn, sharding, "forest",
         static_of=lambda g: _static_forest(est, g),
         dyn_of=dyn_of,
-        data_of=lambda st: {"Xb": xb_by_bins[st[1]], "Y": Y, "y": y},
+        data_of=lambda st: {"Xb": xb_by_bins[st[1]], "Y": Y, "y": y,
+                            **({"layout": layout} if blocks else {})},
         shape_of=shape_of,
         grid_vmap=lambda st, idxs: _pad_depth_of(est, grids, idxs) <= 6,
         host_dispatch=True,
@@ -1309,11 +1340,13 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
 
 @functools.lru_cache(maxsize=_HELD_PROGRAMS)
 def _gbt_rounds_program(static: Tuple, pad_depth: int, objective: str,
-                        eval_metric: str) -> Callable:
+                        eval_metric: str,
+                        blocks: Optional[Tuple[int, int]]) -> Callable:
     """`prog(data, dchunk, Wsel, Vsel, margin, best, since, keys)`: one
     chunk of boosting rounds (as many as `keys` holds) for `width`
     vmapped grid×fold pairs, carrying the early-stopping state. Built
-    from its key alone and held, like `_block_program`."""
+    from its key alone and held, like `_block_program`; `blocks` as in
+    `_fp_forest`."""
     from transmogrifai_tpu.analysis.retrace import instrumented_jit
     from transmogrifai_tpu.models.trees import fit_gbt_chunk
     _, max_bins, esr = static[:3]
@@ -1324,7 +1357,7 @@ def _gbt_rounds_program(static: Tuple, pad_depth: int, objective: str,
             int(ks.shape[0]), pad_depth, max_bins, d["lr"], d["lam"],
             objective, d["mcw"], d["depth"], d["gamma"], d["alpha"],
             d["subsample"], d["colsample"], esr, d["min_gain_norm"],
-            eval_metric)
+            eval_metric, data["layout"] if blocks else None)
         return m, b, s
 
     return instrumented_jit(
@@ -1353,7 +1386,7 @@ def _gbt_score_program(static: Tuple, objective: str,
 
 def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
     from transmogrifai_tpu.models.trees import _pick_rounds_per_dispatch
-    xb_by_bins = _binned_cache(est, grids, X, ctx)
+    xb_by_bins, layout, blocks = _binned_cache(est, grids, X, ctx)
     objective = est._objective
     n_classes = 2
     if objective == "logistic":
@@ -1377,7 +1410,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
         return _static_gbt(est, g)
 
     def data_of(st):
-        return {"Xb": xb_by_bins[st[1]], "y": y}
+        return {"Xb": xb_by_bins[st[1]], "y": y,
+                **({"layout": layout} if blocks else {})}
 
     def dyn_of(g):
         mcw = max(float(_grid_param(est, g, "min_child_weight") or 1.0),
@@ -1405,7 +1439,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             n_estimators, max_bins = st[0], st[1]
             pad_depth = _pad_depth_of(est, grids, idxs)
             return min(len(idxs) * n_folds,
-                       _tree_pair_width(n_rows, d_feat, max_bins,
+                       _tree_pair_width(n_rows,
+                                        hist_slots(d_feat, max_bins, layout),
                                         n_estimators, _sec_per_unit("gbt"),
                                         pad_depth))
 
@@ -1414,7 +1449,7 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             static_of=static_of, dyn_of=dyn_of, data_of=data_of,
             shape_of=lambda st, idxs: (
                 _pad_depth_of(est, grids, idxs), n_classes, seed,
-                objective, eval_metric),
+                objective, eval_metric, blocks),
             grid_vmap=lambda st, idxs: _pad_depth_of(est, grids, idxs) <= 6,
             host_dispatch=sharding is None,
             pair_width=lambda st, idxs, k: width_of(st, idxs),
@@ -1448,11 +1483,13 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                for k in dyn_dicts[0]}
         n_pairs = len(idxs) * n_folds
         nodes = 2 ** min(pad_depth, 14)
-        upr = float(n_rows) * nodes * d_feat * max_bins  # units/round/pair
-        mem_per_pair = n_rows * (d_feat * max_bins + nodes) * 2
+        slots = hist_slots(d_feat, max_bins, layout)
+        upr = float(n_rows) * nodes * slots  # units/round/pair
+        mem_per_pair = n_rows * (slots + nodes) * 2  # as _tree_pair_width
         w_mem = max(1, int(_PAIR_MEM_BYTES // mem_per_pair))
 
-        prog = _gbt_rounds_program(static, pad_depth, objective, eval_metric)
+        prog = _gbt_rounds_program(static, pad_depth, objective, eval_metric,
+                                   blocks)
         score_prog = _gbt_score_program(
             static, objective, None if host else metric_fn.key)
         keys_all = jax.random.split(jax.random.PRNGKey(seed), n_est)
@@ -1609,41 +1646,59 @@ def _run_sweep(est, grids: List[Dict], X, y, folds, evaluator, ctx,
                 and all(a is c and b is d
                         for (a, b), (c, d) in zip(kfolds, folds)))
 
-    cached = getattr(ctx, "_sweep_data_cache", None) if ctx is not None else None
-    if cached is not None and _same_data(cached[0]):
-        _, X, y, W, V = cached  # same selector fit: reuse padded/sharded set
-    else:
-        key_objs = (X, y, list(folds))
-        W = jnp.asarray(np.stack([tr for tr, _ in folds]))
-        V = jnp.asarray(np.stack([va for _, va in folds]))
-        if ctx is not None and ctx.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+    # families arrive together on the selector's thread pool: ONE of them
+    # prepares the sweep's shared data, the others wait and reuse it (two
+    # that both miss would each reset the binned-X cache under the other).
+    # A lock of its own: `_BIN_CACHE_LOCK` is held through the host
+    # quantiles, seconds a linear family has no reason to wait for
+    with _SWEEP_DATA_LOCK:
+        cached = getattr(ctx, "_sweep_data_cache", None) if ctx is not None else None
+        if cached is not None and _same_data(cached[0]):
+            _, X, y, W, V = cached  # same selector fit: reuse padded/sharded set
+        else:
+            key_objs = (X, y, list(folds))
+            W = jnp.asarray(np.stack([tr for tr, _ in folds]))
+            V = jnp.asarray(np.stack([va for _, va in folds]))
+            if ctx is not None and ctx.mesh is not None:
+                from jax.sharding import NamedSharding, PartitionSpec as P
 
-            from transmogrifai_tpu.parallel.mesh import DATA_AXIS
-            data_size = ctx.mesh.shape.get(DATA_AXIS, 1)
-            n = int(np.asarray(y).shape[0])
-            if data_size > 1:
-                # every fit/metric is weight-masked, so rows pad with zero
-                # weight in ALL folds — sharding never silently degrades to
-                # replication on uneven row counts. Tree binning must ignore
-                # the pad rows (see _binned_cache); bootstrap streams are
-                # prefix-stable across the padded shape.
-                ctx._sweep_n_rows = n
-                pad = (-n) % data_size
-                if pad:
-                    X = jnp.concatenate(
-                        [X, jnp.zeros((pad, X.shape[1]), X.dtype)])
-                    y = jnp.concatenate([y, jnp.zeros((pad,), y.dtype)])
-                    W = jnp.concatenate(
-                        [W, jnp.zeros((W.shape[0], pad), W.dtype)], axis=1)
-                    V = jnp.concatenate(
-                        [V, jnp.zeros((V.shape[0], pad), V.dtype)], axis=1)
-                mesh = ctx.mesh
-                X = jax.device_put(X, NamedSharding(mesh, P(DATA_AXIS, None)))
-                y = jax.device_put(y, NamedSharding(mesh, P(DATA_AXIS)))
-                W = jax.device_put(W, NamedSharding(mesh, P(None, DATA_AXIS)))
-                V = jax.device_put(V, NamedSharding(mesh, P(None, DATA_AXIS)))
-        if ctx is not None:
-            ctx._sweep_data_cache = (key_objs, X, y, W, V)
-            ctx._sweep_bin_cache = {}  # binned-X cache is per-data too
+                from transmogrifai_tpu.parallel.mesh import DATA_AXIS
+                data_size = ctx.mesh.shape.get(DATA_AXIS, 1)
+                n = int(np.asarray(y).shape[0])
+                if data_size > 1:
+                    # every fit/metric is weight-masked, so rows pad with zero
+                    # weight in ALL folds — sharding never silently degrades to
+                    # replication on uneven row counts. Tree binning must ignore
+                    # the pad rows (see _binned_cache); bootstrap streams are
+                    # prefix-stable across the padded shape.
+                    ctx._sweep_n_rows = n
+                    pad = (-n) % data_size
+                    if pad:
+                        X = jnp.concatenate(
+                            [X, jnp.zeros((pad, X.shape[1]), X.dtype)])
+                        y = jnp.concatenate([y, jnp.zeros((pad,), y.dtype)])
+                        W = jnp.concatenate(
+                            [W, jnp.zeros((W.shape[0], pad), W.dtype)], axis=1)
+                        V = jnp.concatenate(
+                            [V, jnp.zeros((V.shape[0], pad), V.dtype)], axis=1)
+                    mesh = ctx.mesh
+                    # the waiters need exactly these arrays: they stall
+                    # behind the placement on purpose, not by accident
+                    # conc-ok: C003 (waiters reuse the placed arrays)
+                    X = jax.device_put(X, NamedSharding(mesh, P(DATA_AXIS, None)))
+                    # conc-ok: C003 (waiters reuse the placed arrays)
+                    y = jax.device_put(y, NamedSharding(mesh, P(DATA_AXIS)))
+                    # conc-ok: C003 (waiters reuse the placed arrays)
+                    W = jax.device_put(W, NamedSharding(mesh, P(None, DATA_AXIS)))
+                    # conc-ok: C003 (waiters reuse the placed arrays)
+                    V = jax.device_put(V, NamedSharding(mesh, P(None, DATA_AXIS)))
+            if ctx is not None:
+                ctx._sweep_data_cache = (key_objs, X, y, W, V)
+                # the binned-X cache is per-data too. Which columns are
+                # 0/1 indicators (the tree histograms' layout) is read off
+                # the matrix HERE, before any family has a program in the
+                # device's queue: from a tree family's thread the reduction
+                # would wait behind the logistic block, and the host
+                # quantiles behind it
+                ctx._sweep_bin_cache = {"indicator": indicator_columns(X)}
     return handler(est, grids, X, y, W, V, metric_fn, ctx, sharding)

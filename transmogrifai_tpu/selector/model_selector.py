@@ -146,6 +146,9 @@ class ModelSelector(Estimator):
         with TRACER.span("selector:sweep", category="selector"):
             results, failures = self._sweep(
                 ctx, X, y_dev, folds, train_idx, data_digest)
+        # the sweep's padded/sharded data and binned matrices die with it:
+        # the refit bins for itself, and on a wide table they are gigabytes
+        ctx._sweep_data_cache = ctx._sweep_bin_cache = None
         if not results:
             raise RuntimeError(
                 f"All {failures} model families failed during validation")
@@ -555,10 +558,12 @@ class ModelSelector(Estimator):
                 X, y_dev, jnp.ones_like(y_dev), ctx)
 
         # -- evaluate train + holdout ------------------------------------ #
-        def _eval(idx: np.ndarray) -> Dict[str, Any]:
+        def _eval(idx: np.ndarray, rows=None) -> Dict[str, Any]:
             if len(idx) == 0:
                 return {}
-            pred = model.predict_arrays(X_full[jnp.asarray(idx)])
+            if rows is None:
+                rows = X_full[jnp.asarray(idx)]
+            pred = model.predict_arrays(rows)
             pcol = Column(T.Prediction, {k: np.asarray(v) for k, v in pred.items()})
             lcol = Column(T.RealNN, {
                 "value": y_np[idx], "mask": np.ones(len(idx), dtype=bool)})
@@ -566,7 +571,9 @@ class ModelSelector(Estimator):
             return {k: v for k, v in m.items() if not isinstance(v, list)}
 
         with TRACER.span("selector:evaluate", category="selector"):
-            train_metrics = _eval(train_idx)
+            # the prepared train rows are at hand: a second gather of them
+            # is a table-sized buffer (and its scratch) for nothing
+            train_metrics = _eval(train_idx, X)
             holdout_metrics = _eval(test_idx)
         summary = ModelSelectorSummary(
             problem_type=self.problem_type,
