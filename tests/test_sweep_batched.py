@@ -6,6 +6,7 @@ produces the same metric matrix as the eager host loop (`_sweep_generic`),
 which itself matches the host evaluators used for final model metrics.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -49,6 +50,130 @@ def test_auroc_aupr_device_match_host(rng, tied):
                             jnp.asarray(mask, jnp.float32)))
     assert got_roc == pytest.approx(auroc_score(ym, sm), abs=1e-5)
     assert got_pr == pytest.approx(aupr_score(ym, sm), abs=1e-5)
+
+
+# The formulation `aupr_dev` / `auroc_dev` had until the tie-group ends
+# were read by scan: argsort, gathers and `searchsorted`. It lives here
+# only, as the oracle the scan form has to equal bit for bit.
+
+def _auroc_searchsorted(y, scores, mask):
+    wpos = mask * y
+    wneg = mask * (1.0 - y)
+    order = jnp.argsort(scores)
+    s = scores[order]
+    wp = wpos[order]
+    wn = wneg[order]
+    cumn = jnp.concatenate([jnp.zeros(1, s.dtype), jnp.cumsum(wn)])
+    left = jnp.searchsorted(s, s, side="left")
+    right = jnp.searchsorted(s, s, side="right")
+    below = cumn[left]
+    tied = cumn[right] - cumn[left]
+    num = (wp * (below + 0.5 * tied)).sum()
+    n_pos = wpos.sum()
+    n_neg = wneg.sum()
+    ok = (n_pos > 0) & (n_neg > 0)
+    return jnp.where(ok, num / jnp.maximum(n_pos * n_neg, 1e-30), 0.0)
+
+
+def _aupr_searchsorted(y, scores, mask):
+    wpos = mask * y
+    neg_s = -scores
+    order = jnp.argsort(neg_s)
+    s_asc = neg_s[order]
+    wp = wpos[order]
+    w = mask[order]
+    cum_tp = jnp.cumsum(wp)
+    cum_n = jnp.cumsum(w)
+    right = jnp.searchsorted(s_asc, s_asc, side="right") - 1
+    tp = cum_tp[right]
+    n_at = cum_n[right]
+    n_pos = wpos.sum()
+    prec = jnp.where(n_at > 0, tp / jnp.maximum(n_at, 1e-30), 1.0)
+    rec = tp / jnp.maximum(n_pos, 1e-30)
+    r = jnp.concatenate([jnp.zeros(1, rec.dtype), rec])
+    p = jnp.concatenate([jnp.ones(1, prec.dtype), prec])
+    area = ((r[1:] - r[:-1]) * (p[1:] + p[:-1]) * 0.5).sum()
+    return jnp.where(n_pos > 0, area, 0.0)
+
+
+def _rank_case(name):
+    """(y, scores, mask) float64 host arrays of one named case."""
+    r = np.random.default_rng(27)
+    n = {"n1": 1, "n2": 2, "n2_tied": 2, "non_pow2": 1237}.get(name, 600)
+    y = (r.uniform(size=n) > 0.4).astype(np.float64)
+    s = r.uniform(size=n)
+    mask = (r.uniform(size=n) > 0.3).astype(np.float64)
+    if name in ("n1", "n2", "n2_tied"):
+        y[:] = [1.0, 0.0][:n]
+        mask[:] = 1.0
+    if name == "n2_tied":
+        s[:] = 0.5
+    elif name == "ties3":
+        s = np.round(s * 2) / 2
+    elif name == "ties50":
+        s = np.round(s * 49) / 49
+    elif name == "all_equal":
+        s[:] = 0.25
+    elif name == "ties_straddle_mask":
+        # every tie group holds masked and unmasked rows, at its ends too
+        s = np.repeat(np.linspace(0.05, 0.95, n // 6), 6)
+        mask = np.tile([0.0, 1.0, 0.0, 1.0, 1.0, 0.0], n // 6)
+        y = np.tile([1.0, 0.0, 0.0, 1.0, 0.0, 1.0], n // 6)
+        perm = r.permutation(n)
+        s, mask, y = s[perm], mask[perm], y[perm]
+    elif name == "no_positive":
+        mask = mask * (1.0 - y)
+    elif name == "no_negative":
+        mask = mask * y
+    elif name == "mask_empty":
+        mask[:] = 0.0
+    elif name == "zero_and_negative_scores":
+        s = np.round(s * 4 - 2) * 0.5          # -1.0 .. 1.0 in halves
+        s[::7] = -0.0                          # one tie group with +0.0
+    return y, s, mask
+
+
+RANK_METRICS = {  # name -> (device kernel, searchsorted oracle, host metric)
+    "aupr": (aupr_dev, _aupr_searchsorted, aupr_score),
+    "auroc": (auroc_dev, _auroc_searchsorted, auroc_score)}
+
+RANK_CASES = ["no_ties", "ties3", "ties50", "all_equal", "ties_straddle_mask",
+              "no_positive", "no_negative", "mask_empty", "n1", "n2",
+              "n2_tied", "non_pow2", "zero_and_negative_scores"]
+
+
+@pytest.mark.parametrize("metric", sorted(RANK_METRICS))
+@pytest.mark.parametrize("case", RANK_CASES)
+def test_rank_metric_by_scan_equals_host_and_searchsorted_oracle(case, metric):
+    dev, oracle, host = RANK_METRICS[metric]
+    y, s, mask = _rank_case(case)
+    # the device sees float32 scores: the host groups the same values
+    ym, sm = _masked_host(y, s.astype(np.float32), mask)
+    args = [jnp.asarray(a, jnp.float32) for a in (y, s, mask)]
+    got = np.asarray(jax.jit(dev)(*args))
+    assert got.tobytes() == np.asarray(jax.jit(oracle)(*args)).tobytes()
+    assert float(got) == pytest.approx(host(ym, sm), abs=1e-5)
+
+
+@pytest.mark.parametrize("metric", sorted(RANK_METRICS))
+@pytest.mark.parametrize("case", ["no_ties", "ties3", "ties_straddle_mask",
+                                  "non_pow2"])
+def test_rank_metric_by_scan_under_the_sweeps_vmap(case, metric):
+    # `_gbt_score_program`: one label vector, a batch of score vectors,
+    # each under its own fold mask
+    dev, oracle, host = RANK_METRICS[metric]
+    y, s, mask = _rank_case(case)
+    r = np.random.default_rng(3)
+    S_ = np.stack([s, np.round(s, 1), s[::-1], r.permutation(s)])
+    M_ = np.stack([mask, 1.0 - mask, mask, np.ones_like(mask)])
+    args = (jnp.asarray(y, jnp.float32), jnp.asarray(S_, jnp.float32),
+            jnp.asarray(M_, jnp.float32))
+    got = np.asarray(jax.jit(jax.vmap(dev, in_axes=(None, 0, 0)))(*args))
+    want = np.asarray(jax.jit(jax.vmap(oracle, in_axes=(None, 0, 0)))(*args))
+    assert got.tobytes() == want.tobytes()
+    for k in range(len(S_)):
+        ym, sm = _masked_host(y, S_[k].astype(np.float32), M_[k])
+        assert float(got[k]) == pytest.approx(host(ym, sm), abs=1e-5)
 
 
 def test_binary_confusion_device_match_host(rng):

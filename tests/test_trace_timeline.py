@@ -447,11 +447,20 @@ def _lower_linreg():
         a, b, w, 0.01, 0.01, max_iter=3)).lower(X, y, jnp.ones(16))
 
 
-def _lower_metric(evaluator, pred_key):
+def _lower_metric(evaluator, pred_key, batch=None):
+    """The metric's program; with `batch`, under the sweep's vmap over
+    score vectors and fold masks."""
     fn = make_device_metric(evaluator)
     y = jnp.asarray(np.arange(16) % 2, jnp.float32)
-    return jax.jit(lambda s, m: fn(y, {pred_key: s}, m)) \
-        .lower(jnp.linspace(0.0, 1.0, 16), jnp.ones(16))
+    s, m = jnp.linspace(0.0, 1.0, 16), jnp.ones(16)
+
+    def one(scores, mask):
+        return fn(y, {pred_key: scores}, mask)
+
+    if batch is None:
+        return jax.jit(one).lower(s, m)
+    return jax.jit(jax.vmap(one)).lower(jnp.tile(s, (batch, 1)),
+                                        jnp.tile(m, (batch, 1)))
 
 
 SCOPES = [
@@ -483,6 +492,23 @@ def test_kernel_scope_is_in_the_lowered_programs_op_names(scope, lower):
     # callee's own locations start at the scope
     text = lower().as_text(debug_info=True)
     assert re.search(rf'["/(]{re.escape(scope)}[/)]', text)
+
+
+@pytest.mark.parametrize("metric", ["AuPR", "AuROC"])
+@pytest.mark.parametrize("batch", [None, 3], ids=["plain", "vmap"])
+def test_rank_metric_programs_hold_no_loop_and_no_gather(metric, batch):
+    # tie-group ends come from a running minimum over the sorted scores
+    # and the weights ride the sort: a `searchsorted` would show here as
+    # a `while`, an `argsort`-then-index as a `gather`, and on the chip
+    # each is a serial pass over the rows
+    lowered = _lower_metric(BinaryClassificationEvaluator(metric),
+                            "prediction", batch)
+    ops = set(re.findall(r"\b(?:stablehlo|mhlo|chlo)\.([a-z_]+)",
+                         lowered.as_text()))
+    assert "sort" in ops
+    assert not ops & {"while", "gather", "dynamic_gather", "scatter",
+                      "dynamic_slice", "case"}, sorted(ops)
+    assert not re.search(r"\b(while|gather)\(", lowered.compile().as_text())
 
 
 # --------------------------------------------------------------------- #

@@ -13,6 +13,13 @@ In the rank-based metrics (AuROC/AuPR) masked rows still occupy slots in
 the sorted arrays but with zero weight they only create duplicated curve
 points whose trapezoid contribution is exactly zero, so the result equals
 the host metric computed on the unmasked subset (ties included).
+
+The rank metrics hold no search and no table-sized gather (on a TPU each
+is a serial pass over the rows): the weights ride the one multi-operand
+sort, and a row reads its tie group's prefix sums by a running
+minimum/maximum over the group's flagged end/start (`_at_group_end`,
+`_at_group_start`). Weights are 0/1 and the sums integers below 2^24, so
+the result is the same float in any order within a tie.
 """
 
 from __future__ import annotations
@@ -25,19 +32,33 @@ def _masked_sum(x, mask):
     return (x * mask).sum()
 
 
+def _tie_groups(s):
+    """(is_start, is_end) row flags of the runs of equal values in sorted `s`."""
+    change = s[1:] != s[:-1]
+    edge = jnp.ones(1, bool)
+    return jnp.concatenate([edge, change]), jnp.concatenate([change, edge])
+
+
+def _at_group_end(cum, is_end):
+    """Non-decreasing `cum` read at the LAST row of every row's tie group:
+    a reverse running minimum over the group ends, no search and no gather."""
+    return jax.lax.cummin(jnp.where(is_end, cum, jnp.inf), reverse=True)
+
+
+def _at_group_start(cum, is_start):
+    """Non-decreasing `cum` read at the FIRST row of every row's tie group."""
+    return jax.lax.cummax(jnp.where(is_start, cum, -jnp.inf))
+
+
 def auroc_dev(y: jnp.ndarray, scores: jnp.ndarray, mask: jnp.ndarray):
     """Tie-averaged Mann-Whitney AuROC over masked rows (auroc_score parity)."""
     wpos = mask * y
     wneg = mask * (1.0 - y)
-    order = jnp.argsort(scores)
-    s = scores[order]
-    wp = wpos[order]
-    wn = wneg[order]
-    cumn = jnp.concatenate([jnp.zeros(1, s.dtype), jnp.cumsum(wn)])
-    left = jnp.searchsorted(s, s, side="left")
-    right = jnp.searchsorted(s, s, side="right")
-    below = cumn[left]
-    tied = cumn[right] - cumn[left]
+    s, wp, wn = jax.lax.sort((scores, wpos, wneg), num_keys=1, is_stable=True)
+    is_start, is_end = _tie_groups(s)
+    cumn = jnp.cumsum(wn)
+    below = _at_group_start(cumn - wn, is_start)   # negatives ranked lower
+    tied = _at_group_end(cumn, is_end) - below
     num = (wp * (below + 0.5 * tied)).sum()
     n_pos = wpos.sum()
     n_neg = wneg.sum()
@@ -49,17 +70,13 @@ def aupr_dev(y: jnp.ndarray, scores: jnp.ndarray, mask: jnp.ndarray):
     """Trapezoid area under the tie-grouped PR curve with the (r=0, p=1)
     start point (aupr_score / Spark BinaryClassificationMetrics parity)."""
     wpos = mask * y
-    neg_s = -scores
-    order = jnp.argsort(neg_s)
-    s_asc = neg_s[order]            # ascending == scores descending
-    wp = wpos[order]
-    w = mask[order]
-    cum_tp = jnp.cumsum(wp)
-    cum_n = jnp.cumsum(w)
-    # map every index to its tie-group END (last index with an equal score)
-    right = jnp.searchsorted(s_asc, s_asc, side="right") - 1
-    tp = cum_tp[right]
-    n_at = cum_n[right]
+    # ascending in -scores == scores descending; the weights ride the sort
+    s_asc, wp, w = jax.lax.sort((-scores, wpos, mask), num_keys=1,
+                                is_stable=True)
+    _, is_end = _tie_groups(s_asc)
+    # every row reads the prefix sums at its tie-group END
+    tp = _at_group_end(jnp.cumsum(wp), is_end)
+    n_at = _at_group_end(jnp.cumsum(w), is_end)
     n_pos = wpos.sum()
     prec = jnp.where(n_at > 0, tp / jnp.maximum(n_at, 1e-30), 1.0)
     rec = tp / jnp.maximum(n_pos, 1e-30)
@@ -71,13 +88,20 @@ def aupr_dev(y: jnp.ndarray, scores: jnp.ndarray, mask: jnp.ndarray):
 
 def aupr_binned_dev(y: jnp.ndarray, scores: jnp.ndarray, mask: jnp.ndarray,
                     n_bins: int = 4096):
-    """Sort-free AuPR for out-of-core row counts: scores quantize to
-    `n_bins` buckets, positive/total weights histogram via one-hot
-    matmuls (MXU — `argsort` + `searchsorted` in `aupr_dev` SERIALIZE on
-    TPU and take minutes at 10M rows), then the tie-grouped PR trapezoid
-    runs over the 4096 bucket boundaries. Equivalent to `aupr_dev` with
-    scores rounded to 1/n_bins — at 10M rows every bucket holds thousands
-    of samples, so the quantization error is far below fold noise."""
+    """Sort-free, APPROXIMATE AuPR: scores quantize to `n_bins` buckets,
+    positive/total weights histogram via one-hot matmuls (MXU), then the
+    tie-grouped PR trapezoid runs over the 4096 bucket boundaries.
+    Equivalent to `aupr_dev` with scores rounded to 1/n_bins.
+
+    Not wired into any sweep: `aupr_dev` is exact and is not the slow
+    path on a TPU (one v5e, 2.16 M rows: one metric 8.5 ms, six under a
+    `vmap` 95 ms; `PERF.md` §6, PR 27). What the binned form is for: a
+    table whose scores never sit in one device array (out-of-core: the
+    histograms of row chunks add), and masked row counts past 2^24,
+    where `aupr_dev`'s float32 prefix sums stop being exact integers.
+    It answers a different question than `aupr_dev` (≈ 2e-4 away at
+    10M rows), so it does not stand in for it where a configuration
+    states the exact metric."""
     s = jnp.clip(scores, 0.0, 1.0)
     b = jnp.minimum((s * n_bins).astype(jnp.int32), n_bins - 1)
     n = b.shape[0]
