@@ -230,7 +230,6 @@ def child_train_score(work: str, rehearsal: bool) -> dict:
     from transmogrifai_tpu.analysis.retrace import DISPATCHES, MONITOR
     from transmogrifai_tpu.native.build import native_active
     from transmogrifai_tpu.obs.export import EventLog, install_event_log
-    from transmogrifai_tpu.perf.params import hbm_budget_bytes
     from transmogrifai_tpu.readers import DataReaders
     from transmogrifai_tpu.utils.compile_cache import (
         COMPILE_STATS, compile_cache_entries, enable_compile_cache)
@@ -242,17 +241,15 @@ def child_train_score(work: str, rehearsal: bool) -> dict:
     _say(f"[train] platform={res['device']['platform']} "
          f"device_kind={res['device']['kind']} "
          f"count={res['device']['count']} "
-         f"bytes_limit={res['bytes_limit']} (sweep HBM gate assumes "
-         f"{int(hbm_budget_bytes())}) compile_cache={res['compile_cache']}")
-    # learned state that would change what gets compiled (dispatch-width
-    # calibration, cached programs) lives under the store root only
+         f"bytes_limit={res['bytes_limit']} "
+         f"compile_cache={res['compile_cache']}")
+    # state a run keeps (cached programs, the perf corpus) lives under
+    # the store root only; none of it decides what gets compiled
     from transmogrifai_tpu.store.config import cache_root
     res["store_at_start"] = {
         "root": cache_root(),
-        "sweep_calib": os.path.exists(
-            os.path.join(cache_root(), "sweep_calib.json")),
         "compile_cache_entries": compile_cache_entries()}
-    _say(f"[train] learned state at start: {res['store_at_start']}")
+    _say(f"[train] kept state at start: {res['store_at_start']}")
     res["native"] = native_active()
     _say(f"[train] native host kernels active: {res['native']}")
     if shutil.which("cc") and not all(res["native"].values()):

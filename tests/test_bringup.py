@@ -151,7 +151,7 @@ def test_bench_failed_mode_exits_nonzero(bench, monkeypatch, capsys):
     assert "os._exit" not in open(bench.__file__).read()
 
 
-# -- sweep: retry narrowing, calibration without the dispatch constant ---- #
+# -- sweep: retry narrowing ----------------------------------------------- #
 
 def test_sweep_retry_policy_retries_only_declared_transients():
     from transmogrifai_tpu.selector.model_selector import ModelSelector
@@ -162,18 +162,6 @@ def test_sweep_retry_policy_retries_only_declared_transients():
     flagged = JaxRuntimeError("dropped")
     flagged.transient = True
     assert policy.is_transient(flagged)
-
-
-def test_calibration_uses_the_measured_dispatch_wall(monkeypatch):
-    from transmogrifai_tpu.parallel import sweep
-    monkeypatch.setattr(sweep, "_CALIB", {})
-    monkeypatch.setattr(sweep, "_CALIB_LOADED", True)
-    monkeypatch.setattr(sweep, "_save_calib", lambda: None)
-    # a 0.5 s dispatch calibrates to 0.5 s of work, not to a floor left
-    # after subtracting an assumed per-dispatch overhead
-    assert sweep._record_calib("forest", 0.5, 1e12) == \
-        pytest.approx(0.5 / 1e12)
-    assert not hasattr(sweep, "_DISPATCH_OVERHEAD_S")
 
 
 # -- native kernels: built from the committed source, keyed by its hash --- #

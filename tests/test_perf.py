@@ -1,8 +1,7 @@
 """perf/ learned cost model: fit/predict/persistence, the cold-start
 contract (empty corpus → every consumer reproduces today's heuristics
-bit for bit, regression-tested per call site), the pre-dispatch HBM
-gate vs the OOM-halving fallback, residual recording (histogram +
-goodput), journal facts harvesting, and params threading.
+bit for bit, regression-tested per call site), the sweep's
+OOM-halving, residual recording (histogram + goodput), journal facts harvesting, and params threading.
 
 The suite runs with TRANSMOGRIFAI_PERF_MODEL=0 (conftest); tests that
 exercise the model opt in per-test via the `perf_env` fixture, which
@@ -58,7 +57,7 @@ class TestCostModel:
     def test_fit_recovers_multiplicative_law(self, perf_env):
         corpus = perf.get_corpus()
         synth_corpus(corpus)
-        for target in ("block_runtime", "ingest", "serving_bucket", "hbm"):
+        for target in ("block_runtime", "ingest", "serving_bucket"):
             mape = perf.holdout_mape(corpus, target)
             assert mape is not None and mape < 0.35, (target, mape)
 
@@ -155,7 +154,7 @@ class TestSchedulerPlan:
         perf.set_model(_warm_model(
             {"block_runtime": _block_rows((4, 16, 64))}))
         blocks = _mk_blocks() + [_Block(1, ("generic", "abcd1234"), [0])]
-        # hbm/generic unfitted targets are fine; but a block the model
+        # generic unfitted targets are fine; but a block the model
         # CAN'T price (different... same target, still priced) — force
         # coldness via an unfitted model for contrast
         planned = self._plan(blocks)
@@ -178,7 +177,7 @@ class TestSchedulerPlan:
 
 
 # --------------------------------------------------------------------------- #
-# consumer 2: pre-dispatch HBM gate in _run_groups_resilient                  #
+# _run_groups_resilient: OOM halving, and the rows it records                 #
 # --------------------------------------------------------------------------- #
 
 def _oom_groups():
@@ -205,7 +204,7 @@ def _run_resilient(groups, run_one, facts):
     from transmogrifai_tpu.obs.trace import TRACER
     from transmogrifai_tpu.parallel.sweep import _run_groups_resilient
     commits = []
-    with TRACER.span("run:test-hbm", category="run", new_trace=True) as root:
+    with TRACER.span("run:test-oom", category="run", new_trace=True) as root:
         _run_groups_resilient(
             groups, run_one,
             commit=lambda idxs, s=None, f=None: commits.append(list(idxs)),
@@ -214,7 +213,7 @@ def _run_resilient(groups, run_one, facts):
     return commits, report
 
 
-class TestHbmGate:
+class TestOomHalving:
     def test_cold_pays_oom_redo_via_halving(self, perf_env):
         perf.set_model(perf.CostModel())
         groups, run_one, calls = _oom_groups()
@@ -223,34 +222,9 @@ class TestHbmGate:
         # the halving fallback burned real badput
         assert report.counts.get("oom_redos", 0) >= 1
         assert report.buckets["oom_redo_s"] > 0
-        assert report.counts.get("hbm_preshrinks", 0) == 0
-
-    def test_warm_gate_preshrinks_and_avoids_redo(self, perf_env):
-        # hbm rows: value = 1e12 * n_configs → a 4-config block predicts
-        # ~4 TB against the 4 GB default budget → pre-split to singles
-        rows = []
-        for k in (1, 2, 4, 8):
-            feats = perf.block_features("forest", (20, 32, False, 6),
-                                        k, 1000, 50, 3)
-            rows.append({"features": feats, "value": 1e12 * k})
-        perf.set_model(_warm_model({"hbm": rows}))
-        groups, run_one, calls = _oom_groups()
-        commits, report = _run_resilient(groups, run_one, _facts_cb())
-        assert sorted(i for c in calls for i in c) == [0, 1, 2, 3]
-        # the gate fired BEFORE dispatch: zero oom_redo badput paid
-        assert report.counts.get("hbm_preshrinks", 0) == 1
-        assert report.counts.get("oom_redos", 0) == 0
-        assert report.buckets["oom_redo_s"] == 0.0
-
-    def test_oom_becomes_negative_training_example(self, perf_env):
-        perf.set_model(perf.CostModel())
-        groups, run_one, _ = _oom_groups()
-        _run_resilient(groups, run_one, _facts_cb())
-        oom_rows = [r for r in perf.get_corpus().rows("hbm")
-                    if r.get("oom")]
-        assert oom_rows, "device OOM did not append an hbm training row"
-        # inflated past the budget: the fit learns this shape is over
-        assert oom_rows[0]["value"] >= perf.hbm_budget_bytes()
+        # every half that fitted committed, and recorded its runtime row
+        assert sorted(i for c in commits for i in c) == [0, 1, 2, 3]
+        assert len(perf.get_corpus().rows("block_runtime")) == len(commits)
 
     def test_blocks_record_runtime_rows_even_cold(self, perf_env):
         perf.set_model(perf.CostModel())

@@ -3,9 +3,10 @@
 The contract under test: a sweep program's HLO depends on shapes, dtypes
 and static hyper-parameters only — every array that depends on the
 dataset reaches it as an argument, never as a closure constant — so a
-second `run_sweep` on a same-shaped table compiles nothing, and a
-dispatch that did hold a compile never feeds the width calibration
-(widths are compiled shapes: they have to read the same pass after pass).
+second `run_sweep` on a same-shaped table compiles nothing — and what
+goes into one dispatch (`dispatch_plan`: pairs wide, rounds long) is a
+function of shapes alone, so it reads the same pass after pass and
+process after process, in the sweep and in the refit.
 """
 
 import jax.numpy as jnp
@@ -22,6 +23,8 @@ from transmogrifai_tpu.models import (
     OpGeneralizedLinearRegression, OpLinearRegression, OpLinearSVC,
     OpLogisticRegression, OpMultilayerPerceptronClassifier, OpNaiveBayes,
     OpRandomForestClassifier, OpRandomForestRegressor, OpXGBoostClassifier)
+from transmogrifai_tpu.models import trees
+from transmogrifai_tpu.obs.trace import TRACER
 from transmogrifai_tpu.parallel import sweep as S
 from transmogrifai_tpu.parallel.mesh import make_mesh, sweep_sharding
 from transmogrifai_tpu.selector.validators import OpCrossValidation
@@ -144,11 +147,8 @@ def _clear_programs():
 
 
 @pytest.fixture
-def fresh_sweep(monkeypatch):
-    """No held program, no learned calibration, nothing persisted."""
-    monkeypatch.setattr(S, "_CALIB", {})
-    monkeypatch.setattr(S, "_CALIB_LOADED", True)
-    monkeypatch.setattr(S, "_save_calib", lambda: None)
+def fresh_sweep():
+    """No held program (the sweep learns nothing else to reset)."""
     _clear_programs()
     yield
     _clear_programs()
@@ -221,21 +221,95 @@ def test_lowered_text_would_show_a_closed_over_table():
     assert a != b
 
 
+# -- what goes into one dispatch ------------------------------------------ #
+
+# id -> (n_rows, slots, pad_depth, learners, n_pairs, pad_tail), (width,
+# rounds). The first four are the tree blocks of the benchmark's two cells
+# (`higgs`: 2,160,000 rows x 28 x 32 slots; `criteo`: 900,000 x 1,446): the
+# memory budget pins each to one pair a dispatch, and a forest's one tree
+# or both boosting rounds go into it.
+PLANS = {
+    "higgs-forest": ((2_160_000, 896, 12, 1, 3, False), (1, 1)),
+    "higgs-boosted": ((2_160_000, 896, 10, 2, 6, True), (1, 2)),
+    "criteo-forest": ((900_000, 1_446, 12, 1, 3, False), (1, 1)),
+    "criteo-boosted": ((900_000, 1_446, 10, 2, 6, True), (1, 2)),
+    # a tiny table: every pair and every round in one dispatch, the
+    # forest at the pair count itself, the boosted chunk at its
+    # power-of-two floor (its last chunk is padded)
+    "tiny-forest": ((240, 48, 4, 3, 6, False), (6, 3)),
+    "tiny-boosted": ((240, 48, 4, 3, 6, True), (4, 3)),
+    # memory allows 4 pairs, the work budget 2 of 50-tree forests...
+    "work-binds-width": ((100_000, 1_760, 10, 50, 64, False), (2, 50)),
+    # ...and, with one pair over it, 25 of 200 rounds at a time
+    "work-binds-rounds": ((1_000_000, 896, 10, 200, 6, True), (1, 25)),
+    # a prime round count has no divisor to chunk by: the ideal, and a tail
+    "prime-rounds": ((1_000_000, 896, 10, 199, 6, True), (1, 27)),
+}
+
+
+@pytest.mark.parametrize("case", list(PLANS))
+def test_dispatch_plan_from_shapes_alone(case):
+    args, want = PLANS[case]
+    assert trees.dispatch_plan(*args) == want
+    assert trees.dispatch_plan(*args) == want     # nothing was learned
+    width, rounds = want
+    assert width & (width - 1) == 0 or width == args[4]
+    assert 1 <= rounds <= args[3]
+
+
+def test_sweep_and_refit_chunk_a_shape_by_the_same_rounds(
+        fresh_sweep, monkeypatch):
+    """One rule: the sweep's boosted chunk and `fit_gbt_hosted`'s default
+    dispatch the same number of rounds for one shape."""
+    X, y, folds = _table("binary", 11)
+    est = OpXGBoostClassifier(n_estimators=4, max_depth=4, max_bins=8,
+                              early_stopping_rounds=0)
+    # a work budget of 2.5 rounds of this table at depth 4 (2^4 nodes)
+    unit = N * 2 ** 4 * trees.hist_slots(D, 8, None)
+    monkeypatch.setattr(trees, "_DISPATCH_UNITS", 2.5 * unit)
+    seen = []
+    real_chunk = trees.fit_gbt_chunk
+
+    def recording_chunk(*args, **kw):
+        seen.append(int(args[8]))            # n_rounds
+        return real_chunk(*args, **kw)
+
+    monkeypatch.setattr(trees, "fit_gbt_chunk", recording_chunk)
+    ctx = FitContext(n_rows=N, seed=7)
+    S.run_sweep(est, [{}], X, y, folds, BinaryClassificationEvaluator(), ctx)
+    swept, seen[:] = set(seen), []
+    est.fit_arrays(X, y, jnp.ones((N,), jnp.float32),
+                   FitContext(n_rows=N, seed=7))
+    assert swept == set(seen) == {2}
+
+
 @pytest.mark.parametrize("family", ["gbt", "forest"])
-def test_a_dispatch_that_compiled_is_no_calibration_sample(
-        family, fresh_sweep, monkeypatch):
-    # one pair and one round a dispatch, so the group makes several
-    monkeypatch.setattr(S, "_PAIR_EXEC_TARGET_S", 1e-9)
-    seen = []   # _CALIB as each dispatch ends, before its own sample
-    real_record = S.SWEEP_STATS.record
-    monkeypatch.setattr(
-        S.SWEEP_STATS, "record",
-        lambda dt: (seen.append(dict(S._CALIB)), real_record(dt)))
-    _sweep("boosted-binary-chunked" if family == "gbt"
-           else "forest-classifier", seed=11)
-    assert len(seen) >= 3
-    # the first dispatch traced and compiled its program: clean of any
-    # overlap (one thread), and still not a sample
-    assert seen[1] == {} and S._sec_per_unit(family) != S._CALIB_INIT[family]
-    # the second was execution only, and is one
-    assert family in seen[2]
+def test_a_second_sweep_dispatches_the_same_and_leaves_no_state(
+        family, fresh_sweep, monkeypatch, tmp_path):
+    """Two sweeps of one table in one process: the same dispatches, no
+    `recompile` event in the second, and nothing on disk but what the
+    compile cache and the perf corpus keep."""
+    from transmogrifai_tpu import perf
+    monkeypatch.setenv("TRANSMOGRIFAI_STORE_DIR", str(tmp_path))
+    monkeypatch.setenv("TRANSMOGRIFAI_PERF_MODEL", "1")
+    perf.set_model(None)
+    # one pair and one learner a dispatch, so a group makes several
+    monkeypatch.setattr(trees, "_DISPATCH_UNITS", 1.0)
+    case = ("boosted-binary-chunked" if family == "gbt"
+            else "forest-classifier")
+    made, events = [], []
+    try:
+        for _ in range(2):
+            d0 = S.SWEEP_STATS.dispatches
+            with TRACER.span("run:test-plan", category="run",
+                             new_trace=True) as root:
+                _sweep(case, seed=11)
+            made.append(S.SWEEP_STATS.dispatches - d0)
+            events.append([name for sp in TRACER.trace_spans(root.trace_id)
+                           for name, _, _ in sp.events])
+    finally:
+        perf.set_model(None)
+    assert made[0] == made[1] >= 3
+    assert "recompile" in events[0] and "recompile" not in events[1]
+    assert {p.name for p in tmp_path.iterdir()} <= {"xla-cache", "perf"}
+    assert (tmp_path / "perf").is_dir()      # the store did see the run

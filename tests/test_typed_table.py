@@ -22,7 +22,6 @@ from transmogrifai_tpu.models import (
     OpGBTClassifier, OpRandomForestClassifier, OpXGBoostClassifier)
 from transmogrifai_tpu.models import trees
 from transmogrifai_tpu.ops.categorical import OneHotModel
-from transmogrifai_tpu.parallel import sweep as S
 from transmogrifai_tpu.stages.base import FitContext
 from transmogrifai_tpu.workflow import Workflow
 
@@ -268,7 +267,7 @@ def test_pair_width_widens_when_columns_are_indicators():
     uniform = trees.hist_slots(d, bins, None)
     typed = trees.hist_slots(d, bins, layout)
     assert (uniform, typed) == (528 * 32, 13 * 32 + 515 * 2)
-    args = (1, 1e-15, 10)       # learners, sec/unit, depth: memory binds
-    assert S._tree_pair_width(n, typed, *args) \
-        > S._tree_pair_width(n, uniform, *args)
-    assert S._tree_pair_width(n, uniform, *args) == 1
+    def width(slots):           # depth 10, one learner: memory binds
+        return trees.dispatch_plan(n, slots, 10, 1, n_pairs=64)[0]
+    assert width(typed) > width(uniform)
+    assert width(uniform) == 1

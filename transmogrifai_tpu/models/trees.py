@@ -852,19 +852,44 @@ def _pick_rounds_per_dispatch(n_estimators: int, ideal: int) -> int:
     return best if best * 2 >= ideal else ideal
 
 
-def _default_rounds_per_dispatch(n: int, slots: int, n_estimators: int,
-                                 max_depth: int) -> int:
-    """Rounds between early-stopping checks: the unit budget targets a
-    few seconds per dispatch at ~1e-12 s/unit on 90k×55×32×2^10 (a
-    guess that predates PR 21's first direct chip run; not re-measured).
-    Chunked dispatch is what lets early stopping SKIP the remaining
-    dispatches. PR 21's chip run neither needed nor contradicted it: no
-    early stop fired in the default sweep (all 200 rounds ran), so what
-    it buys is unmeasured (ROADMAP, unre-measured defaults). `slots` is
-    the histogram operand's width a row (`hist_slots`)."""
-    unit = n * (2 ** min(max_depth, 14)) * slots
-    return _pick_rounds_per_dispatch(
-        n_estimators, max(1, int(2.5e13 // max(unit, 1))))
+# One dispatch's budgets. The memory budget caps the pairs alive at once;
+# the work budget bounds a dispatch's wall (a boosted sweep checks early
+# stopping only between dispatches): 25 s at the ~1e-12 s/unit of a
+# 90k x 55 x 32-bin table at depth 10. Both predate PR 21's first direct
+# chip run and have not been re-measured since (ROADMAP).
+_PAIR_MEM_BYTES = 4 << 30
+_DISPATCH_UNITS = 2.5e13
+
+
+def _pow2_floor(x: int) -> int:
+    return 1 << max(0, int(x).bit_length() - 1)
+
+
+def dispatch_plan(n_rows: int, slots: int, pad_depth: int, learners: int,
+                  n_pairs: int = 1, pad_tail: bool = False
+                  ) -> Tuple[int, int]:
+    """(width, rounds) of one tree dispatch, from shapes alone: how many
+    grid×fold pairs it vmaps and how many of a pair's `learners`
+    (boosting rounds; a forest grows all its trees in one) it runs.
+    `slots` is the histogram operand's width a row (`hist_slots`). The
+    work unit is learners × rows × nodes × slots, the histogram matmul's
+    shape. The width is a power of two (a width is a compiled shape),
+    capped by the pair count: at its power-of-two floor where the caller
+    pads the last chunk (`pad_tail`), at the count itself otherwise.
+    The sweep and the refit (`n_pairs` 1) both plan here, so a shape
+    compiles the same rounds in either."""
+    nodes = 2 ** min(pad_depth, 14)
+    unit = max(n_rows * nodes * slots, 1)       # one learner of one pair
+    # bf16 bytes of the bin one-hots and the deepest level's routing
+    # one-hot, as if every pair held its own: the bin one-hots are built
+    # once a dispatch and shared by its pairs, so this over-counts them
+    w_mem = _PAIR_MEM_BYTES // max(n_rows * (slots + nodes) * 2, 1)
+    w_work = int(_DISPATCH_UNITS // (learners * unit))
+    width = min(_pow2_floor(max(1, min(w_mem, w_work))),
+                _pow2_floor(n_pairs) if pad_tail else n_pairs)
+    rounds = _pick_rounds_per_dispatch(
+        learners, max(1, int(_DISPATCH_UNITS // (width * unit))))
+    return width, rounds
 
 
 def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
@@ -885,8 +910,8 @@ def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
     if val_w is None:
         val_w = jnp.zeros(n, jnp.float32)
     if rounds_per_dispatch is None:
-        rounds_per_dispatch = _default_rounds_per_dispatch(
-            n, hist_slots(d, n_bins, layout), n_estimators, max_depth)
+        _, rounds_per_dispatch = dispatch_plan(
+            n, hist_slots(d, n_bins, layout), max_depth, n_estimators)
     keys = jax.random.split(jax.random.PRNGKey(seed), n_estimators)
     margin = jnp.zeros(n, jnp.float32)
     best = jnp.float32(jnp.inf)
@@ -1518,10 +1543,10 @@ class OpGBTClassifier(_TreeEstimatorBase):
             # instead of compiling a fresh XLA shape; the ≤R-1 extra
             # rounds match XGBoost's default of
             # predicting with post-best-iteration trees included
-            rpd = _default_rounds_per_dispatch(
+            _, rpd = dispatch_plan(
                 Xb.shape[0],
                 hist_slots(Xb.shape[1], self.max_bins, layout),
-                self.n_estimators, self.max_depth)
+                self.max_depth, self.n_estimators)
             n_rounds = min(-(-n_live // rpd) * rpd, self.n_estimators)
             rpd_refit = rpd
         else:

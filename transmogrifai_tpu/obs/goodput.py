@@ -228,7 +228,7 @@ def build_report(root: Span, spans: Iterable[Span]) -> GoodputReport:
               "resumed_blocks": 0, "faults_injected": 0,
               "cache_hits": 0, "cache_misses": 0,
               "steals": 0, "workers_retired": 0,
-              "hbm_preshrinks": 0, "block_resizes": 0}
+              "block_resizes": 0}
     saved = 0.0
     cache_saved = 0.0
     compile_saved = 0.0
@@ -392,8 +392,6 @@ def build_report(root: Span, spans: Iterable[Span]) -> GoodputReport:
                 counts["steals"] += 1
             elif name == "worker_retired":
                 counts["workers_retired"] += 1
-            elif name == "hbm_preshrink":
-                counts["hbm_preshrinks"] += 1
             elif name == "block_resize":
                 counts["block_resizes"] += 1
             elif name == "perf_residual":

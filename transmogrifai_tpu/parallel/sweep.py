@@ -33,6 +33,7 @@ is dropped with a warning; only all-families-failing raises.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import logging
 import threading
@@ -67,7 +68,7 @@ from transmogrifai_tpu.models.trees import (
     OpDecisionTreeClassifier, OpDecisionTreeRegressor, OpGBTClassifier,
     OpGBTRegressor, OpRandomForestClassifier, OpRandomForestRegressor,
     OpXGBoostClassifier, OpXGBoostRegressor,
-    bin_features, fit_forest, fit_gbt, fit_gbt_multiclass,
+    bin_features, dispatch_plan, fit_forest, fit_gbt, fit_gbt_multiclass,
     forest_classification_pred, forest_regression_pred,
     gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
     hist_layout, hist_slots, indicator_columns, quantile_bin_edges)
@@ -193,12 +194,6 @@ def _run_groups_resilient(groups: Dict[Tuple, List[int]], run_one,
 
     - `fault_point(SITE_RUN_BLOCK)` fires before every block, so a chaos
       plan can kill/fail the sweep at any block boundary;
-    - with a warm cost model (`perf/`), a block whose PREDICTED HBM
-      footprint exceeds the budget is pre-shrunk into narrower parts
-      BEFORE dispatch — the ``oom_redo`` badput the halving path would
-      have paid is never spent (an ``hbm_preshrink`` event marks the
-      decision); the halving path below stays as the fallback, and
-      every OOM observed becomes a negative training example;
     - a device-OOM failure HALVES the block width and retries each half
       before surfacing (narrower blocks fit where wide ones did not —
       the compiled program per half persists in the compile cache); the
@@ -211,29 +206,21 @@ def _run_groups_resilient(groups: Dict[Tuple, List[int]], run_one,
     `facts(static, idxs)` returns the block's cost-model feature dict
     (`perf/features.block_features`); when provided, every executed
     block records its measured wall time (and predicted-vs-measured
-    residual, when the model was warm) into the perf corpus — cold
-    start changes NOTHING about execution, it only collects rows.
+    residual, when the model was warm) into the perf corpus — it only
+    collects rows (the mesh scheduler's LPT order reads the model),
+    nothing about this execution changes.
     `block_key(idxs)` stamps each row with the block's content key
     (same formula as the journal's `facts["block_key"]`) so a later
     `harvest_journal` of this run's journal recognizes the block as
     already recorded instead of duplicating it.
     """
-    model = None
-    budget = 0.0
+    perf = model = None
     if facts is not None:
         try:
-            from transmogrifai_tpu import perf as _perf
-            model = _perf.get_model()
-            budget = _perf.hbm_budget_bytes()
+            from transmogrifai_tpu import perf
+            model = perf.get_model()
         except Exception:
-            model = None
-
-    def _note(target, feats, predicted, measured, **extra):
-        try:
-            from transmogrifai_tpu import perf as _perf
-            _perf.note(target, feats, predicted, measured, **extra)
-        except Exception:
-            log.debug("perf recording failed", exc_info=True)
+            perf = None
 
     def run(static, idxs):
         feats = facts(static, idxs) if facts is not None else None
@@ -253,16 +240,6 @@ def _run_groups_resilient(groups: Dict[Tuple, List[int]], run_one,
             obs_export.record_event("oom_redo", family=family,
                                     configs=len(idxs),
                                     wasted_s=round(wasted, 6))
-            if feats is not None:
-                # negative training example: this block's footprint
-                # exceeded the device — teach the HBM target that shapes
-                # like it sit past the budget, so the NEXT run's gate
-                # pre-shrinks instead of paying this redo again
-                from transmogrifai_tpu.perf.features import \
-                    hbm_proxy_bytes
-                proxy = hbm_proxy_bytes(feats)
-                _note("hbm", feats, None,
-                      max(proxy, budget or proxy) * 1.25, oom=True)
             mid = (len(idxs) + 1) // 2
             log.warning(
                 "sweep %s block %r: device OOM with %d configs (%s) — "
@@ -272,39 +249,17 @@ def _run_groups_resilient(groups: Dict[Tuple, List[int]], run_one,
             run(static, idxs[mid:])
             return
         block_s = time.perf_counter() - t0
-        if feats is not None:
-            from transmogrifai_tpu.perf.features import hbm_proxy_bytes
-            extra = ({"block_key": block_key(idxs)}
-                     if block_key is not None else {})
-            _note("block_runtime", feats, pred, block_s, **extra)
-            _note("hbm", feats, None, hbm_proxy_bytes(feats))
+        if feats is not None and perf is not None:
+            try:
+                perf.note("block_runtime", feats, pred, block_s,
+                          **({"block_key": block_key(idxs)}
+                             if block_key is not None else {}))
+            except Exception:
+                log.debug("perf recording failed", exc_info=True)
         commit(idxs, block_s, feats)
 
     for static, idxs in groups.items():
-        parts = [idxs]
-        if model is not None and facts is not None and budget > 0 \
-                and len(idxs) > 1:
-            hp = model.predict("hbm", facts(static, idxs))
-            if hp is not None and hp.value > budget:
-                import math as _math
-                k = min(len(idxs), int(_math.ceil(hp.value / budget)))
-                if k > 1:
-                    step = -(-len(idxs) // k)
-                    parts = [idxs[i:i + step]
-                             for i in range(0, len(idxs), step)]
-                    obs_export.record_event(
-                        "hbm_preshrink", family=family,
-                        configs=len(idxs), parts=len(parts),
-                        predicted_bytes=round(hp.value),
-                        budget_bytes=round(budget))
-                    log.info(
-                        "sweep %s block %r: predicted HBM %.2f GB over "
-                        "the %.2f GB budget — pre-shrinking %d configs "
-                        "into %d parts (no OOM redo)", family, static,
-                        hp.value / 2**30, budget / 2**30, len(idxs),
-                        len(parts))
-        for part in parts:
-            run(static, part)
+        run(static, idxs)
 
 
 # --------------------------------------------------------------------------- #
@@ -351,8 +306,7 @@ def _sweep_generic(est, grids: List[Dict], X, y, folds, evaluator,
                 clone._bin_cache = bin_cache
             row = []
             for tr, va in folds:
-                # visible to tree-family calib timing
-                with _DispatchSpan(type(est).__name__):
+                with _dispatch_span(type(est).__name__):
                     model = clone.fit_arrays(X, y, jnp.asarray(tr), ctx)
                     pred = model.predict_arrays(X)
                 row.append(_metric(
@@ -373,6 +327,47 @@ def _sweep_generic(est, grids: List[Dict], X, y, folds, evaluator,
 # --------------------------------------------------------------------------- #
 # batched execution scaffold                                                  #
 # --------------------------------------------------------------------------- #
+
+class SweepStats:
+    """Per-process dispatch accounting (SURVEY §5.1 'measure instead'):
+    how many timed dispatches the tree families' sweep loops made and
+    the thread-seconds they held (a first dispatch's compile included;
+    the `compile:sweep:dispatch:*` spans say how much of it that was).
+    `bench.py` resets before a sweep and reports the fraction."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self):
+        with self._lock:
+            self.dispatch_s = 0.0
+            self.dispatches = 0
+
+    def record(self, seconds: float) -> None:
+        with self._lock:
+            self.dispatch_s += seconds
+            self.dispatches += 1
+
+
+SWEEP_STATS = SweepStats()
+
+
+@contextlib.contextmanager
+def _dispatch_span(family: str, timed: bool = False):
+    """One device dispatch: holds a `sweep:dispatch:<family>` span open,
+    so the dispatch — and the XLA compile a first dispatch asks for, as
+    the span's `compile:*` child — sits in the run's timeline
+    (`instrumented_jit` drops a `recompile` event on it when the program
+    is traced, `utils/compile_cache.py` a `compile_cache_hit`). `timed`
+    (the tree families' host loops) also counts a dispatch that
+    completed, and its wall, in `SWEEP_STATS`."""
+    with TRACER.span(f"sweep:dispatch:{family}", category="sweep_dispatch"):
+        t0 = time.perf_counter()
+        yield
+        if timed:
+            SWEEP_STATS.record(time.perf_counter() - t0)
+
 
 def _grid_param(est, grid: Dict, name: str) -> Any:
     return grid.get(name, getattr(est, name, est.params.get(name)))
@@ -415,10 +410,7 @@ def _run_block(prog: Callable, data, W, V, dyn: Dict[str, jnp.ndarray],
     fallback path).
     """
     dyn, g = _shard_dyn(dyn, sharding)
-    # span-wrapped (even though THIS site never feeds calibration) so a
-    # tree family timing a dispatch on another thread sees the overlap —
-    # a linear-family execution queues tree dispatches just the same
-    with _DispatchSpan(family):
+    with _dispatch_span(family):
         out = jax.block_until_ready(prog(data, W, V, dyn))
     return jax.tree_util.tree_map(lambda a: a[:g], out)  # drop pad rows
 
@@ -483,8 +475,6 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
                   host_dispatch: bool = False,
                   pair_width: Callable[[Tuple, List[int], int], int]
                   = lambda s, i, k: 1,
-                  calibrate: Optional[Callable[[Tuple, List[int], float, int,
-                                                int, bool], int]] = None,
                   x_info: Optional[Tuple[int, int]] = None,
                   ) -> List[List[float]]:
     """Shared scaffold: group grids by static params; per group, stack the
@@ -544,10 +534,7 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
             # discarded). Dispatching `width` vmapped pairs at a time
             # amortizes the per-dispatch overhead over `width` fits while
             # each chunk is scored before the next dispatch, so peak HBM
-            # is one chunk, not the whole group. `calibrate`
-            # may resize `width` between dispatches from measured wall
-            # time (a resize recompiles, so it only fires when the
-            # remaining work amortizes the new compile).
+            # is one chunk, not the whole group.
             prog = _block_program(family, static, shape, metric_key,
                                   "pairs")
             s = 0
@@ -558,19 +545,16 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
             # analogue of score_stream(fetch_group)). The host-metric fallback still
             # fetches per chunk: it needs the full prediction pytree and
             # bounding peak HBM to one chunk matters there.
-            pend: List[Tuple[int, int, Any]] = []  # (s, width, device out)
+            pend: List[Tuple[int, Any]] = []  # (s, device out)
             while s < n_pairs:
                 ps = [min(s + t, n_pairs - 1) for t in range(width)]
                 gs = [p // n_folds for p in ps]
                 fs = [p % n_folds for p in ps]
                 dchunk = {k: v[jnp.asarray(gs)] for k, v in dyn.items()}
-                with _DispatchSpan(family) as span:
-                    t0 = time.perf_counter()
+                with _dispatch_span(family, timed=True):
                     out = jax.block_until_ready(
                         prog(data, dchunk, W[jnp.asarray(fs)],
                              V[jnp.asarray(fs)]))
-                    dt = time.perf_counter() - t0
-                SWEEP_STATS.record(dt)
                 if host:
                     out_np = jax.tree_util.tree_map(np.asarray, out)
                     for t in range(min(width, n_pairs - s)):
@@ -582,31 +566,20 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
                             jax.tree_util.tree_map(
                                 lambda a, t=t: a[t], out_np), V_np[j])
                 else:
-                    pend.append((s, width, out))
+                    pend.append((s, out))
                 s += width
-                if calibrate is not None and s < n_pairs:
-                    new_w = max(1, min(calibrate(static, idxs, dt, width,
-                                                 n_pairs - s, span.clean),
-                                       n_pairs - s))
-                    if new_w != width:
-                        # same jitted fn — the new chunk shape compiles on
-                        # first use and persists in the compile cache
-                        log.info("sweep dispatch width recalibrated "
-                                 "%d -> %d (measured %.1fs)", width, new_w, dt)
-                        width = new_w
             if pend:
                 with TRACER.span(f"sweep:fetch:{family}",
                                  category="sweep_fetch"):
                     flat = np.asarray(jnp.concatenate(
-                        [jnp.asarray(o, jnp.float32) for _, _, o in pend]))
-                off = 0
-                for s0, w0, _ in pend:
-                    for t in range(min(w0, n_pairs - s0)):
+                        [jnp.asarray(o, jnp.float32) for _, o in pend]))
+                for c, (s0, _) in enumerate(pend):
+                    for t in range(min(width, n_pairs - s0)):
                         row_i, j = divmod(s0 + t, n_folds)
                         if metrics[idxs[row_i]] is None:
                             metrics[idxs[row_i]] = [None] * n_folds  # type: ignore
-                        metrics[idxs[row_i]][j] = float(flat[off + t])  # type: ignore
-                    off += w0
+                        metrics[idxs[row_i]][j] = float(  # type: ignore
+                            flat[c * width + t])
             return
 
         batched = grid_vmap(static, idxs) or sharding is not None
@@ -630,13 +603,10 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
                 metrics[grid_i] = [float(m) for m in gk[row_i]]
 
     # groups run SEQUENTIALLY on purpose (families already overlap on
-    # the selector's thread pool): fanning groups out as well would (a)
+    # the selector's thread pool): fanning groups out as well would
     # multiply concurrently-live dispatch chunks past the per-dispatch
-    # _PAIR_MEM_BYTES budget (device OOM faults poison the process on
-    # this serving stack), (b) poison the persisted width calibration
-    # with queue-contention time — and width feeds compiled dispatch
-    # shapes, defeating the stable-shape/persistent-cache strategy, and
-    # (c) let later groups reuse calibration learned by earlier ones.
+    # memory budget (`dispatch_plan`; device OOM faults poison the
+    # process on this serving stack).
     _run_groups_resilient(
         groups, _run_group,
         commit=lambda idxs, block_s=None, facts=None: _journal_commit(
@@ -974,211 +944,6 @@ def _sweep_mlp(est, grids, X, y, W, V, metric_fn, ctx, sharding):
 # tree families: padded-depth trick, one compile per (bins, trees) group      #
 # --------------------------------------------------------------------------- #
 
-# host-dispatch batching model: how many grid×fold pairs fit in one
-# dispatch. The work unit is learners × rows × nodes × operand slots
-# (`hist_slots`: bins a wide column, 2 an indicator column) — the
-# histogram-matmul FLOP shape. The INITIAL per-family constants are
-# guesses; every real dispatch is then timed and the measured sec/unit
-# (EMA) replaces the guess for the rest of the process — a different TPU
-# generation or feature width recalibrates itself after one dispatch.
-# The exec target bounds one dispatch's wall (GBT early stopping is only
-# checked between dispatches); the memory bound caps the simultaneous
-# bin one-hots (n·slots bf16) plus deepest-level routing one-hots
-# (n·2^depth bf16). Both values predate PR 21's first direct chip run
-# and have not been re-measured since (ROADMAP).
-_PAIR_EXEC_TARGET_S = 25.0
-_PAIR_MEM_BYTES = 4 << 30
-# initial guesses: forest 0.9s / 20·90000·2^12·55·32,
-# gbt 0.55s / 50·90000·2^6·55·32
-_CALIB_INIT = {"forest": 2.8e-13, "gbt": 2.3e-12}
-_CALIB: Dict[str, float] = {}
-_CALIB_LOADED = False
-
-
-class SweepStats:
-    """Per-process dispatch accounting (SURVEY §5.1 'measure instead'):
-    how many timed dispatches the tree families' sweep loops made and
-    the thread-seconds they held (a first dispatch's compile included;
-    the `compile:sweep:dispatch:*` spans say how much of it that was).
-    `bench.py` resets before a sweep and reports the fraction."""
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self.reset()
-
-    def reset(self):
-        with self._lock:
-            self.dispatch_s = 0.0
-            self.dispatches = 0
-
-    def record(self, seconds: float) -> None:
-        with self._lock:
-            self.dispatch_s += seconds
-            self.dispatches += 1
-
-
-SWEEP_STATS = SweepStats()
-
-
-# Concurrent-dispatch detection: families sweep on the selector's thread
-# pool, so one family's `block_until_ready` wall-clock can include time
-# queued behind ANOTHER family's device execution. Feeding that inflated
-# measurement into `_record_calib` persists a too-slow sec/unit (the EMA
-# leans 0.7 toward slower), which shrinks dispatch widths and forces
-# fresh compiled shapes mid-sweep — exactly the instabilities the
-# sequential-groups comment in `_sweep_blocks` guards against (r4
-# advisor, medium). Every timed device dispatch wraps itself in
-# `_DispatchSpan`; a measurement is CLEAN only if no other span was live
-# at entry, none started before it exited, and it held no trace or
-# compile of its own program: a first dispatch's seconds of XLA in the
-# sec/unit would pick another width next pass, and a new width is a new
-# compile.
-_SPAN_LOCK = threading.Lock()
-_SPAN_ACTIVE = 0
-_SPAN_STARTS = 0
-
-
-class _DispatchSpan:
-    """Context manager around one timed device dispatch; `.clean` (valid
-    after exit) is True iff the wall-clock is device execution and
-    nothing else: no other dispatch overlapped it, and the program was
-    neither traced nor compiled inside it (a program's first dispatch,
-    or a new width's). For its lifetime it holds a
-    `sweep:dispatch:<family>` span open, so the dispatch — and the XLA
-    compile a first dispatch asks for, as the span's `compile:*` child —
-    sits in the run's timeline; the same span says whether it compiled:
-    `instrumented_jit` drops a `recompile` event on it when the program
-    is traced, `utils/compile_cache.py` a `compile_cache_hit`."""
-
-    def __init__(self, family: str):
-        self._span = TRACER.span(f"sweep:dispatch:{family}",
-                                 category="sweep_dispatch")
-
-    def __enter__(self):
-        global _SPAN_ACTIVE, _SPAN_STARTS
-        self._sp = self._span.__enter__()
-        with _SPAN_LOCK:
-            _SPAN_ACTIVE += 1
-            _SPAN_STARTS += 1
-            self._epoch = _SPAN_STARTS
-            self.clean = _SPAN_ACTIVE == 1
-        return self
-
-    def __exit__(self, *exc):
-        global _SPAN_ACTIVE
-        with _SPAN_LOCK:
-            _SPAN_ACTIVE -= 1
-            if _SPAN_STARTS != self._epoch:  # someone started during us
-                self.clean = False
-        if any(name in ("recompile", "compile_cache_hit")
-               for name, _, _ in self._sp.events):
-            self.clean = False
-        return bool(self._span.__exit__(*exc))
-
-
-def _calib_path() -> str:
-    import os
-    from transmogrifai_tpu.store.config import cache_root
-    return os.path.join(cache_root(), "sweep_calib.json")
-
-
-def _load_calib() -> None:
-    """Measured sec/unit persists beside the XLA compile cache so a NEW
-    process starts from the previous run's measurements — widths converge
-    to the same values run over run, which also keeps dispatch shapes
-    stable for the persistent compile cache."""
-    global _CALIB_LOADED
-    if _CALIB_LOADED:
-        return
-    _CALIB_LOADED = True
-    import json as _json
-    import os
-    try:
-        if os.path.exists(_calib_path()):
-            with open(_calib_path()) as f:
-                _CALIB.update({k: float(v) for k, v in _json.load(f).items()})
-    except (OSError, ValueError, TypeError):
-        log.debug("sweep calibration file unreadable; using initial "
-                  "estimates", exc_info=True)
-
-
-def _save_calib() -> None:
-    import json as _json
-    import os
-    try:
-        os.makedirs(os.path.dirname(_calib_path()), exist_ok=True)
-        tmp = _calib_path() + ".tmp"
-        with open(tmp, "w") as f:
-            _json.dump(_CALIB, f)
-        os.replace(tmp, _calib_path())
-    except OSError:
-        pass
-
-
-def _sec_per_unit(kind: str) -> float:
-    _load_calib()
-    return _CALIB.get(kind, _CALIB_INIT[kind])
-
-
-_CALIB_LOCK = threading.Lock()
-
-
-def _record_calib(kind: str, seconds: float, units: float) -> float:
-    """Fold one measured dispatch into the family's sec/unit estimate.
-    Callers pass CLEAN dispatches only (`_DispatchSpan.clean`: no overlap
-    with another family's dispatch, no trace or compile inside): the
-    estimate feeds `_tree_pair_width` and the boosted family's rounds
-    per dispatch, which are compiled shapes, so it has to read the same
-    from pass to pass. Conservative EMA: jumps fast on
-    slower-than-expected, slow on faster (an over-wide dispatch costs
-    HBM and early-stop granularity, an under-wide one only dispatch
-    overhead). Locked: families sweep on a thread
-    pool, and a racy read-modify-write (or two writers interleaving the
-    same .tmp file) would corrupt the persisted calibration the stable-
-    shape strategy depends on."""
-    if units <= 0:
-        return _sec_per_unit(kind)
-    with _CALIB_LOCK:
-        measured = max(seconds, 1e-4) / units
-        # the lock intentionally covers the read-modify-write AND the
-        # persisted .tmp/replace below: two interleaved writers would
-        # corrupt the calibration file (see docstring)
-        # conc-ok: C003 (calibration RMW + persist must be atomic)
-        prev = _sec_per_unit(kind) if kind in _CALIB else None
-        if prev is None:
-            new = measured
-        elif measured > prev:
-            new = 0.3 * prev + 0.7 * measured
-        else:
-            new = 0.7 * prev + 0.3 * measured
-        _CALIB[kind] = new
-        # conc-ok: C003 (calibration RMW + persist must be atomic)
-        _save_calib()
-        return new
-
-
-def _pow2_floor(x: int) -> int:
-    return 1 << max(0, int(x).bit_length() - 1)
-
-
-def _tree_pair_width(n: int, slots: int, learners: int,
-                     sec_per_unit: float, pad_depth: int) -> int:
-    """Grid×fold pairs a tree dispatch vmaps; `slots` is the histogram
-    operand's width a row (`hist_slots`: bins a wide column, 2 an
-    indicator column)."""
-    nodes = 2 ** min(pad_depth, 14)
-    est_s = max(0.05, float(learners) * n * nodes * slots * sec_per_unit)
-    # bf16 bytes of the bin one-hots and the deepest level's routing
-    # one-hot, as if every pair held its own: the bin one-hots are built
-    # once a dispatch and shared by its pairs, so this over-counts them
-    mem_per_pair = n * (slots + nodes) * 2
-    w_exec = int(_PAIR_EXEC_TARGET_S / est_s)
-    w_mem = int(_PAIR_MEM_BYTES // max(mem_per_pair, 1))
-    # power-of-2 width: small calibration drift between runs must not
-    # change the dispatch shape (every distinct width is a fresh XLA
-    # compile that misses the persistent cache)
-    return _pow2_floor(max(1, min(w_exec, w_mem)))
-
 def _binned_cache(est, grids, X, ctx) -> Tuple[
         Dict[int, jnp.ndarray], Optional[Dict], Optional[Tuple[int, int]]]:
     """Bin X once per distinct max_bins ACROSS tree families in a sweep:
@@ -1274,35 +1039,11 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
 
     def width_of(st, idxs):
         n_trees, max_bins, _ = st[:3]
-        pad_depth = _pad_depth_of(est, grids, idxs)
-        # real dispatch width never exceeds the pair count — keep the
-        # fit_forest chunk budget in step with actual live instances
-        return min(len(idxs) * n_folds,
-                   _tree_pair_width(n_rows,
-                                    hist_slots(d_feat, max_bins, layout),
-                                    n_trees, _sec_per_unit("forest"),
-                                    pad_depth))
-
-    def calibrate(st, idxs, seconds, width, remaining, clean):
-        n_trees, max_bins, _ = st[:3]
-        pad_depth = _pad_depth_of(est, grids, idxs)
-        slots = hist_slots(d_feat, max_bins, layout)
-        units = (float(width) * n_trees * n_rows
-                 * (2 ** min(pad_depth, 14)) * slots)
-        # an overlapped wall-clock includes another family's queue time,
-        # one that traced or compiled includes XLA's — never let either
-        # reach the persisted calibration or GROW compiled dispatch shapes
-        if not clean:
-            return width
-        spu = _record_calib("forest", seconds, units)
-        ideal = _tree_pair_width(n_rows, slots, n_trees, spu, pad_depth)
-        # a resize recompiles: grow only when the
-        # dispatch badly underfills the exec target AND enough pairs
-        # remain to amortize the new program
-        if (ideal >= 2 * width and remaining >= 2 * width
-                and seconds < 0.3 * _PAIR_EXEC_TARGET_S):
-            return min(ideal, remaining)
-        return width
+        # capped at the pair count itself — keeps the fit_forest chunk
+        # budget in step with actual live instances
+        return dispatch_plan(n_rows, hist_slots(d_feat, max_bins, layout),
+                             _pad_depth_of(est, grids, idxs), n_trees,
+                             len(idxs) * n_folds)[0]
 
     def shape_of(st, idxs):
         # unsharded → host dispatch of `width` vmapped pairs at a time;
@@ -1335,7 +1076,7 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
         grid_vmap=lambda st, idxs: _pad_depth_of(est, grids, idxs) <= 6,
         host_dispatch=True,
         pair_width=lambda st, idxs, k: width_of(st, idxs),
-        calibrate=calibrate, x_info=_x_info(X))
+        x_info=_x_info(X))
 
 
 @functools.lru_cache(maxsize=_HELD_PROGRAMS)
@@ -1385,7 +1126,6 @@ def _gbt_score_program(static: Tuple, objective: str,
 
 
 def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
-    from transmogrifai_tpu.models.trees import _pick_rounds_per_dispatch
     xb_by_bins, layout, blocks = _binned_cache(est, grids, X, ctx)
     objective = est._objective
     n_classes = 2
@@ -1437,12 +1177,10 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
         # over the grid axis
         def width_of(st, idxs):
             n_estimators, max_bins = st[0], st[1]
-            pad_depth = _pad_depth_of(est, grids, idxs)
-            return min(len(idxs) * n_folds,
-                       _tree_pair_width(n_rows,
-                                        hist_slots(d_feat, max_bins, layout),
-                                        n_estimators, _sec_per_unit("gbt"),
-                                        pad_depth))
+            return dispatch_plan(
+                n_rows, hist_slots(d_feat, max_bins, layout),
+                _pad_depth_of(est, grids, idxs), n_estimators,
+                len(idxs) * n_folds)[0]
 
         return _sweep_blocks(
             grids, W, V, metric_fn, sharding, "gbt",
@@ -1482,11 +1220,13 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                               else jnp.float32)
                for k in dyn_dicts[0]}
         n_pairs = len(idxs) * n_folds
-        nodes = 2 ** min(pad_depth, 14)
-        slots = hist_slots(d_feat, max_bins, layout)
-        upr = float(n_rows) * nodes * slots  # units/round/pair
-        mem_per_pair = n_rows * (slots + nodes) * 2  # as _tree_pair_width
-        w_mem = max(1, int(_PAIR_MEM_BYTES // mem_per_pair))
+        # a power-of-two width, NOT clamped to the remaining pair count:
+        # the pair-index padding (`ps` repeats the last pair) keeps the
+        # tail chunk at the same compiled shape instead of forcing a
+        # second compile; `rpd` divides the rounds, so one chunk shape
+        width, rpd = dispatch_plan(
+            n_rows, hist_slots(d_feat, max_bins, layout), pad_depth, n_est,
+            n_pairs, pad_tail=True)
 
         prog = _gbt_rounds_program(static, pad_depth, objective, eval_metric,
                                    blocks)
@@ -1496,18 +1236,6 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
 
         s = 0
         while s < n_pairs:
-            spu = _sec_per_unit("gbt")
-            # power-of-2 width + divisor-quantized rounds: calibration
-            # drift between runs must not change compiled dispatch shapes.
-            # NOT clamped to the remaining pair count — the pair-index
-            # padding (`ps` repeats the last pair) keeps the tail chunk at
-            # the same compiled shape instead of forcing a second compile
-            width = _pow2_floor(max(1, min(
-                n_pairs, w_mem, int(_PAIR_EXEC_TARGET_S
-                                    / max(n_est * upr * spu, 1e-9)))))
-            rpd = _pick_rounds_per_dispatch(
-                n_est, _pow2_floor(max(1, int(
-                    _PAIR_EXEC_TARGET_S / max(width * upr * spu, 1e-9)))))
             ps = [min(s + t, n_pairs - 1) for t in range(width)]
             gs = [p // n_folds for p in ps]
             fs = [p % n_folds for p in ps]
@@ -1520,18 +1248,11 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             done = 0
             while done < n_est:
                 ks = keys_all[done:done + rpd]
-                with _DispatchSpan("gbt") as span:
-                    t0 = time.perf_counter()
+                with _dispatch_span("gbt", timed=True):
                     margin, best, since = jax.block_until_ready(
                         prog(data, dchunk, Wsel, Vsel, margin, best, since,
                              ks))
-                    dt = time.perf_counter() - t0
-                SWEEP_STATS.record(dt)
                 done += int(ks.shape[0])
-                # overlapped or compiling wall-clock never enters calib
-                if span.clean:
-                    _record_calib(
-                        "gbt", dt, float(width) * int(ks.shape[0]) * upr)
                 if (esr > 0 and done < n_est
                         and bool(np.all(np.asarray(since) >= esr))):
                     log.info("gbt sweep: early stop after %d/%d rounds "

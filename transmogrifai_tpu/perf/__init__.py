@@ -3,12 +3,10 @@
 The repo emits a timed profile corpus on every run (journal
 `duration_s` stamps, `IngestStats`, serving latency histograms); this
 package fits a small per-target predictor on it (`perf/model.py`) and
-closes the loop into four consumers:
+closes the loop into three consumers:
 
 - `parallel/scheduler.py` orders grid blocks by PREDICTED seconds (true
   LPT) and sizes block widths toward a seconds-per-block target;
-- `parallel/sweep.py` pre-shrinks blocks whose predicted HBM footprint
-  exceeds the budget instead of paying an OOM-redo first;
 - `parallel/bigdata.py` picks upload workers/depth from the predicted
   read-vs-upload balance;
 - `serving/batcher.py` derives the bucket ladder from the observed
@@ -26,14 +24,13 @@ from transmogrifai_tpu.perf.corpus import (
     CostCorpus, device_generation, get_corpus, harvest_journal, note,
     note_parse, note_serving)
 from transmogrifai_tpu.perf.features import (
-    block_features, hbm_proxy_bytes, ingest_features, parse_features,
-    serving_features)
+    block_features, ingest_features, parse_features, serving_features)
 from transmogrifai_tpu.perf.model import (
     CostModel, Prediction, choose_upload_plan, fit_corpus, get_model,
     holdout_mape, observe, predict_block_seconds, predict_bucket_seconds,
     predict_drain_seconds, predict_sweep_seconds, refresh, set_model)
 from transmogrifai_tpu.perf.params import (
-    PerfModelParams, enabled, get_params, hbm_budget_bytes, params_scope,
+    PerfModelParams, enabled, get_params, params_scope,
     resolved_corpus_dir, set_params, target_block_s)
 
 __all__ = [
@@ -41,7 +38,7 @@ __all__ = [
     "block_features", "choose_upload_plan", "device_generation",
     "enabled", "fit_corpus",
     "get_corpus", "get_model", "get_params", "harvest_journal",
-    "hbm_budget_bytes", "hbm_proxy_bytes", "holdout_mape",
+    "holdout_mape",
     "ingest_features", "note", "note_parse", "note_serving", "observe",
     "params_scope", "parse_features", "predict_block_seconds",
     "predict_bucket_seconds", "predict_drain_seconds",

@@ -59,8 +59,7 @@ ENV_REPLICA = "TRANSMOGRIFAI_PERF_REPLICA"
 ENV_DEVGEN = "TRANSMOGRIFAI_PERF_DEVGEN"
 
 # targets the model learns; anything else is ignored at fit time
-TARGETS = ("block_runtime", "hbm", "ingest", "serving_bucket",
-           "serving_parse")
+TARGETS = ("block_runtime", "ingest", "serving_bucket", "serving_parse")
 
 _DEVGEN_LOCK = threading.Lock()
 _DEVGEN: Optional[str] = None  # guarded-by: _DEVGEN_LOCK

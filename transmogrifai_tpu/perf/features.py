@@ -1,7 +1,7 @@
 """Feature engineering for the cost model: one dict of numeric features
 per decision, shared by the RECORDING side (parallel/sweep.py journaling
-measured block wall times) and the PREDICTION side (the scheduler, the
-HBM gate, bench extrapolations) — the two must agree on names or the
+measured block wall times) and the PREDICTION side (the scheduler,
+bench extrapolations) — the two must agree on names or the
 model silently predicts garbage for half its consumers.
 
 The static-signature layouts mirrored here are the module-level
@@ -15,8 +15,8 @@ from __future__ import annotations
 
 from typing import Dict, Tuple
 
-__all__ = ["block_features", "hbm_proxy_bytes", "ingest_features",
-           "parse_features", "serving_features"]
+__all__ = ["block_features", "ingest_features", "parse_features",
+           "serving_features"]
 
 
 def block_features(family: str, static: Tuple, n_configs: int,
@@ -59,25 +59,6 @@ def block_features(family: str, static: Tuple, n_configs: int,
     except (IndexError, TypeError, ValueError):
         pass  # foreign static layout: shape facts still predict coarsely
     return f
-
-
-def hbm_proxy_bytes(feats: Dict[str, float]) -> float:
-    """Analytic peak-HBM proxy for a block, in bytes — the 'observed
-    peak-HBM proxy' training target. Tree families: per-pair bin
-    one-hots (n·d·bins bf16) plus deepest-level routing one-hots
-    (n·nodes bf16), times the grid×fold pairs simultaneously live
-    (mirrors `_tree_pair_width`'s memory bound in parallel/sweep.py).
-    Linear-likes: the per-config parameter/logit working set on top of
-    the shared X."""
-    n = feats.get("n_rows", 0.0)
-    d = feats.get("n_cols", 0.0)
-    pairs = feats.get("n_configs", 1.0) * max(feats.get("n_folds", 1.0), 1.0)
-    if feats.get("nodes"):
-        per_pair = n * (d * max(feats.get("bins", 1.0), 1.0)
-                        + feats["nodes"]) * 2.0
-        return pairs * per_pair
-    # linear-likes: X (shared) + per-pair logits/params f32
-    return n * d * feats.get("dtype_bytes", 4.0) + pairs * n * 4.0
 
 
 def ingest_features(bytes_wire: float, workers: int, depth: int,

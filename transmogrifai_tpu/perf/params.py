@@ -16,7 +16,6 @@ params file or CLI flag always wins:
 - ``TRANSMOGRIFAI_PERF_MODEL=0``       kill switch (all consumers cold)
 - ``TRANSMOGRIFAI_PERF_CORPUS_DIR``    corpus directory
 - ``TRANSMOGRIFAI_PERF_TARGET_BLOCK_S``scheduler seconds-per-block target
-- ``TRANSMOGRIFAI_PERF_HBM_BUDGET_GB`` pre-dispatch HBM gate budget
 """
 
 from __future__ import annotations
@@ -28,14 +27,8 @@ from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
 __all__ = ["PerfModelParams", "get_params", "set_params", "params_scope",
-           "enabled", "resolved_corpus_dir", "target_block_s",
-           "hbm_budget_bytes"]
+           "enabled", "resolved_corpus_dir", "target_block_s"]
 
-# today's pre-dispatch budget heuristic is "none" — the HBM gate only
-# fires when a budget is configured OR the model is warm enough to
-# predict a footprint; the default budget matches the sweep's dispatch
-# memory plan (_PAIR_MEM_BYTES in parallel/sweep.py)
-_DEFAULT_HBM_BUDGET_GB = 4.0
 _DEFAULT_TARGET_BLOCK_S = 30.0
 
 
@@ -53,11 +46,10 @@ class PerfModelParams:
     corpus_dir: Optional[str] = None      # default: env / <store root>/perf
     model_path: Optional[str] = None      # fitted model JSON to load
     target_block_s: Optional[float] = None  # scheduler width sizing
-    hbm_budget_gb: Optional[float] = None   # pre-dispatch OOM gate
     min_rows: int = 8                     # per-target cold-start floor
 
     _FIELDS = ("enabled", "corpus_dir", "model_path", "target_block_s",
-               "hbm_budget_gb", "min_rows")
+               "min_rows")
 
     @staticmethod
     def from_json(d: Dict[str, Any]) -> "PerfModelParams":
@@ -135,13 +127,3 @@ def target_block_s() -> float:
     except ValueError:
         return _DEFAULT_TARGET_BLOCK_S
 
-
-def hbm_budget_bytes() -> float:
-    if _PARAMS.hbm_budget_gb is not None:
-        return float(_PARAMS.hbm_budget_gb) * 2.0 ** 30
-    try:
-        gb = float(os.environ.get("TRANSMOGRIFAI_PERF_HBM_BUDGET_GB",
-                                  _DEFAULT_HBM_BUDGET_GB))
-    except ValueError:
-        gb = _DEFAULT_HBM_BUDGET_GB
-    return gb * 2.0 ** 30

@@ -61,14 +61,6 @@ def synth_corpus(corpus, seed: int = 11) -> None:
         for _ in range(4):
             lat = (0.002 + 2e-5 * bucket) * noise(0.08)
             corpus.append("serving_bucket", {"bucket": bucket}, lat)
-    for n_configs in (1, 2, 4, 8):
-        for n_rows in (10_000, 50_000, 200_000):
-            hbm = n_configs * 3.0 * n_rows * (50 * 32 + 64) * 2.0
-            corpus.append("hbm", {
-                "n_configs": n_configs, "n_rows": n_rows, "n_cols": 50,
-                "n_folds": 3, "dtype_bytes": 4, "fam_forest": 1.0,
-                "learners": 20, "bins": 32, "depth": 6, "nodes": 64},
-                hbm * noise(0.05))
 
 
 def _measured_schedule(selector_fn, cols, n_rows, mesh, label: str
@@ -105,7 +97,7 @@ def run_costmodel_bench(n_devices: int = 8,
     with tempfile.TemporaryDirectory(prefix="costmodel-synth-") as tmp:
         synth = perf.CostCorpus(tmp)
         synth_corpus(synth)
-        for target in ("block_runtime", "ingest", "serving_bucket", "hbm"):
+        for target in ("block_runtime", "ingest", "serving_bucket"):
             mape = perf.holdout_mape(synth, target)
             payload[f"holdout_mape_{target}"] = (
                 round(mape, 4) if mape is not None else None)
@@ -175,7 +167,7 @@ def _smoke() -> int:
     mape = payload.get("holdout_mape_block_runtime")
     assert mape is not None and mape < MAPE_GATE, (
         f"block-runtime holdout MAPE {mape} over the {MAPE_GATE} gate")
-    for target in ("ingest", "serving_bucket", "hbm"):
+    for target in ("ingest", "serving_bucket"):
         m = payload.get(f"holdout_mape_{target}")
         assert m is not None and m < MAPE_GATE, (
             f"{target} holdout MAPE {m} over the {MAPE_GATE} gate")

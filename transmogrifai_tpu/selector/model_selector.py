@@ -35,6 +35,10 @@ from transmogrifai_tpu.stages.base import Estimator, FitContext, Transformer
 
 log = logging.getLogger(__name__)
 
+# Families sweep on a thread pool of the reference's width (Parallelism=8,
+# the Future-per-fit pool of OpValidator.scala:374).
+_FAMILY_THREADS = 8
+
 
 @dataclass
 class ValidationResult:
@@ -211,17 +215,14 @@ class ModelSelector(Estimator):
                     self._save_checkpoint(ckpt, grid_fold)
                     return grid_fold
 
-            # Families run on a thread pool (the reference's Parallelism=8
-            # Future-per-fit pool, OpValidator.scala:374): device
+            # Families run on a thread pool (`_FAMILY_THREADS`): device
             # executions serialize on the chip anyway, but one family's
             # XLA compiles overlap another's compiles AND executions —
             # the dominant cold-process cost. Threads only help a fresh
             # process; a warm compile cache degrades gracefully to
             # interleaved execution.
-            import os as _os
             from concurrent.futures import ThreadPoolExecutor
-            par = min(len(self.models), int(_os.environ.get(
-                "TRANSMOGRIFAI_SWEEP_PARALLELISM", "8")))
+            par = min(len(self.models), _FAMILY_THREADS)
             if use_scheduler:
                 outcomes = self._sweep_scheduled(
                     ctx, X, y_dev, folds, data_digest)

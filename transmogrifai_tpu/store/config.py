@@ -1,13 +1,13 @@
 """One resolution point for every shared on-disk location.
 
 `TRANSMOGRIFAI_STORE_DIR` moves the WHOLE root (feature cache, perf
-corpus, sweep calibration, warm-up manifests, XLA compile cache all
-follow), while each subsystem's own env var still wins for its subtree
-(for the compile cache that is the standard `JAX_COMPILATION_CACHE_DIR`,
-utils/compile_cache.py). Unset, the root is
-`DEFAULT_ROOT`: a fixed, git-ignored directory INSIDE the checkout, so
-state a run learns (dispatch-width calibration feeds compiled shapes)
-never passes between two checkouts on one machine through `$HOME`.
+corpus, warm-up manifests, XLA compile cache all follow), while each
+subsystem's own env var still wins for its subtree (for the compile
+cache that is the standard `JAX_COMPILATION_CACHE_DIR`,
+utils/compile_cache.py). Unset, the root is `DEFAULT_ROOT`: a fixed,
+git-ignored directory INSIDE the checkout, so what a run keeps (the
+compile cache's keys hold the path) never passes between two checkouts
+on one machine through `$HOME`.
 """
 
 from __future__ import annotations
