@@ -427,6 +427,15 @@ def _lower_bin():
     return jax.jit(trees.bin_features).lower(X, edges)
 
 
+def _lower_edges():
+    """The order statistics behind `quantile_bin_edges` of a device
+    matrix (the scope has to reach into the loop over its columns)."""
+    X = jnp.ones((16, 3), jnp.float32)
+    return trees._order_statistics.lower(
+        X, jnp.asarray([0, 2], jnp.int32),
+        jnp.asarray([3, 4, 7, 8], jnp.int32))
+
+
 def _lower_predict():
     Xb, G, H = _tree_inputs()
     tree = trees.grow_tree(Xb, G, H, 2, 4)
@@ -467,6 +476,7 @@ SCOPES = [
     ("tree:hist", _lower_grow_tree), ("tree:split", _lower_grow_tree),
     ("tree:route", _lower_grow_tree), ("tree:bootstrap", _lower_forest),
     ("tree:hist", _lower_forest), ("tree:bin", _lower_bin),
+    ("tree:edges", _lower_edges),
     ("tree:hist:wide", _lower_grow_tree),
     ("tree:hist:wide", _lower_grow_tree_two_blocks),
     ("tree:hist:ind", _lower_grow_tree_two_blocks),
@@ -532,6 +542,7 @@ PASS_A = {"wall_s": 20.0, "spans": [
     ("sweep:family:OpXGBoostClassifier", 12.0), ("sweep:block", 11.0),
     ("sweep:dispatch:gbt", 6.0), ("sweep:dispatch:gbt", 1.0),
     ("sweep:dispatch:logistic", 3.0), ("sweep:fetch:gbt", 0.5),
+    ("sweep:bin", 4.0), ("sweep:bin", 0.5), ("tree:edges", 0.25),
     ("compile:sweep:dispatch:gbt/jit(chunk_pair)", 4.0),
     ("compile:sweep:dispatch:logistic/jit(one_cfg)", 2.0),
     ("compile:selector:refit/jit(fit_gbt)", 2.5),
@@ -541,7 +552,8 @@ PASS_B = {"wall_s": 10.0, "spans": [
     ("stage:fit:ModelSelector", 8.0), ("stage:transform:ModelSelector", 0.5),
     ("selector:prepare", 1.0), ("selector:sweep", 5.0),
     ("selector:refit", 1.0), ("selector:evaluate", 0.5),
-    ("sweep:dispatch:gbt", 2.0), ("sweep:dispatch:logistic", 1.0)]}
+    ("sweep:dispatch:gbt", 2.0), ("sweep:dispatch:logistic", 1.0),
+    ("sweep:bin", 1.5)]}
 # a pass of a program from before these spans existed
 PASS_OLD = {"wall_s": 20.0, "spans": [
     ("stage:fit:RealVectorizer", 0.5), ("stage:fit:ModelSelector", 17.0),
@@ -617,6 +629,7 @@ READINGS = {
     "train_sweep_wait_s": (4.0, 3.5),
     "train_refit_s": (3.0, 2.0),
     "train_evaluate_s": (0.5, 0.5),
+    "train_bin_s": (4.5, 3.0),
     # A: (20 - 18.5 - 1) + (17 - 16.5); B: (10 - 8.5 - 1) + (8 - 7.5)
     "train_unspanned_s": (1.0, 1.0),
 }

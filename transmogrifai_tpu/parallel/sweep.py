@@ -71,7 +71,8 @@ from transmogrifai_tpu.models.trees import (
     bin_features, dispatch_plan, fit_forest, fit_gbt, fit_gbt_multiclass,
     forest_classification_pred, forest_regression_pred,
     gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
-    hist_layout, hist_slots, indicator_columns, quantile_bin_edges)
+    edges_site, hist_layout, hist_slots, indicator_columns,
+    quantile_bin_edges)
 from transmogrifai_tpu.runtime.faults import (
     SITE_RUN_BLOCK, fault_point, is_oom_error)
 
@@ -956,8 +957,9 @@ def _binned_cache(est, grids, X, ctx) -> Tuple[
 
     Quantile edges come from the UNPADDED rows (`ctx._sweep_n_rows`): mesh
     padding appends zero-weight rows which must not shift bin edges, or
-    sharded sweeps would silently deviate from unsharded ones. Only the
-    non-indicator columns cross to the host for them.
+    sharded sweeps would silently deviate from unsharded ones. Of a
+    device matrix only the non-indicator columns' order statistics cross
+    to the host for them (the span's `edges` attribute says which).
 
     Guarded by a lock: tree families now sweep on a thread pool, and two
     families hitting the same max_bins must not double-build the (n, d)
@@ -985,7 +987,8 @@ def _binned_cache(est, grids, X, ctx) -> Tuple[
                 edges = quantile_bin_edges(X_edges, mb, out["indicator"])
                 out[mb] = bin_features(jnp.asarray(X), jnp.asarray(edges))
                 sp.set(hist_slots=hist_slots(int(X.shape[1]), mb,
-                                             out["layout"]))
+                                             out["layout"]),
+                       edges=edges_site(X_edges))
         layout = out.get("layout")
         blocks = None if layout is None else tuple(
             int(layout[b].shape[0]) for b in ("wide", "ind"))
@@ -1370,8 +1373,8 @@ def _run_sweep(est, grids: List[Dict], X, y, folds, evaluator, ctx,
     # families arrive together on the selector's thread pool: ONE of them
     # prepares the sweep's shared data, the others wait and reuse it (two
     # that both miss would each reset the binned-X cache under the other).
-    # A lock of its own: `_BIN_CACHE_LOCK` is held through the host
-    # quantiles, seconds a linear family has no reason to wait for
+    # A lock of its own: `_BIN_CACHE_LOCK` is held through the tree
+    # families' binning, which a linear family has no reason to wait for
     with _SWEEP_DATA_LOCK:
         cached = getattr(ctx, "_sweep_data_cache", None) if ctx is not None else None
         if cached is not None and _same_data(cached[0]):
@@ -1419,7 +1422,7 @@ def _run_sweep(est, grids: List[Dict], X, y, folds, evaluator, ctx,
                 # 0/1 indicators (the tree histograms' layout) is read off
                 # the matrix HERE, before any family has a program in the
                 # device's queue: from a tree family's thread the reduction
-                # would wait behind the logistic block, and the host
-                # quantiles behind it
+                # would wait behind the logistic block, and the bin edges
+                # behind it
                 ctx._sweep_bin_cache = {"indicator": indicator_columns(X)}
     return handler(est, grids, X, y, W, V, metric_fn, ctx, sharding)
