@@ -22,7 +22,8 @@ import pytest
 from transmogrifai_tpu.automl import transmogrify
 from transmogrifai_tpu.data import Dataset
 from transmogrifai_tpu.evaluators import (
-    BinaryClassificationEvaluator, RegressionEvaluator)
+    BinaryClassificationEvaluator, MultiClassificationEvaluator,
+    RegressionEvaluator)
 from transmogrifai_tpu.evaluators.device_metrics import make_device_metric
 from transmogrifai_tpu.features import FeatureBuilder
 from transmogrifai_tpu.models import (
@@ -390,6 +391,75 @@ def test_binning_is_a_span_with_the_operands_slots(typed_spans):
     assert {s.attributes["max_bins"] for s in bins} == {8}
 
 
+@pytest.fixture(scope="module")
+def multi_spans():
+    """One tiny train of a four-label table through the multiclass
+    selector (the top label never falls): (root, spans)."""
+    from transmogrifai_tpu import types as T
+    from transmogrifai_tpu.automl.sanity_checker import SanityChecker
+    from transmogrifai_tpu.selector import (
+        DataCutter, MultiClassificationModelSelector)
+    rng = np.random.default_rng(4)
+    n = 300
+    y = rng.choice(3, size=n, p=[0.6, 0.3, 0.1]).astype(np.float64)
+    ds = Dataset(
+        {"rate": np.round(rng.uniform(size=n), 2) + 0.3 * y,
+         "count": np.floor(np.exp(rng.normal(1.0, 1.0, n))) + 2 * y,
+         "flag": (rng.uniform(size=n) < 0.3 + 0.2 * y) * 1.0, "y": y},
+        {"rate": T.Real, "count": T.Integral, "flag": T.Binary,
+         "y": T.Integral})
+    preds, label = FeatureBuilder.from_dataset(ds, response="y")
+    checked = SanityChecker().set_input(
+        label, transmogrify(preds)).get_output()
+    sel = MultiClassificationModelSelector.with_cross_validation(
+        models=[FAMILIES[f] for f in ("logistic", "forest")], n_folds=2,
+        splitter=DataCutter(reserve_test_fraction=0.2), n_classes=4)
+    pf = sel.set_input(label, checked).get_output()
+    with TRACER.span("run:train-multi", new_trace=True) as root:
+        Workflow().set_result_features(pf, label) \
+            .set_input_dataset(ds).train()
+    return root, TRACER.trace_spans(root.trace_id)
+
+
+def test_cutter_is_a_span_under_prepare_with_its_counts(multi_spans):
+    _, spans = multi_spans
+    cut, = [s for s in spans if s.name == "cutter:prepare"]
+    by_id = {s.span_id: s for s in spans}
+    assert by_id[cut.parent_id].name == "selector:prepare"
+    assert cut.attributes == {"labels_seen": 3, "labels_kept": 3,
+                              "rows_dropped": 0}
+
+
+def test_sweep_states_the_classes_and_the_operands_reads(multi_spans):
+    _, spans = multi_spans
+    sweep, = [s for s in spans if s.name == "selector:sweep"]
+    assert sweep.attributes["classes"] == 4     # stated, not max(y) + 1
+    binned, = [s for s in spans if s.name == "sweep:bin"]
+    assert binned.attributes["value_columns"] == 4
+    assert binned.attributes["hist_reads"] == 1     # one composite read
+
+
+@pytest.mark.parametrize("est,y_of,columns,reads", [
+    (lambda: OpRandomForestClassifier(n_trees=1, max_depth=2, max_bins=8,
+                                      n_classes=5),
+     lambda rng, n: rng.integers(0, 3, n), 5, 1),
+    (lambda: trees.OpRandomForestRegressor(n_trees=1, max_depth=2,
+                                           max_bins=8),
+     lambda rng, n: rng.normal(size=n), 1, 2),
+], ids=["classifier", "regressor"])
+def test_an_estimators_own_binning_states_value_columns_and_reads(
+        est, y_of, columns, reads):
+    rng = np.random.default_rng(0)
+    X = jnp.asarray(rng.normal(size=(64, 3)), jnp.float32)
+    y = jnp.asarray(y_of(rng, 64), jnp.float32)
+    with TRACER.span("run:edges", new_trace=True) as root:
+        est().fit_arrays(X, y, jnp.ones(64), FitContext(n_rows=64, seed=1))
+    edges, = [s for s in TRACER.trace_spans(root.trace_id)
+              if s.name == "tree:edges"]
+    assert edges.attributes["value_columns"] == columns
+    assert edges.attributes["hist_reads"] == reads
+
+
 # --------------------------------------------------------------------- #
 # E. stable kernel names                                                #
 # --------------------------------------------------------------------- #
@@ -419,6 +489,42 @@ def _lower_grow_tree_two_blocks():
     layout = trees.hist_layout(np.asarray([False, True, True]))
     return jax.jit(lambda a, g, h, lay: trees.grow_tree(
         a, g, h, 2, 4, layout=lay)).lower(Xb, G, H, layout)
+
+
+def _lower_grow_tree_classes(layout=None):
+    """A classifier's tree: labels in, the composite class histograms."""
+    Xb, _, H = _tree_inputs()
+    y = jnp.asarray(np.arange(64) % 3, jnp.int32)
+    if layout is None:
+        return jax.jit(lambda a, g, h: trees.grow_tree(
+            a, g, h, 2, 4, n_classes=3)).lower(Xb, y, H)
+    return jax.jit(lambda a, g, h, lay: trees.grow_tree(
+        a, g, h, 2, 4, layout=lay, n_classes=3)).lower(Xb, y, H, layout)
+
+
+def _lower_grow_tree_classes_two_blocks():
+    return _lower_grow_tree_classes(
+        trees.hist_layout(np.asarray([False, True, True])))
+
+
+def _lower_multiclass_metric(batch=None):
+    """The weighted F1's program (the confusion product inside it); with
+    `batch`, under the sweep's vmap over predictions and fold masks."""
+    fn = make_device_metric(MultiClassificationEvaluator("F1"), n_classes=5)
+    y = jnp.asarray(np.arange(16) % 4, jnp.float32)
+    p, m = jnp.asarray(np.arange(16) % 3, jnp.float32), jnp.ones(16)
+
+    def one(pred, mask):
+        return fn(y, {"prediction": pred}, mask)
+
+    if batch is None:
+        return jax.jit(one).lower(p, m)
+    return jax.jit(jax.vmap(one)).lower(jnp.tile(p, (batch, 1)),
+                                        jnp.tile(m, (batch, 1)))
+
+
+def _lower_multiclass_metric_vmap():
+    return _lower_multiclass_metric(3)
 
 
 def _lower_bin():
@@ -489,6 +595,12 @@ SCOPES = [
         BinaryClassificationEvaluator("AuROC"), "prediction")),
     ("metric:rmse", lambda: _lower_metric(
         RegressionEvaluator(), "prediction")),
+    ("tree:hist:classes", _lower_grow_tree_classes),
+    ("tree:hist:wide", _lower_grow_tree_classes),
+    ("tree:hist:classes", _lower_grow_tree_classes_two_blocks),
+    ("tree:hist:ind", _lower_grow_tree_classes_two_blocks),
+    ("metric:f1", _lower_multiclass_metric),
+    ("metric:f1", _lower_multiclass_metric_vmap),
 ]
 
 
@@ -519,6 +631,28 @@ def test_rank_metric_programs_hold_no_loop_and_no_gather(metric, batch):
     assert not ops & {"while", "gather", "dynamic_gather", "scatter",
                       "dynamic_slice", "case"}, sorted(ops)
     assert not re.search(r"\b(while|gather)\(", lowered.compile().as_text())
+
+
+@pytest.mark.parametrize("batch", [None, 3], ids=["plain", "vmap"])
+def test_multiclass_metric_program_is_a_product_and_no_scatter(batch):
+    # the (K, K) confusion matrix is two one-hots and one product; a
+    # scatter-add of every row is a serial pass on the chip
+    lowered = _lower_multiclass_metric(batch)
+    ops = set(re.findall(r"\b(?:stablehlo|mhlo|chlo)\.([a-z_]+)",
+                         lowered.as_text()))
+    assert "dot_general" in ops
+    # (the (K, K) table's diagonal is read by a K-element gather)
+    assert not ops & {"scatter", "while", "sort"}, sorted(ops)
+    assert not re.search(r"\bscatter\(", lowered.compile().as_text())
+
+
+def test_a_classifiers_tree_program_reads_the_operand_once_a_level():
+    # depth 2, one block: one histogram product a level (the per-column
+    # form would hold K + 1 = 4 a level), none for the leaves
+    text = _lower_grow_tree_classes().as_text()
+    assert len(re.findall(r"stablehlo\.dot_general", text)) == 2
+    per_column = _lower_grow_tree().as_text()      # one target + weights
+    assert len(re.findall(r"stablehlo\.dot_general", per_column)) == 4
 
 
 # --------------------------------------------------------------------- #
@@ -620,6 +754,63 @@ def test_typed_share_of_the_peak_reads_on_the_chip_only(name, monkeypatch):
     least = 1598777600000.0 / 819e9
     assert share == pytest.approx(
         100 * least / (30.0 if name == "train_typed_mfu_pct" else 12.0))
+    assert 0 < share < 100
+
+
+# a many-label pass: the cutter's span and the driver's counters
+PASS_M = {"wall_s": 24.0, "spans": [
+    ("selector:prepare", 1.0), ("cutter:prepare", 0.25),
+    ("sweep:bin", 0.5)],
+    "counters": {"hist_slots": 1042, "value_columns": 23, "hist_reads": 1,
+                 "labels_seen": 22, "labels_kept": 22, "rows_dropped": 0}}
+PASS_N = {"wall_s": 20.0, "spans": [
+    ("selector:prepare", 1.0), ("cutter:prepare", 0.75),
+    ("sweep:bin", 0.5)],
+    "counters": {"hist_slots": 1042, "value_columns": 23, "hist_reads": 24,
+                 "labels_seen": 23, "labels_kept": 23, "rows_dropped": 0}}
+MULTI_READINGS = {
+    "train_hist_reads": (1, 12.5),
+    "train_cutter_s": (0.25, 0.5),
+}
+
+
+@pytest.mark.parametrize("name", sorted(MULTI_READINGS))
+def test_multi_layer_metric_reader_on_a_hand_made_window(name):
+    read = _reader(name)
+    one, two = MULTI_READINGS[name]
+    assert read({"window": {"passes": [PASS_M]}}) == pytest.approx(one)
+    assert read({"window": {"passes": [PASS_M, PASS_N]}}) \
+        == pytest.approx(two)
+    assert read({"window": {"passes": []}}) is None
+    assert read({"window": {}}) is None
+    # a program without the span or the counter gives nothing
+    assert read({"window": {"passes": [PASS_OLD]}}) is None
+    assert read({"window": {"passes": [PASS_T]}}) is None
+
+
+@pytest.mark.parametrize("name", ["train_multi_mfu_pct",
+                                  "train_multi_busy_mfu_pct"])
+def test_multi_share_of_the_peak_reads_on_the_chip_only(name, monkeypatch):
+    import json
+    import sys
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "benchmark"))
+    sys.modules.pop("work_multi", None)
+    read = _reader(name)
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "kddcup99.json")) as fh:
+        config = json.load(fh)
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as fh:
+        peaks = json.load(fh)["TPU v5 lite"]
+    obs = {"window": {"passes": [PASS_M], "rows": 2_000_000},
+           "config": config, "peaks": None,
+           "trace": {"n_ops": 5, "busy_s": 12.0}}
+    assert read(obs) is None                         # off the chip
+    share = read(dict(obs, peaks=peaks))
+    import work_multi
+    work = work_multi.train_pass(config, 2_000_000)
+    least = max(work["ops"] / 197e12, work["bytes"] / 819e9)
+    assert share == pytest.approx(
+        100 * least / (24.0 if name == "train_multi_mfu_pct" else 12.0))
     assert 0 < share < 100
 
 

@@ -580,7 +580,10 @@ def cmd_warmup(args) -> int:
               else BinaryClassificationEvaluator())
     folds = [((np.arange(n) % 3 != f).astype(np.float32),
               (np.arange(n) % 3 == f).astype(np.float32)) for f in range(3)]
-    ctx = FitContext(n_rows=n, seed=42)
+    # the selector states the number of classes of every sweep program it
+    # compiles: the warm-up states the same
+    ctx = FitContext(n_rows=n, seed=42,
+                     n_classes=None if args.problem == "regression" else k)
     t0 = _time.perf_counter()
     for est, grids in models:
         t1 = _time.perf_counter()

@@ -71,6 +71,21 @@ class MultiClassificationEvaluator(Evaluator):
         pred = np.asarray(prediction.data["prediction"], dtype=np.float64)
         return multiclass_metrics(y, pred)
 
+    def evaluate_device(self, y, pred: dict, n_classes: int):
+        """`evaluate` of device arrays: the (K, K) confusion table is
+        counted on the device (`confusion_dev`: exact while a cell stays
+        under 2^24) and only it crosses to the host, where the metrics
+        are taken in float64 as `evaluate` takes them."""
+        import jax.numpy as jnp
+
+        from transmogrifai_tpu.evaluators.device_metrics import (
+            confusion_dev)
+        from transmogrifai_tpu.evaluators.metrics import (
+            multiclass_from_confusion)
+        p = pred["prediction"]
+        return multiclass_from_confusion(np.asarray(confusion_dev(
+            y, p, jnp.ones(p.shape[0], jnp.float32), int(n_classes))))
+
 
 class RegressionEvaluator(Evaluator):
     """RMSE default, smaller is better (OpRegressionEvaluator)."""

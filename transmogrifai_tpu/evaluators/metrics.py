@@ -128,6 +128,14 @@ def multiclass_metrics(y_true, y_pred, n_classes: Optional[int] = None
     k = n_classes or int(max(y.max(initial=0), p.max(initial=0))) + 1
     conf = np.zeros((k, k), dtype=np.int64)
     np.add.at(conf, (y, p), 1)
+    return multiclass_from_confusion(conf)
+
+
+def multiclass_from_confusion(conf) -> MultiClassificationMetrics:
+    """The four weighted metrics from a (K, K) table of counts (rows the
+    label, columns the prediction), in float64."""
+    conf = np.asarray(conf).astype(np.int64)
+    k = conf.shape[0]
     tp = np.diag(conf).astype(np.float64)
     support = conf.sum(axis=1).astype(np.float64)
     pred_count = conf.sum(axis=0).astype(np.float64)
@@ -136,7 +144,7 @@ def multiclass_metrics(y_true, y_pred, n_classes: Optional[int] = None
     f1_c = np.divide(2 * prec_c * rec_c, prec_c + rec_c,
                      out=np.zeros(k), where=(prec_c + rec_c) > 0)
     w = support / max(support.sum(), 1.0)
-    err = 1.0 - tp.sum() / max(len(y), 1)
+    err = 1.0 - tp.sum() / max(conf.sum(), 1)
     return MultiClassificationMetrics(
         precision=float((prec_c * w).sum()), recall=float((rec_c * w).sum()),
         f1=float((f1_c * w).sum()), error=float(err), confusion=conf.tolist())

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -71,6 +72,40 @@ def infer_n_classes(y: np.ndarray) -> int:
     """Label cardinality for classification (labels must be 0..k-1)."""
     k = int(np.asarray(y).max(initial=0)) + 1
     return max(k, 2)
+
+
+def stated_n_classes(est, ctx=None) -> Optional[int]:
+    """The number of classes somebody STATED: the selector's
+    (`FitContext.n_classes`, which `ModelSelector.fit_model` sets once a
+    fit from its own `n_classes` or the host label) or, for a bare
+    `fit_arrays` / `run_sweep` call, the estimator's own `n_classes`;
+    None when neither says. The selector is the owner: an estimator that
+    states another number than its selector is an error, not a silent
+    winner."""
+    own = getattr(est, "n_classes", None)
+    held = getattr(ctx, "n_classes", None)
+    if own and held and int(own) != int(held):
+        raise ValueError(
+            f"{type(est).__name__}(n_classes={int(own)}) under a selector "
+            f"whose label has {int(held)} classes: state the number of "
+            "classes in one place, the selector's `n_classes`")
+    k = held or own
+    return int(k) if k else None
+
+
+def n_classes_of(est, y, ctx=None) -> int:
+    """The number of classes a classifier fits: `stated_n_classes`, else
+    the label's largest value + 1, which is what a bare `fit_arrays` or
+    `run_sweep` call that states nothing falls back to (of a device label
+    only the maximum crosses to the host, never the label). K is a
+    compiled shape: taken from the data, a table in which the top label
+    does not fall compiles every program anew."""
+    k = stated_n_classes(est, ctx)
+    if k:
+        return k
+    if isinstance(y, jax.Array):
+        return max(int(jnp.max(y, initial=0)) + 1, 2)
+    return infer_n_classes(y)
 
 
 def resolve_init_params(est: PredictorEstimator,

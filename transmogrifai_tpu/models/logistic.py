@@ -1,14 +1,20 @@
-"""Multinomial logistic regression, L-BFGS-optimized, vmappable.
+"""Multinomial logistic regression, vmappable: L-BFGS for the pure-L2
+penalty (`fit_logreg`), FISTA for the elastic net (`fit_logreg_enet`),
+which is the path every selector's default grid takes
+(elasticNetParam 0.1 and 0.5).
 
 Reference parity: `core/.../impl/classification/OpLogisticRegression.scala`
 (wrapping Spark MLlib LogisticRegression, itself L-BFGS/OWL-QN).
 
-TPU-first: the fit is a fixed-length `lax.scan` of optax L-BFGS steps over
-the full batch — static shapes, no data-dependent control flow — so the
-sweep engine can `vmap` it over hyperparameters and fold masks and `pjit`
-the batch dimension over the mesh. bfloat16 is deliberately NOT used for
-the optimizer state (convergence); X enters as f32 and the dominant cost
-(X @ W) hits the MXU.
+TPU-first: either fit is a fixed-length `lax.scan` over the full batch —
+optax L-BFGS steps, or proximal-gradient steps with a Lipschitz step
+from power iteration — with static shapes and no data-dependent control
+flow, so the sweep engine can `vmap` it over hyperparameters and fold
+masks and `pjit` the batch dimension over the mesh. bfloat16 is
+deliberately NOT used for the optimizer state (convergence); X enters as
+f32 and the dominant cost (X @ W, (n, d) @ (d, K), and its transpose)
+hits the MXU. Neither path scales the columns: on raw columns of very
+different ranges the step is set by the widest one.
 """
 
 from __future__ import annotations
@@ -22,7 +28,7 @@ import numpy as np
 import optax
 
 from transmogrifai_tpu.models.base import (
-    PredictionModel, PredictorEstimator, infer_n_classes,
+    PredictionModel, PredictorEstimator, n_classes_of,
     resolve_init_params)
 from transmogrifai_tpu.stages.base import FitContext
 
@@ -217,7 +223,7 @@ class OpLogisticRegression(PredictorEstimator):
     def fit_arrays(self, X, y, w, ctx: FitContext,
                    init_params: Optional[Dict] = None
                    ) -> LogisticRegressionModel:
-        k = self.n_classes or infer_n_classes(np.asarray(y))
+        k = n_classes_of(self, y, ctx)
         warm = resolve_init_params(self, init_params,
                                    {"W": (X.shape[1], k), "b": (k,)})
         alpha = float(self.elastic_net_param)

@@ -18,7 +18,7 @@ import numpy as np
 import optax
 
 from transmogrifai_tpu.models.base import (
-    PredictionModel, PredictorEstimator, infer_n_classes)
+    PredictionModel, PredictorEstimator, n_classes_of)
 from transmogrifai_tpu.stages.base import FitContext
 
 
@@ -126,7 +126,7 @@ class OpMultilayerPerceptronClassifier(PredictorEstimator):
         self.n_classes = n_classes
 
     def fit_arrays(self, X, y, w, ctx: FitContext) -> MLPModel:
-        k = self.n_classes or infer_n_classes(np.asarray(y))
+        k = n_classes_of(self, y, ctx)
         layers = (int(X.shape[1]),) + self.hidden_layers + (k,)
         params = fit_mlp(X, y, w, layers, self.max_iter,
                          self.learning_rate, ctx.seed)

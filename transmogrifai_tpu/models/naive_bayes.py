@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from transmogrifai_tpu.models.base import (
-    PredictionModel, PredictorEstimator, infer_n_classes)
+    PredictionModel, PredictorEstimator, n_classes_of)
 from transmogrifai_tpu.stages.base import FitContext
 
 
@@ -86,7 +86,7 @@ class OpNaiveBayes(PredictorEstimator):
         if bool(jnp.any(X < 0)):
             raise ValueError(
                 "NaiveBayes requires non-negative features (Spark parity)")
-        k = self.n_classes or infer_n_classes(np.asarray(y))
+        k = n_classes_of(self, y, ctx)
         p = fit_naive_bayes(X, y, w, jnp.float32(self.smoothing), k)
         return NaiveBayesModel(np.asarray(p["log_prior"]),
                                np.asarray(p["log_theta"]))

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from transmogrifai_tpu.models.base import (
-    PredictionModel, PredictorEstimator, infer_n_classes)
+    PredictionModel, PredictorEstimator, n_classes_of)
 from transmogrifai_tpu.obs.trace import TRACER
 from transmogrifai_tpu.stages.base import FitContext
 
@@ -280,13 +280,16 @@ def _histograms(B, node_idx, G, H, n_nodes: int, block: str = "wide"):
     also means a mesh-sharded batch reduces via an XLA-inserted psum —
     the Rabit-allreduce analogue (SURVEY.md §2.9).
 
-    Per-value-column matmuls (B read m+1 times) measure FASTER here than
-    stacking [G, H] into one ((m+1)·nodes, n) operand: at the in-core
-    shape that was timed (d ≈ 55 columns of 32 bins, 1,760 slots a row;
-    a 13 + 515-column typed table is 1,446) the A-side (n, (m+1)·nodes)
-    materialization costs more than the saved B reads — the OPPOSITE
-    tradeoff from the out-of-core path (d=500, B per-chunk rebuilt),
-    where `parallel/bigdata.py` stacks.
+    Per-value-column matmuls (B read m+1 times) measured FASTER than
+    stacking [G, H] into one ((m+1)·nodes, n) operand at m = 2 and the
+    in-core shape that was timed before PR 21's first direct chip run
+    (d ≈ 55 columns of 32 bins, 1,760 slots a row): the A-side
+    (n, (m+1)·nodes) concatenation cost more than the saved B reads —
+    the OPPOSITE tradeoff from the out-of-core path (d=500, B per-chunk
+    rebuilt), where `parallel/bigdata.py` stacks. What still comes here
+    is a regressor's one column and a boosted round's gradients; a
+    CLASSIFIER's K columns are one-hot times one weight and need no
+    stacking at all: `_class_histograms`.
 
     Value precision is governed by HIST_PRECISION (see above)."""
     n, d, nb = B.shape
@@ -313,6 +316,55 @@ def _histograms(B, node_idx, G, H, n_nodes: int, block: str = "wide"):
         hh = red(H)
         hg = jnp.stack([red(G[:, c]) for c in range(m)])
     return hg, hh
+
+
+def hist_reads(n_classes: int = 0, value_columns: int = 1) -> int:
+    """Passes over the histogram operand a tree level: ONE for a
+    classifier of `n_classes` (`_class_histograms`), else one a value
+    column and one for the weights (`_histograms`)."""
+    return 1 if n_classes else int(value_columns) + 1
+
+
+@jax.named_scope("tree:hist")
+def _class_histograms(B, node_idx, cls, H, n_nodes: int, n_classes: int,
+                      block: str = "wide"):
+    """`_histograms` of a CLASSIFIER, whose value columns are a one-hot
+    label times one row weight: G[:, c] = (cls == c)·H. Then the K class
+    histograms are ONE histogram over the composite index
+    cls·nodes + node, a single (K·nodes, n) @ (n, d·bins) product that
+    reads the operand once a level where the per-column form reads it
+    K + 1 times, and the weights' histogram is the sum of the class
+    histograms (every row is in exactly one class). Same value
+    precision as `_histograms`: the weight narrows to bf16 (exact for
+    the whole-number weights of a bootstrap under a 0/1 fold mask, so
+    the two forms then give the same histograms bit for bit), float32
+    accumulation; exact float32 under HIST_PRECISION = "f32". Scope
+    `tree:hist:classes` inside `tree:hist:<block>`.
+
+    Timed on one TPU v5e (PR 30), one depth-12 tree with its bootstrap,
+    composite against per-column, the trees equal bit for bit in every
+    case: 1,800,000 rows × (33 wide + 41 two-valued columns, 1,138
+    slots) at K = 23, 2.89 s against 3.37 (compile 70 s against 105),
+    at K = 7, 0.92 against 1.16; 2,160,000 × 28 wide (896 slots) at
+    K = 2, 0.256 against 0.373; 900,000 × (13 + 515, 1,446 slots) at
+    K = 2, 0.206 against 0.287. So every classifier takes this form,
+    the binary ones too."""
+    n, d, nb = B.shape
+    exact = HIST_PRECISION == "f32"
+    dt = jnp.float32 if exact else jnp.bfloat16
+    A = jax.nn.one_hot(cls * n_nodes + node_idx, n_classes * n_nodes,
+                       dtype=dt) * H[:, None].astype(dt)
+    Bf = B.reshape(n, d * nb)
+    with jax.named_scope(f"tree:hist:{block}"), \
+            jax.named_scope("tree:hist:classes"):
+        if exact:
+            out = jnp.matmul(A.T, Bf.astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
+        else:
+            out = jnp.matmul(A.T, Bf, preferred_element_type=jnp.float32)
+        hg = out.reshape(n_classes, n_nodes, d, nb)
+        return hg, hg.sum(0)
 
 
 def _best_split(hg, hh, reg_lambda, min_child_weight, feature_mask):
@@ -413,12 +465,19 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
               feature_mask: Optional[jnp.ndarray] = None,
               active_depth=None, alpha: float = 0.0,
               B: Optional[List[Tuple]] = None,
-              min_gain_norm=0.0, layout: Optional[Dict] = None) -> Dict:
+              min_gain_norm=0.0, layout: Optional[Dict] = None,
+              n_classes: int = 0) -> Dict:
     """Grow one fixed-depth tree. Returns dense arrays:
 
     {"feat": (depth, 2^depth) int32, "bin": (depth, 2^depth) int32,
      "leaf": (2^max_depth, m) float32}
     (per-level arrays are padded to 2^max_depth node slots)
+
+    A CLASSIFIER passes its labels as a 1-D `G` with `n_classes`: the
+    targets are then one_hot(G)·H and are never built as an (n, K)
+    array (`_class_histograms`; the leaf sums scatter one weight a row
+    into the composite (leaf, class) index). A label outside [0, K)
+    counts nowhere.
 
     `active_depth`: optional TRACED effective depth ≤ max_depth. Levels at or
     beyond it never split (every sample routes left, partition unchanged), so
@@ -450,7 +509,12 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
     LightGBM subtracts in full precision.
     """
     n, d = Xb.shape
-    m = G.shape[1]
+    cls = None
+    if G.ndim == 1:                 # a classifier's labels
+        cls = G.astype(jnp.int32)
+        H = jnp.where((cls >= 0) & (cls < n_classes), H, 0.0)
+        cls = jnp.clip(cls, 0, n_classes - 1)
+    m = n_classes if cls is not None else G.shape[1]
     max_nodes = 2 ** max_depth
     node_idx = jnp.zeros(n, dtype=jnp.int32)
     feats = jnp.zeros((max_depth, max_nodes), jnp.int32)
@@ -459,6 +523,9 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
         B = hist_operand(Xb, n_bins, layout)
 
     def histograms(node, Gv, Hv, n_nodes):
+        if cls is not None:
+            return [_class_histograms(Bk, node, cls, Hv, n_nodes, m, name)
+                    for name, _, Bk in B]
         return [_histograms(Bk, node, Gv, Hv, n_nodes, name)
                 for name, _, Bk in B]
 
@@ -493,10 +560,17 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
                  jnp.stack([hh - hh_r, hh_r], axis=1).reshape(
                      2 * n_nodes, *hh.shape[1:]))
                 for (hg, hh), (hg_r, hh_r) in zip(hists, histograms(
-                    node_idx >> 1, G * right[:, None], H * right, n_nodes))]
+                    node_idx >> 1,
+                    None if cls is not None else G * right[:, None],
+                    H * right, n_nodes))]
 
-    leaf_g = jnp.zeros((max_nodes, m), G.dtype).at[node_idx].add(G)
-    leaf_h = jnp.zeros((max_nodes,), H.dtype).at[node_idx].add(H)
+    if cls is not None:
+        leaf_g = jnp.zeros((max_nodes * m,), H.dtype).at[
+            node_idx * m + cls].add(H).reshape(max_nodes, m)
+        leaf_h = leaf_g.sum(1)
+    else:
+        leaf_g = jnp.zeros((max_nodes, m), G.dtype).at[node_idx].add(G)
+        leaf_h = jnp.zeros((max_nodes,), H.dtype).at[node_idx].add(H)
     # L1 (alpha) soft-thresholds the leaf numerator (XGBoost leaf formula)
     leaf_g = jnp.sign(leaf_g) * jnp.maximum(jnp.abs(leaf_g) - alpha, 0.0)
     leaf = leaf_g / (leaf_h + reg_lambda)[:, None]
@@ -633,7 +707,10 @@ def fit_forest(Xb, Y, w, n_trees: int, max_depth: int, n_bins: int,
                min_child_weight: float = 1.0, active_depth=None,
                bootstrap: bool = True, tree_budget_divisor: int = 1,
                min_gain=0.0, layout: Optional[Dict] = None):
+    """`Y`: (n, n_outputs) float targets, or a classifier's (n,) labels
+    in [0, n_outputs) (`grow_tree`'s class form)."""
     n, d = Xb.shape
+    classes = Y.ndim == 1
     keys = jax.random.split(jax.random.PRNGKey(seed), n_trees)
     n_sub = max(int(np.sqrt(d)), 1) if subsample_features else d
     B = hist_operand(Xb, n_bins, layout)  # shared across all trees
@@ -652,10 +729,12 @@ def fit_forest(Xb, Y, w, n_trees: int, max_depth: int, n_bins: int,
             fmask = scores <= thresh
         else:
             fmask = jnp.ones((d,), bool)
-        return grow_tree(Xb, Y * boot[:, None], boot, max_depth, n_bins,
+        return grow_tree(Xb, Y if classes else Y * boot[:, None], boot,
+                         max_depth, n_bins,
                          reg_lambda=1e-6, min_child_weight=min_child_weight,
                          min_gain_norm=min_gain,
-                         feature_mask=fmask, active_depth=active_depth, B=B)
+                         feature_mask=fmask, active_depth=active_depth, B=B,
+                         n_classes=n_outputs if classes else 0)
 
     # Bound simultaneous per-tree working set: each live instance holds the
     # (n, nodes) one-hot routing matrix at the deepest level plus O(n·d)
@@ -924,24 +1003,33 @@ def _pow2_floor(x: int) -> int:
 
 
 def dispatch_plan(n_rows: int, slots: int, pad_depth: int, learners: int,
-                  n_pairs: int = 1, pad_tail: bool = False
-                  ) -> Tuple[int, int]:
+                  n_pairs: int = 1, pad_tail: bool = False,
+                  value_columns: int = 1) -> Tuple[int, int]:
     """(width, rounds) of one tree dispatch, from shapes alone: how many
     grid×fold pairs it vmaps and how many of a pair's `learners`
     (boosting rounds; a forest grows all its trees in one) it runs.
-    `slots` is the histogram operand's width a row (`hist_slots`). The
-    work unit is learners × rows × nodes × slots, the histogram matmul's
-    shape. The width is a power of two (a width is a compiled shape),
-    capped by the pair count: at its power-of-two floor where the caller
-    pads the last chunk (`pad_tail`), at the count itself otherwise.
-    The sweep and the refit (`n_pairs` 1) both plan here, so a shape
-    compiles the same rounds in either."""
+    `slots` is the histogram operand's width a row (`hist_slots`),
+    `value_columns` the learner's target columns (a classifier forest's
+    K; 1 for a regressor and for a boosted round). The work unit is
+    learners × rows × nodes × slots × value columns, the histogram
+    matmuls' shape. The width is a power of two (a width is a compiled
+    shape), capped by the pair count: at its power-of-two floor where
+    the caller pads the last chunk (`pad_tail`), at the count itself
+    otherwise. The sweep and the refit (`n_pairs` 1) both plan here, so
+    a shape compiles the same rounds in either. Neither budget has been
+    timed at more than two value columns: at 1,800,000 rows either term
+    already pins the width to 1 for any K (the cells' shapes)."""
     nodes = 2 ** min(pad_depth, 14)
-    unit = max(n_rows * nodes * slots, 1)       # one learner of one pair
+    k = max(int(value_columns), 1)
+    unit = max(n_rows * nodes * slots * k, 1)   # one learner of one pair
     # bf16 bytes of the bin one-hots and the deepest level's routing
     # one-hot, as if every pair held its own: the bin one-hots are built
-    # once a dispatch and shared by its pairs, so this over-counts them
-    w_mem = _PAIR_MEM_BYTES // max(n_rows * (slots + nodes) * 2, 1)
+    # once a dispatch and shared by its pairs, so this over-counts them.
+    # A pair's own float32 histograms of the deepest level (nodes / 2),
+    # with their cumulative sums and the gain table: (k + 1) value
+    # columns × nodes / 2 × slots × 4 bytes, three times
+    w_mem = _PAIR_MEM_BYTES // max(
+        n_rows * (slots + nodes) * 2 + 6 * (k + 1) * nodes * slots, 1)
     w_work = int(_DISPATCH_UNITS // (learners * unit))
     width = min(_pow2_floor(max(1, min(w_mem, w_work))),
                 _pow2_floor(n_pairs) if pad_tail else n_pairs)
@@ -1152,14 +1240,15 @@ def warm_refit_forest(est, warm: Dict, X, y, w, ctx,
     yd = jnp.asarray(y)[-delta:]
     wd = jnp.asarray(w)[-delta:]
     Xb = bin_features(Xd, edges)
-    if classification:
-        k = int(old["leaf"].shape[-1])
-        Y = jax.nn.one_hot(yd.astype(jnp.int32), k)
+    if classification:  # the labels themselves: `grow_tree`'s class form
+        n_out = int(old["leaf"].shape[-1])
+        Y = yd.astype(jnp.int32)
     else:
+        n_out = 1
         Y = yd[:, None]
     seed = (ctx.seed if ctx is not None else 0) + n_trees  # fresh draws
     new = fit_forest(Xb, Y, wd, n_new, est.max_depth, est.max_bins,
-                     Y.shape[1], seed, est.subsample_features,
+                     n_out, seed, est.subsample_features,
                      est._effective_mcw(),
                      min_gain=jnp.float32(est.min_info_gain))
     combined = jax.tree.map(
@@ -1317,15 +1406,19 @@ class _TreeEstimatorBase(PredictorEstimator):
     # sweep path keeps its own per-family cache (`parallel/sweep.py:_binned`).
     _bin_cache: Optional[Dict] = None
 
-    def _edges_binned(self, X, ctx):
+    def _edges_binned(self, X, ctx, n_classes: int = 0):
         """(edges, binned matrix, histogram layout) of a training matrix;
         the matrix stays where it is (on the device in a workflow's
-        refit: the span `tree:edges` says so)."""
+        refit: the span `tree:edges` says so). `n_classes`: a classifier
+        forest's K; the span carries the fit's value columns (K, else 1)
+        and the passes over the histogram operand a tree level."""
         cache = self._bin_cache
         if cache is not None and self.max_bins in cache:
             return cache[self.max_bins]
         with TRACER.span("tree:edges", category="tree",
-                         max_bins=self.max_bins, edges=edges_site(X)):
+                         max_bins=self.max_bins, edges=edges_site(X),
+                         value_columns=n_classes or 1,
+                         hist_reads=hist_reads(n_classes)):
             indicator = indicator_columns(X)
             edges = quantile_bin_edges(X, self.max_bins, indicator)
             out = (edges, bin_features(jnp.asarray(X), jnp.asarray(edges)),
@@ -1367,7 +1460,7 @@ class OpRandomForestClassifier(_TreeEstimatorBase):
                    float(self.min_instances_per_node))
 
     def fit_arrays(self, X, y, w, ctx: FitContext):
-        k = self.n_classes or infer_n_classes(np.asarray(y))
+        k = n_classes_of(self, y, ctx)
         warm = self.init_params
         if warm is not None and "trees" in warm and \
                 warm_tree_compatible(warm, X, n_classes=k,
@@ -1376,9 +1469,9 @@ class OpRandomForestClassifier(_TreeEstimatorBase):
                                       classification=True)
             return ForestClassificationModel(
                 np.asarray(warm["edges"], np.float32), trees)
-        edges, Xb, layout = self._edges_binned(X, ctx)
-        Y = jax.nn.one_hot(y.astype(jnp.int32), k)
-        trees = fit_forest(Xb, Y, w, self.n_trees, self.max_depth,
+        edges, Xb, layout = self._edges_binned(X, ctx, n_classes=k)
+        trees = fit_forest(Xb, y.astype(jnp.int32), w, self.n_trees,
+                           self.max_depth,
                            self.max_bins, k, ctx.seed,
                            self.subsample_features, self._effective_mcw(),
                            min_gain=jnp.float32(self.min_info_gain),
@@ -1426,14 +1519,13 @@ class OpDecisionTreeClassifier(OpRandomForestClassifier):
                        "n_classes": n_classes}
 
     def fit_arrays(self, X, y, w, ctx: FitContext):
-        k = self.n_classes or infer_n_classes(np.asarray(y))
-        edges, Xb, layout = self._edges_binned(X, ctx)
-        Y = jax.nn.one_hot(y.astype(jnp.int32), k)
-        tree = grow_tree(Xb, Y * w[:, None], w, self.max_depth, self.max_bins,
-                         reg_lambda=1e-6,
+        k = n_classes_of(self, y, ctx)
+        edges, Xb, layout = self._edges_binned(X, ctx, n_classes=k)
+        tree = grow_tree(Xb, y.astype(jnp.int32), w, self.max_depth,
+                         self.max_bins, reg_lambda=1e-6,
                          min_child_weight=self._effective_mcw(),
                          min_gain_norm=jnp.float32(self.min_info_gain),
-                         layout=layout)
+                         layout=layout, n_classes=k)
         trees = jax.tree.map(lambda a: a[None], tree)  # (1, ...) forest shape
         return ForestClassificationModel(edges, {k2: np.asarray(v)
                                                  for k2, v in trees.items()})
@@ -1528,7 +1620,7 @@ class OpGBTClassifier(_TreeEstimatorBase):
 
     def fit_arrays(self, X, y, w, ctx: FitContext):
         if self._objective == "logistic":
-            k = self.n_classes or infer_n_classes(np.asarray(y))
+            k = n_classes_of(self, y, ctx)
         else:
             k = 2
         warm = self.init_params

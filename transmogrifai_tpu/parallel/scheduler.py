@@ -468,11 +468,13 @@ class GridScheduler:
     def _worker_ctx(self, k: int, ctx):
         """Same n_rows and — critically — the SAME seed as the caller's
         context: bootstrap/fold streams must match the single-device
-        sweep bit for bit."""
+        sweep bit for bit. The selector's number of classes goes with
+        it: a lane compiles the programs the single-device sweep would."""
         from transmogrifai_tpu.stages.base import FitContext
         return FitContext(n_rows=getattr(ctx, "n_rows", 0),
                           seed=getattr(ctx, "seed", 42),
-                          mesh=self._submesh(k))
+                          mesh=self._submesh(k),
+                          n_classes=getattr(ctx, "n_classes", None))
 
     # -- queue protocol ----------------------------------------------------- #
 

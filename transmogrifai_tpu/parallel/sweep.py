@@ -50,7 +50,7 @@ from transmogrifai_tpu.obs.trace import TRACER
 from transmogrifai_tpu.data.columns import Column
 from transmogrifai_tpu.evaluators.device_metrics import (
     device_metric, make_device_metric)
-from transmogrifai_tpu.models.base import infer_n_classes
+from transmogrifai_tpu.models.base import n_classes_of, stated_n_classes
 from transmogrifai_tpu.models.glm import (
     OpGeneralizedLinearRegression, fit_glm, predict_glm)
 from transmogrifai_tpu.models.linear import (
@@ -71,7 +71,7 @@ from transmogrifai_tpu.models.trees import (
     bin_features, dispatch_plan, fit_forest, fit_gbt, fit_gbt_multiclass,
     forest_classification_pred, forest_regression_pred,
     gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
-    edges_site, hist_layout, hist_slots, indicator_columns,
+    edges_site, hist_layout, hist_reads, hist_slots, indicator_columns,
     quantile_bin_edges)
 from transmogrifai_tpu.runtime.faults import (
     SITE_RUN_BLOCK, fault_point, is_oom_error)
@@ -874,7 +874,7 @@ def _xy_data(X, y) -> Callable[[Tuple], Dict[str, Any]]:
 
 
 def _sweep_logistic(est, grids, X, y, W, V, metric_fn, ctx, sharding):
-    n_classes = est.n_classes or infer_n_classes(np.asarray(y))
+    n_classes = n_classes_of(est, y, ctx)
     return _sweep_blocks(
         grids, W, V, metric_fn, sharding, "logistic",
         static_of=lambda g: _static_logistic(est, g),
@@ -920,7 +920,7 @@ def _sweep_nb(est, grids, X, y, W, V, metric_fn, ctx, sharding):
     if cache[1]:
         raise ValueError(
             "NaiveBayes requires non-negative features (Spark parity)")
-    n_classes = est.n_classes or infer_n_classes(np.asarray(y))
+    n_classes = n_classes_of(est, y, ctx)
     return _sweep_blocks(
         grids, W, V, metric_fn, sharding, "naive_bayes",
         static_of=lambda g: _static_nb(est, g),
@@ -930,7 +930,7 @@ def _sweep_nb(est, grids, X, y, W, V, metric_fn, ctx, sharding):
 
 
 def _sweep_mlp(est, grids, X, y, W, V, metric_fn, ctx, sharding):
-    n_classes = est.n_classes or infer_n_classes(np.asarray(y))
+    n_classes = n_classes_of(est, y, ctx)
     seed = int(ctx.seed) if ctx is not None else 0
     return _sweep_blocks(
         grids, W, V, metric_fn, sharding, "mlp",
@@ -945,7 +945,7 @@ def _sweep_mlp(est, grids, X, y, W, V, metric_fn, ctx, sharding):
 # tree families: padded-depth trick, one compile per (bins, trees) group      #
 # --------------------------------------------------------------------------- #
 
-def _binned_cache(est, grids, X, ctx) -> Tuple[
+def _binned_cache(est, grids, X, ctx, n_classes: int = 0) -> Tuple[
         Dict[int, jnp.ndarray], Optional[Dict], Optional[Tuple[int, int]]]:
     """Bin X once per distinct max_bins ACROSS tree families in a sweep:
     the cache lives on the FitContext, so RF and XGB in the same selector
@@ -960,6 +960,10 @@ def _binned_cache(est, grids, X, ctx) -> Tuple[
     sharded sweeps would silently deviate from unsharded ones. Of a
     device matrix only the non-indicator columns' order statistics cross
     to the host for them (the span's `edges` attribute says which).
+    `n_classes` is the calling family's (a classifier forest's K, else
+    0): the span of the family that bins carries that family's value
+    columns and its passes over the histogram operand a tree level
+    (`hist_reads`).
 
     Guarded by a lock: tree families now sweep on a thread pool, and two
     families hitting the same max_bins must not double-build the (n, d)
@@ -988,7 +992,9 @@ def _binned_cache(est, grids, X, ctx) -> Tuple[
                 out[mb] = bin_features(jnp.asarray(X), jnp.asarray(edges))
                 sp.set(hist_slots=hist_slots(int(X.shape[1]), mb,
                                              out["layout"]),
-                       edges=edges_site(X_edges))
+                       edges=edges_site(X_edges),
+                       value_columns=n_classes or 1,
+                       hist_reads=hist_reads(n_classes))
         layout = out.get("layout")
         blocks = None if layout is None else tuple(
             int(layout[b].shape[0]) for b in ("wide", "ind"))
@@ -1024,13 +1030,14 @@ def _pad_depth_of(est, grids, idxs) -> int:
 
 def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
                   regression: bool):
-    xb_by_bins, layout, blocks = _binned_cache(est, grids, X, ctx)
     if regression:
         Y = jnp.asarray(y)[:, None]
         n_out = 1
-    else:
-        n_out = est.n_classes or infer_n_classes(np.asarray(y))
-        Y = jax.nn.one_hot(jnp.asarray(y).astype(jnp.int32), n_out)
+    else:       # the labels themselves: `grow_tree`'s class form
+        n_out = n_classes_of(est, y, ctx)
+        Y = jnp.asarray(y).astype(jnp.int32)
+    xb_by_bins, layout, blocks = _binned_cache(
+        est, grids, X, ctx, n_classes=0 if regression else n_out)
     seed = int(ctx.seed) if ctx is not None else 0
     # single deterministic tree for DT estimators (no Poisson bootstrap), so
     # sweep metrics describe exactly what the refit fit_arrays produces
@@ -1046,7 +1053,7 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
         # budget in step with actual live instances
         return dispatch_plan(n_rows, hist_slots(d_feat, max_bins, layout),
                              _pad_depth_of(est, grids, idxs), n_trees,
-                             len(idxs) * n_folds)[0]
+                             len(idxs) * n_folds, value_columns=n_out)[0]
 
     def shape_of(st, idxs):
         # unsharded → host dispatch of `width` vmapped pairs at a time;
@@ -1133,8 +1140,7 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
     objective = est._objective
     n_classes = 2
     if objective == "logistic":
-        n_classes = getattr(est, "n_classes", None) or \
-            infer_n_classes(np.asarray(y))
+        n_classes = n_classes_of(est, y, ctx)
     seed = int(ctx.seed) if ctx is not None else 0
     multiclass = objective == "logistic" and n_classes > 2
 
@@ -1352,14 +1358,11 @@ def _run_sweep(est, grids: List[Dict], X, y, folds, evaluator, ctx,
     handler = _dispatch(est)
     if handler is None:
         return _sweep_generic(est, grids, X, y, folds, evaluator, ctx)
-    try:
-        n_classes = getattr(est, "n_classes", None) or \
-            infer_n_classes(np.asarray(y))
-    except Exception:
-        n_classes = None
-    # no device kernel for this evaluator → batched fits, host metrics
-    metric_fn = (make_device_metric(evaluator, n_classes=n_classes)
-                 or HostMetricFallback(evaluator))
+    # no device kernel for this evaluator (or a multiclass one and nobody
+    # stated the number of classes) → batched fits, host metrics
+    metric_fn = (make_device_metric(
+        evaluator, n_classes=stated_n_classes(est, ctx))
+        or HostMetricFallback(evaluator))
     # the cache entry RETAINS the keying objects so `is` comparisons are
     # safe (an id()-only key could false-hit after GC address reuse): a
     # FitContext reused with different X/y/folds (public run_sweep callers)

@@ -102,9 +102,15 @@ class ModelSelector(Estimator):
     def __init__(self, models: Sequence[Tuple[Estimator, List[Dict]]],
                  validator=None, splitter=None, evaluator=None,
                  problem_type: str = "binary", uid: Optional[str] = None,
-                 checkpoint_dir: Optional[str] = None):
+                 checkpoint_dir: Optional[str] = None,
+                 n_classes: Optional[int] = None):
         super().__init__(uid=uid)
         self.models = list(models)
+        # a classifier's number of classes is a compiled shape of every
+        # sweep program: stated here (a configuration knows its label) it
+        # stays put when a table's rarest label does not fall; left None
+        # it is read off the label once a fit (`fit_model`)
+        self.n_classes = n_classes
         self.validator = validator or OpCrossValidation()
         self.splitter = splitter
         self.evaluator = evaluator or BinaryClassificationEvaluator()
@@ -138,6 +144,11 @@ class ModelSelector(Estimator):
             else:
                 train_idx = np.arange(len(y_np))
                 test_idx = np.array([], dtype=np.int64)
+            if self.problem_type != "regression":
+                # the one place the number of classes is read off the
+                # label (the host column the split just used)
+                ctx.n_classes = int(self.n_classes or max(
+                    int(y_np.max(initial=0)) + 1, 2))
 
             X = X_full[jnp.asarray(train_idx)]
             y_train = y_np[train_idx]
@@ -147,7 +158,8 @@ class ModelSelector(Estimator):
                            if ctx.cv_refit is None
                            and self.checkpoint_dir is not None else None)
 
-        with TRACER.span("selector:sweep", category="selector"):
+        with TRACER.span("selector:sweep", category="selector",
+                         classes=ctx.n_classes or 0):
             results, failures = self._sweep(
                 ctx, X, y_dev, folds, train_idx, data_digest)
         # the sweep's padded/sharded data and binned matrices die with it:
@@ -559,22 +571,35 @@ class ModelSelector(Estimator):
                 X, y_dev, jnp.ones_like(y_dev), ctx)
 
         # -- evaluate train + holdout ------------------------------------ #
-        def _eval(idx: np.ndarray, rows=None) -> Dict[str, Any]:
+        def _eval(idx: np.ndarray, rows=None, y=None) -> Dict[str, Any]:
             if len(idx) == 0:
                 return {}
             if rows is None:
                 rows = X_full[jnp.asarray(idx)]
             pred = model.predict_arrays(rows)
-            pcol = Column(T.Prediction, {k: np.asarray(v) for k, v in pred.items()})
-            lcol = Column(T.RealNN, {
-                "value": y_np[idx], "mask": np.ones(len(idx), dtype=bool)})
-            m = self.evaluator.evaluate(lcol, pcol).to_json()
-            return {k: v for k, v in m.items() if not isinstance(v, list)}
+            on_device = getattr(self.evaluator, "evaluate_device", None)
+            if on_device is not None and len(idx) < (1 << 24):
+                # counts stay exact in float32: only a (K, K) table
+                # crosses to the host, not (n, K) probabilities
+                if y is None:
+                    y = jnp.asarray(y_np[idx], jnp.float32)
+                m = on_device(y, pred, ctx.n_classes)
+            else:
+                pcol = Column(T.Prediction,
+                              {k: np.asarray(v) for k, v in pred.items()})
+                lcol = Column(T.RealNN, {
+                    "value": y_np[idx],
+                    "mask": np.ones(len(idx), dtype=bool)})
+                m = self.evaluator.evaluate(lcol, pcol)
+            # scalars, and a multiclass evaluator's (K, K) table of
+            # counts: the summary keeps the table its metrics came from
+            return {k: v for k, v in m.to_json().items()
+                    if k == "Confusion" or not isinstance(v, list)}
 
         with TRACER.span("selector:evaluate", category="selector"):
             # the prepared train rows are at hand: a second gather of them
             # is a table-sized buffer (and its scratch) for nothing
-            train_metrics = _eval(train_idx, X)
+            train_metrics = _eval(train_idx, X, y_dev)
             holdout_metrics = _eval(test_idx)
         summary = ModelSelectorSummary(
             problem_type=self.problem_type,
@@ -684,27 +709,31 @@ class MultiClassificationModelSelector:
             models: Optional[Sequence[Tuple[Estimator, List[Dict]]]] = None,
             n_folds: int = 3, validation_metric: str = "F1",
             splitter=None, seed: int = 42,
-            checkpoint_dir: Optional[str] = None) -> ModelSelector:
+            checkpoint_dir: Optional[str] = None,
+            n_classes: Optional[int] = None) -> ModelSelector:
         return ModelSelector(
             models=models or _default_multiclass_models(),
             validator=OpCrossValidation(n_folds=n_folds, seed=seed),
             splitter=splitter if splitter is not None else DataCutter(seed=seed),
             evaluator=MultiClassificationEvaluator(metric=validation_metric),
-            problem_type="multiclass", checkpoint_dir=checkpoint_dir)
+            problem_type="multiclass", checkpoint_dir=checkpoint_dir,
+            n_classes=n_classes)
 
     @staticmethod
     def with_train_validation_split(
             models: Optional[Sequence[Tuple[Estimator, List[Dict]]]] = None,
             train_ratio: float = 0.75, validation_metric: str = "F1",
             splitter=None, seed: int = 42,
-            checkpoint_dir: Optional[str] = None) -> ModelSelector:
+            checkpoint_dir: Optional[str] = None,
+            n_classes: Optional[int] = None) -> ModelSelector:
         from transmogrifai_tpu.selector.validators import OpTrainValidationSplit
         return ModelSelector(
             models=models or _default_multiclass_models(),
             validator=OpTrainValidationSplit(train_ratio=train_ratio, seed=seed),
             splitter=splitter if splitter is not None else DataCutter(seed=seed),
             evaluator=MultiClassificationEvaluator(metric=validation_metric),
-            problem_type="multiclass", checkpoint_dir=checkpoint_dir)
+            problem_type="multiclass", checkpoint_dir=checkpoint_dir,
+            n_classes=n_classes)
 
 
 class RegressionModelSelector:

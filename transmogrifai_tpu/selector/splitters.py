@@ -15,6 +15,8 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from transmogrifai_tpu.obs.trace import TRACER
+
 
 @dataclass
 class SplitterSummary:
@@ -103,15 +105,25 @@ class DataCutter(DataSplitter):
 
     def prepare(self, y: np.ndarray, train_idx: np.ndarray
                 ) -> Tuple[np.ndarray, Dict]:
-        yt = y[train_idx]
-        labels, counts = np.unique(yt, return_counts=True)
-        order = np.argsort(-counts)
-        keep = []
-        for i in order[: self.max_label_categories]:
-            if counts[i] / len(yt) >= self.min_label_fraction:
-                keep.append(labels[i])
-        keep_set = np.isin(yt, np.asarray(keep))
-        details = {"labels_kept": [float(v) for v in keep],
-                   "labels_dropped": [float(v) for v in labels
-                                      if v not in set(keep)]}
-        return train_idx[keep_set], details
+        """Rows of the `max_label_categories` most frequent training
+        labels (count descending, a tie to the smaller label) whose share
+        is at least `min_label_fraction`; the holdout is not cut. Under
+        the span `cutter:prepare` (`labels_seen`, `labels_kept`,
+        `rows_dropped`)."""
+        with TRACER.span("cutter:prepare", category="selector") as sp:
+            yt = y[train_idx]
+            labels, counts = np.unique(yt, return_counts=True)
+            order = np.argsort(-counts, kind="stable")
+            keep = [labels[i] for i in order[: self.max_label_categories]
+                    if counts[i] / len(yt) >= self.min_label_fraction]
+            kept = set(keep)
+            details = {"labels_kept": [float(v) for v in keep],
+                       "labels_dropped": [float(v) for v in labels
+                                          if v not in kept]}
+            if len(keep) == len(labels):       # nothing to cut
+                out = train_idx
+            else:
+                out = train_idx[np.isin(yt, np.asarray(keep))]
+            sp.set(labels_seen=len(labels), labels_kept=len(keep),
+                   rows_dropped=int(len(train_idx) - len(out)))
+        return out, details

@@ -48,6 +48,10 @@ class FitContext:
     mesh: Any = None  # jax.sharding.Mesh when running sharded
     data_axis: str = "data"
     cv_refit: Any = None
+    # a classification selector reads the number of classes off the label
+    # ONCE and leaves it here for every family's sweep, the refit and the
+    # metrics (`models/base.n_classes_of`)
+    n_classes: Any = None
 
     def child(self, salt: int) -> "FitContext":
         return FitContext(self.n_rows, self.seed * 1000003 + salt, self.mesh, self.data_axis)
