@@ -5,7 +5,10 @@ arrays. Each `Column` holds one feature's values for a whole batch in the
 layout best suited to its kind:
 
 - scalar (OPNumeric):  float64/int64 `value` + bool `mask` (True = present)
-- text:                object ndarray of str|None
+- text:                object ndarray of str|None, beside it (made at most
+                       once a column, on first use) its factorization:
+                       int32 `codes` (-1 = missing) into the distinct
+                       `levels`, which the pivots read instead of cells
 - list/set/geo:        object ndarray of list/frozenset
 - map:                 object ndarray of dict
 - vector (OPVector):   dense (n, d) float32 array + `VectorMetadata`
@@ -25,7 +28,7 @@ from __future__ import annotations
 import math
 import numbers
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -58,6 +61,17 @@ def _is_integral(ftype: type) -> bool:
     return issubclass(ftype, T.Integral)
 
 
+def factorize_text(values) -> Tuple[np.ndarray, np.ndarray]:
+    """(codes, levels) of a 1-D array of cells: `codes` int32 with -1 for a
+    missing cell (None or float NaN), `levels` the object array of the
+    distinct present cells in first-seen order. One hash pass in C, no
+    sort and no stringification: a level keeps its python identity.
+    Raises TypeError on an unhashable cell."""
+    import pandas as pd
+    codes, levels = pd.factorize(np.asarray(values, dtype=object))
+    return codes.astype(np.int32), levels
+
+
 @dataclass
 class Column:
     """One feature's values for a batch, in columnar layout."""
@@ -65,6 +79,8 @@ class Column:
     ftype: type
     data: Any
     meta: Optional[VectorMetadata] = None
+    # (the `data` array it was made from, codes, levels): text kind only
+    _fact: Optional[tuple] = field(default=None, repr=False, compare=False)
 
     @property
     def kind(self) -> str:
@@ -91,13 +107,34 @@ class Column:
         raise TypeError(f"width undefined for kind {k}")
 
     # ------------------------------------------------------------------ #
+    # text factorization                                                 #
+    # ------------------------------------------------------------------ #
+
+    @property
+    def factorized(self) -> bool:
+        """Whether `factorization()` has been made for the `data` held."""
+        return self._fact is not None and self._fact[0] is self.data
+
+    def factorization(self) -> Tuple[np.ndarray, np.ndarray]:
+        """`factorize_text(data)`, made at most once for a `data` array (a
+        column whose `data` was replaced makes it again). A `take()` keeps
+        its parent's levels, so there a level may have no cell."""
+        if not self.factorized:
+            self._fact = (self.data, *factorize_text(self.data))
+        return self._fact[1], self._fact[2]
+
+    # ------------------------------------------------------------------ #
     # construction                                                       #
     # ------------------------------------------------------------------ #
 
     @staticmethod
     def from_values(ftype: type, values: Sequence[Any]) -> "Column":
         """Build a column from raw python values (each may be a FeatureType
-        instance or a plain value acceptable to `ftype`)."""
+        instance or a plain value acceptable to `ftype`). Typed storage
+        is not copied where it already is the column: a numeric array
+        gives value/mask without a per-cell pass, and an object array of
+        str|None IS the text column (aliasing the caller's array), with
+        its factorization attached."""
         k = kind_of(ftype)
         n = len(values)
 
@@ -156,6 +193,9 @@ class Column:
         # host encode at scale
         arr = np.empty(n, dtype=object)
         if k == TEXT:
+            col = _text_column_of(ftype, values)
+            if col is not None:
+                return col
             for i, v in enumerate(values):
                 arr[i] = v if (v is None or type(v) is str) else unwrap(v)
         else:
@@ -222,7 +262,30 @@ class Column:
             return Column(self.ftype, {key: np.asarray(a)[idx] for key, a in self.data.items()})
         if k == VECTOR:
             return Column(self.ftype, np.asarray(self.data)[idx], meta=self.meta)
-        return Column(self.ftype, self.data[idx])
+        out = Column(self.ftype, self.data[idx])
+        if self.factorized:
+            # a subset's levels are a subset: the codes stay valid
+            out._fact = (out.data, self._fact[1][idx], self._fact[2])
+        return out
+
+
+def _text_column_of(ftype: type, values) -> Optional[Column]:
+    """The text column that IS `values`, when `values` is a 1-D object
+    array holding only str and None: decided from one factorization (the
+    distinct levels' types, the missing cells' types), with no per-cell
+    python. None where anything else is seen (a FeatureType instance, a
+    number, NaN, an unhashable cell): the per-cell path's business."""
+    if not (isinstance(values, np.ndarray) and values.dtype == object
+            and values.ndim == 1):
+        return None
+    try:
+        codes, levels = factorize_text(values)
+    except TypeError:
+        return None
+    if not (set(map(type, levels)) <= {str}
+            and set(map(type, values[codes < 0])) <= {type(None)}):
+        return None
+    return Column(ftype, values, _fact=(values, codes, levels))
 
 
 def scalar_to_float(col: Column) -> np.ndarray:

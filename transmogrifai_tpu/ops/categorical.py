@@ -5,63 +5,91 @@ Reference parity: `core/.../feature/OpOneHotVectorizer.scala` /
 defaults TopK=20, MinSupport=10 (`Transmogrifier.scala:52-90`).
 
 TPU-first: the vocabulary (data-dependent) is resolved at fit time on host;
-the transform is a static-shape `one_hot` over integer ids — host_prepare
-maps strings → ids with a dict lookup, device_apply builds the dense pivot
-so XLA fuses it with the downstream combine/model matmul.
+the transform is a static-shape `one_hot` over integer ids. Neither host
+pass walks the cells in python: both read the column's factorization
+(`Column.factorization()`: integer codes into the distinct levels, made
+once a column) — the fit is a `bincount` of the codes and a ranking of the
+levels that reach `min_support`, host_prepare a take of the codes from a
+level → id table; device_apply builds the dense pivot so XLA fuses it with
+the downstream combine/model matmul.
 """
 
 from __future__ import annotations
 
 from collections import Counter
-from typing import Dict, List, Optional, Sequence, Tuple
+from itertools import repeat
+from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import jax.nn
 import jax.numpy as jnp
 import numpy as np
 
 from transmogrifai_tpu import types as T
-from transmogrifai_tpu.data.columns import Column
+from transmogrifai_tpu.data.columns import Column, factorize_text
 from transmogrifai_tpu.data.metadata import (
     NULL_INDICATOR, OTHER_INDICATOR, VectorColumnMetadata, VectorMetadata)
 from transmogrifai_tpu.obs.trace import TRACER
 from transmogrifai_tpu.stages.base import Estimator, FitContext, Transformer
 
 
+def _factorization(values: Union[Column, Sequence]
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+    """(codes, levels) of a text column (kept on the column) or of raw
+    cells (made here)."""
+    if isinstance(values, Column):
+        return values.factorization()
+    return factorize_text(values)
+
+
+def level_counts(values: Union[Column, Sequence]
+                 ) -> Tuple[np.ndarray, np.ndarray]:
+    """(levels, counts): the distinct present levels and the cells of
+    each. The one counting pass of every text pivot's fit; a `take()`
+    subset lists its parent's levels, some with no cell."""
+    codes, levels = _factorization(values)
+    return levels, np.bincount(codes[codes >= 0], minlength=len(levels))
+
+
+def rank_levels(levels: Sequence, counts: np.ndarray, top_k: int,
+                min_support: int) -> List[str]:
+    """The `top_k` most frequent of the levels that reach `min_support`,
+    count-desc then lexicographic for determinism. Python sees only the
+    levels at or above the `top_k`-th count (ties included)."""
+    counts = np.asarray(counts)
+    eligible = np.flatnonzero(counts >= max(min_support, 1))
+    if eligible.size > top_k > 0:
+        cut = eligible.size - top_k
+        eligible = eligible[
+            counts[eligible] >= np.partition(counts[eligible], cut)[cut]]
+    ranked = sorted((-int(counts[j]), levels[j]) for j in eligible)
+    return [lvl for _, lvl in ranked[:top_k]]
+
+
 def top_k_levels(counter: Counter, top_k: int, min_support: int) -> List[str]:
-    """Most frequent levels, count-desc then lexicographic for determinism."""
-    eligible = [(c, lvl) for lvl, c in counter.items() if c >= min_support]
-    eligible.sort(key=lambda t: (-t[0], t[1]))
-    return [lvl for _, lvl in eligible[:top_k]]
+    """`rank_levels` of a Counter (the map pivots count per key)."""
+    return rank_levels(list(counter),
+                       np.fromiter(counter.values(), np.int64, len(counter)),
+                       top_k, min_support)
 
 
-def pivot_encode_ids(values, lut: Dict[str, int], k: int) -> np.ndarray:
+def pivot_encode_ids(values: Union[Column, Sequence], lut: Dict[str, int],
+                     k: int) -> np.ndarray:
     """Map level strings → ids with OTHER=k, NULL=k+1 (shared by OneHotModel
-    and SmartTextModel so the two pivot encodings cannot drift).
+    and SmartTextModel so the two pivot encodings cannot drift). None and
+    float NaN are both missing.
 
-    Vectorized: id-map each UNIQUE level once, then gather — categorical
-    columns are overwhelmingly duplicated, so this replaces n dict lookups
-    with |levels| lookups + one unique/take (VERDICT r1 weak#5)."""
-    n = len(values)
-    arr = np.asarray(values, dtype=object)
-    # None and float NaN are both missing → NULL id (pd.factorize would
-    # otherwise code NaN as -1, which fancy-indexes the LAST level)
-    mask = np.fromiter((v is not None and v == v for v in arr),
-                       dtype=bool, count=n)
-    out = np.full(n, k + 1, dtype=np.int32)  # NULL id
-    present = arr[mask]
-    if present.size:
-        try:
-            # hash-based factorize: no sort, no stringification — levels
-            # keep their python identity for the lut lookup
-            import pandas as pd
-            inv, uniq = pd.factorize(present)
-            ids = np.fromiter((lut.get(u, k) for u in uniq), np.int32,
-                              len(uniq))
-            out[mask] = ids[inv]
-        except Exception:  # unhashable levels etc: direct per-row path
-            out[mask] = np.fromiter((lut.get(v, k) for v in present),
-                                    np.int32, present.size)
-    return out
+    Reads the factorization (a Column's own, kept on it; made here for raw
+    cells): a level → id table, OTHER wherever the vocabulary has no such
+    level, with one more slot at the end holding NULL, which is where a
+    missing cell's code -1 lands; the ids are one take of the codes. The
+    levels go through the vocabulary's dict in C, the cells through no
+    python at all."""
+    codes, levels = _factorization(values)
+    table = np.empty(len(levels) + 1, dtype=np.int32)
+    table[:-1] = np.fromiter(map(lut.get, levels, repeat(k)), np.int32,
+                             len(levels))
+    table[-1] = k + 1
+    return table[codes]
 
 
 def one_hot_np(ids: np.ndarray, k: int, track_nulls: bool) -> np.ndarray:
@@ -89,14 +117,18 @@ class OneHotModel(Transformer):
 
     def host_prepare(self, cols: Sequence[Optional[Column]]):
         # the text cells' host pass, named in the timeline under the
-        # stage's `stage:transform:*` span with the cells it read
+        # stage's `stage:transform:*` span with the cells it read, the
+        # columns that came with their codes made (`materialize` makes
+        # them: in a training pass all) and the distinct levels
         with TRACER.span("pivot:encode", category="pivot",
-                         cells=sum(len(c.data) for c in cols)):
-            return [
-                pivot_encode_ids(c.data, self._lookups[i],
-                                 len(self.vocabs[i]))
+                         cells=sum(len(c.data) for c in cols),
+                         codes_reused=sum(c.factorized for c in cols)) as sp:
+            ids = [
+                pivot_encode_ids(c, self._lookups[i], len(self.vocabs[i]))
                 for i, c in enumerate(cols)
             ]
+            sp.set(levels=sum(len(c.factorization()[1]) for c in cols))
+            return ids
 
     def device_apply(self, enc, dev):
         outs = []
@@ -145,11 +177,15 @@ class OneHotVectorizer(Estimator):
 
     def fit_model(self, cols: Sequence[Column], ctx: FitContext) -> Transformer:
         vocabs = []
-        with TRACER.span("pivot:fit", category="pivot", columns=len(cols)):
+        with TRACER.span("pivot:fit", category="pivot", columns=len(cols),
+                         codes_reused=sum(c.factorized for c in cols)) as sp:
+            n_levels = 0
             for c in cols:
-                counter = Counter(s for s in c.data if s is not None)
-                vocabs.append(
-                    top_k_levels(counter, self.top_k, self.min_support))
+                levels, counts = level_counts(c)
+                n_levels += len(levels)
+                vocabs.append(rank_levels(
+                    levels, counts, self.top_k, self.min_support))
+            sp.set(levels=n_levels)
         return OneHotModel(vocabs, self.track_nulls)
 
 

@@ -14,7 +14,6 @@ cross-process determinism (python's hash() is salted), memoized per token.
 from __future__ import annotations
 
 import re
-from collections import Counter
 from typing import Dict, List, Optional, Sequence
 
 import jax.numpy as jnp
@@ -25,7 +24,7 @@ from transmogrifai_tpu.data.columns import Column
 from transmogrifai_tpu.data.metadata import (
     NULL_INDICATOR, VectorColumnMetadata, VectorMetadata)
 from transmogrifai_tpu.ops.categorical import (
-    one_hot_np, pivot_encode_ids, top_k_levels)
+    level_counts, one_hot_np, pivot_encode_ids, rank_levels)
 from transmogrifai_tpu.stages.base import (
     Estimator, FitContext, HostTransformer, Transformer)
 
@@ -504,7 +503,7 @@ class SmartTextModel(Transformer):
             n = len(c.data)
             if strat == PIVOT:
                 lut, k = self._lookups[i], len(self.vocabs[i])
-                block = one_hot_np(pivot_encode_ids(c.data, lut, k), k,
+                block = one_hot_np(pivot_encode_ids(c, lut, k), k,
                                    self.track_nulls)
             elif strat == HASH:
                 hasher = TokenHasher(self.num_features, self.seed + i)
@@ -593,15 +592,16 @@ class SmartTextVectorizer(Estimator):
     def fit_model(self, cols: Sequence[Column], ctx: FitContext) -> Transformer:
         strategies, vocabs = [], []
         for c in cols:
-            counter = Counter(s for s in c.data if s is not None)
-            n_values = sum(counter.values())
-            n_distinct = len(counter)
+            levels, counts = level_counts(c)
+            n_values = int(counts.sum())
+            n_distinct = int(np.count_nonzero(counts))
             if n_distinct == 0:
                 strategies.append(IGNORE)
                 vocabs.append([])
             elif n_distinct <= self.max_cardinality:
                 strategies.append(PIVOT)
-                vocabs.append(top_k_levels(counter, self.top_k, self.min_support))
+                vocabs.append(rank_levels(
+                    levels, counts, self.top_k, self.min_support))
             elif n_values > 0 and n_distinct / n_values >= self.id_detect_ratio:
                 strategies.append(IGNORE)  # ID-like: every value unique
                 vocabs.append([])

@@ -22,7 +22,7 @@ import numpy as np
 
 _log = logging.getLogger(__name__)
 
-from transmogrifai_tpu.data.columns import Column
+from transmogrifai_tpu.data.columns import TEXT, Column
 from transmogrifai_tpu.data.dataset import Dataset
 from transmogrifai_tpu.features.dag import clone_graph, topological_layers
 from transmogrifai_tpu.obs.metrics import get_registry
@@ -212,12 +212,18 @@ class Workflow:
         columns: Dict[str, Column] = {}
         fitted: Dict[str, Transformer] = {}
 
-        with TRACER.span("workflow:materialize", category="workflow"):
+        with TRACER.span("workflow:materialize", category="workflow") as sp:
             for gen in layers[0] if layers else []:
                 if not isinstance(gen, FeatureGeneratorStage):
                     raise TypeError(
                         f"Layer-0 stage {gen!r} is not a feature generator")
                 columns[gen.get_output().uid] = gen.materialize(ds)
+            # `text_factorized` short of `text_columns`: a column took
+            # `Column.from_values`' per-cell path (not str|None storage)
+            text = [c for c in columns.values() if c.kind == TEXT]
+            sp.set(text_columns=len(text),
+                   text_cells=sum(len(c) for c in text),
+                   text_factorized=sum(c.factorized for c in text))
 
         n_fits = 0
         for li, layer in enumerate(layers[1:], start=1):
