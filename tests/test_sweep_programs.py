@@ -22,7 +22,8 @@ from transmogrifai_tpu.evaluators.metrics import auroc_score
 from transmogrifai_tpu.models import (
     OpGeneralizedLinearRegression, OpLinearRegression, OpLinearSVC,
     OpLogisticRegression, OpMultilayerPerceptronClassifier, OpNaiveBayes,
-    OpRandomForestClassifier, OpRandomForestRegressor, OpXGBoostClassifier)
+    OpGBTRegressor, OpRandomForestClassifier, OpRandomForestRegressor,
+    OpXGBoostClassifier)
 from transmogrifai_tpu.models import trees
 from transmogrifai_tpu.obs.trace import TRACER
 from transmogrifai_tpu.parallel import sweep as S
@@ -104,6 +105,13 @@ CASES = {
         lambda: OpXGBoostClassifier(n_estimators=3, max_bins=8,
                                     early_stopping_rounds=0),
         [{"max_depth": 2}, {"max_depth": 3}], "binary", BINARY),
+    "boosted-regressor-chunked": (
+        lambda: OpGBTRegressor(n_estimators=3, max_bins=8),
+        [{"max_depth": 2}, {"max_depth": 3}], "regression", REG),
+    "linreg-enet": (
+        lambda: OpLinearRegression(),
+        [{"reg_param": 0.01, "elastic_net_param": 0.1},
+         {"reg_param": 0.1, "elastic_net_param": 0.5}], "regression", REG),
     "boosted-multiclass": (
         lambda: OpXGBoostClassifier(n_estimators=2, max_bins=8,
                                     early_stopping_rounds=0),

@@ -562,6 +562,34 @@ def _lower_linreg():
         a, b, w, 0.01, 0.01, max_iter=3)).lower(X, y, jnp.ones(16))
 
 
+def _lower_linreg_block():
+    """The sweep's least-squares block as `_block_program` builds it: the
+    elastic-net fit and its prediction under the grid and fold vmaps."""
+    from transmogrifai_tpu.parallel import sweep as S
+    fit_predict = S._fp_linreg((True,))
+    X = jnp.ones((16, 3), jnp.float32)
+    data = {"X": X, "y": jnp.asarray(np.arange(16), jnp.float32)}
+
+    def one(d, w):
+        return fit_predict(data, d, w, w)["prediction"]
+
+    return jax.jit(jax.vmap(one)).lower(
+        {"l1": jnp.full(2, 0.01), "l2": jnp.full(2, 0.01)},
+        jnp.ones((2, 16)))
+
+
+def _lower_regression_metric_vmap():
+    return _lower_metric(RegressionEvaluator(), "prediction", batch=3)
+
+
+def _lower_selector_regression_metric():
+    """The program behind a regression selector's train and holdout
+    metrics (`RegressionEvaluator.evaluate_device`)."""
+    from transmogrifai_tpu.evaluators.device_metrics import (
+        regression_metrics_dev)
+    return regression_metrics_dev("RMSE").lower(jnp.ones(16), jnp.ones(16))
+
+
 def _lower_metric(evaluator, pred_key, batch=None):
     """The metric's program; with `batch`, under the sweep's vmap over
     score vectors and fold masks."""
@@ -601,6 +629,9 @@ SCOPES = [
     ("tree:hist:ind", _lower_grow_tree_classes_two_blocks),
     ("metric:f1", _lower_multiclass_metric),
     ("metric:f1", _lower_multiclass_metric_vmap),
+    ("linear:fista", _lower_linreg_block),
+    ("metric:rmse", _lower_regression_metric_vmap),
+    ("metric:rmse", _lower_selector_regression_metric),
 ]
 
 

@@ -464,6 +464,9 @@ class SanityChecker(Estimator):
             oh = None if meta is None else _label_onehot(
                 y_np, self.categorical_label_max_card,
                 force=self.categorical_label)
+            # False: a label of too many (or fractional) values, every
+            # decision is from the moments and the label correlations
+            sp.set(categorical_label=oh is not None)
             if oh is not None:
                 groups: Dict[str, List[int]] = {}
                 for i, c in enumerate(meta.columns):

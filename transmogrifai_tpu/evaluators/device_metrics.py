@@ -24,6 +24,8 @@ the result is the same float in any order within a tie.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -207,6 +209,19 @@ def regression_dev(y, pred, mask):
     ss_res = (err ** 2).sum()
     r2 = jnp.where(ss_tot > 0, 1.0 - ss_res / jnp.maximum(ss_tot, 1e-30), 0.0)
     return {"RMSE": jnp.sqrt(mse), "MSE": mse, "MAE": mae, "R2": r2}
+
+
+@functools.lru_cache(maxsize=None)
+def regression_metrics_dev(metric: str = "RMSE"):
+    """`prog(y, pred)`: `regression_dev` over every row as one held
+    program, under the kernel name the sweep's regression metric has
+    (`metric:<metric>`): a selector's train and holdout metrics, of
+    which only the four scalars cross to the host."""
+    @jax.jit
+    @jax.named_scope(f"metric:{metric.lower()}")
+    def prog(y, pred):
+        return regression_dev(y, pred, jnp.ones(y.shape, jnp.float32))
+    return prog
 
 
 def _binary_scores(pred: dict) -> jnp.ndarray:

@@ -103,6 +103,25 @@ class RegressionEvaluator(Evaluator):
         pred = np.asarray(prediction.data["prediction"], dtype=np.float64)
         return regression_metrics(y, pred)
 
+    def evaluate_device(self, y, pred: dict, n_classes=None):
+        """`evaluate` of device arrays: the four metrics are reduced on
+        the device (`regression_dev`, float32 sums under the scope
+        `metric:<default metric>`) and only those scalars cross to the
+        host; no (n,) prediction does. The signed-percentage-error
+        histogram is the host form's alone (a selector's summary keeps
+        scalars). `n_classes` is the classifiers' argument and is not
+        read."""
+        import jax.numpy as jnp
+
+        from transmogrifai_tpu.evaluators.device_metrics import (
+            regression_metrics_dev)
+        from transmogrifai_tpu.evaluators.metrics import RegressionMetrics
+        m = regression_metrics_dev(self.default_metric)(
+            jnp.asarray(y, jnp.float32), pred["prediction"])
+        return RegressionMetrics(
+            rmse=float(m["RMSE"]), mse=float(m["MSE"]), mae=float(m["MAE"]),
+            r2=float(m["R2"]))
+
 
 class BinScoreEvaluator(Evaluator):
     """Score-decile calibration (`OpBinScoreEvaluator.scala:53`)."""

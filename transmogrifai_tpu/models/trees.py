@@ -287,9 +287,18 @@ def _histograms(B, node_idx, G, H, n_nodes: int, block: str = "wide"):
     (n, (m+1)·nodes) concatenation cost more than the saved B reads —
     the OPPOSITE tradeoff from the out-of-core path (d=500, B per-chunk
     rebuilt), where `parallel/bigdata.py` stacks. What still comes here
-    is a regressor's one column and a boosted round's gradients; a
-    CLASSIFIER's K columns are one-hot times one weight and need no
-    stacking at all: `_class_histograms`.
+    is a regressor's one column and a boosted round's gradients (m = 1:
+    the operand is read twice a level, `hist_reads` 2); a CLASSIFIER's
+    K columns are one-hot times one weight and need no stacking at all:
+    `_class_histograms`. The regression shape, timed on one TPU v5e
+    (PR 32, from the traced pass's device operations): 4,500,000 rows ×
+    (6 wide + 63 two-valued columns, 318 slots), one value column. A
+    depth-6 squared-loss round is 0.144 s, of which the histograms,
+    splits and routing of its six levels are 0.065 s (10.8 ms a level)
+    and the round's two float32 leaf scatter-adds (`grow_tree`'s
+    `.at[node_idx].add`, 39 ms each) 0.079 s: at this height the leaf
+    sums, not the histograms, are over half of a boosted round. A
+    depth-12 regression tree with its bootstrap is 1.8 s.
 
     Value precision is governed by HIST_PRECISION (see above)."""
     n, d, nb = B.shape
@@ -872,6 +881,30 @@ def _gbt_val_loss(margin, y, val_w, objective: str,
     return (((margin - y) ** 2) * val_w).sum() / vs
 
 
+def gbt_base_score(y, w, objective: str):
+    """Where a boosted chain starts: for squared loss the weighted mean
+    of the target (the constant that minimises it, so the 20 rounds of a
+    `learning_rate` 0.1 chain are not spent walking from 0 to a target
+    whose mean is far from it), 0 for the logistic margin. One rule for
+    the sweep's chains, the refit and the model's prediction
+    (`GBTRegressionModel.base_score`)."""
+    if objective != "squared":
+        return jnp.float32(0.0)
+    return ((y * w).sum() / jnp.maximum(w.sum(), 1e-12)).astype(jnp.float32)
+
+
+def gbt_train_summary(margin, y, w, objective: str) -> Dict:
+    """What a finished chain says of the rows it was fitted on:
+    `train_loss`, the objective's own loss (mean squared error, or the
+    logistic loss) of its final margin under its training weights, and
+    `train_weight`, their sum. The sweep puts both on a fold chain's
+    `sweep:fetch:gbt` span: beside the validation metric they show what
+    the chain fitted and how far it got (two scalars a chain; no row
+    crosses to the host)."""
+    return {"train_loss": _gbt_val_loss(margin, y, w, objective),
+            "train_weight": w.sum()}
+
+
 def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
               max_depth: int, n_bins: int, learning_rate, reg_lambda,
               objective: str, min_child_weight, active_depth, gamma, alpha,
@@ -936,6 +969,7 @@ def fit_gbt(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
             eval_metric: str = "logloss", layout: Optional[Dict] = None):
     """Returns (trees, final_margin): the scan carry already holds the full
     training-matrix margin, so sweep callers need not re-walk the forest.
+    The chain starts at `gbt_base_score` (the margin includes it).
 
     XGBoost param surface (OpXGBoostClassifier.scala / XGBoostParams.scala):
     `gamma` = min split gain, `alpha` = leaf L1, `subsample` = per-round
@@ -948,7 +982,9 @@ def fit_gbt(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
         early_stopping_rounds = 0
     keys = jax.random.split(jax.random.PRNGKey(seed), n_estimators)
     (margin, _, _), trees = _gbt_scan(
-        Xb, y, w, val_w, jnp.zeros(n, jnp.float32), jnp.float32(jnp.inf),
+        Xb, y, w, val_w,
+        jnp.full(n, gbt_base_score(y, w, objective), jnp.float32),
+        jnp.float32(jnp.inf),
         jnp.int32(0), keys, max_depth, n_bins, learning_rate, reg_lambda,
         objective, min_child_weight, active_depth, gamma, alpha, subsample,
         colsample, early_stopping_rounds, min_gain_norm, eval_metric,
@@ -1016,9 +1052,13 @@ def dispatch_plan(n_rows: int, slots: int, pad_depth: int, learners: int,
     shape), capped by the pair count: at its power-of-two floor where
     the caller pads the last chunk (`pad_tail`), at the count itself
     otherwise. The sweep and the refit (`n_pairs` 1) both plan here, so
-    a shape compiles the same rounds in either. Neither budget has been
-    timed at more than two value columns: at 1,800,000 rows either term
-    already pins the width to 1 for any K (the cells' shapes)."""
+    a shape compiles the same rounds in either. What the cells' shapes
+    give (each timed on the chip, none at a width over 1 beyond two
+    value columns): at 1,800,000 rows × 1,042 slots either term pins
+    the width to 1 for any K; at 4,500,000 rows × 318 slots and depth 6
+    (PR 32) the memory term alone does (the bin one-hots of one pair
+    are 3.4 GB of the 4 GiB), and the work term lets a pair's whole
+    chain of 10 or 20 rounds into one dispatch, 1.4 or 2.9 s."""
     nodes = 2 ** min(pad_depth, 14)
     k = max(int(value_columns), 1)
     unit = max(n_rows * nodes * slots * k, 1)   # one learner of one pair
@@ -1045,12 +1085,13 @@ def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
                    early_stopping_rounds: int = 0,
                    rounds_per_dispatch: Optional[int] = None,
                    min_gain_norm=0.0, eval_metric: str = "logloss",
-                   layout: Optional[Dict] = None):
+                   layout: Optional[Dict] = None, base_score=None):
     """Host-chunked boosting: bitwise-identical trees/margin to `fit_gbt`
     (same key stream, same scan body) but dispatched `rounds_per_dispatch`
     rounds at a time so early stopping SKIPS the remaining dispatches
     instead of masking them. Used for refits whose full scan would be
-    tens of seconds (200-round depth-10 at 100k rows)."""
+    tens of seconds (200-round depth-10 at 100k rows). `base_score`:
+    where the chain starts, `gbt_base_score` of (y, w) when None."""
     n, d = Xb.shape
     esr = int(early_stopping_rounds) if val_w is not None else 0
     if val_w is None:
@@ -1059,7 +1100,9 @@ def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
         _, rounds_per_dispatch = dispatch_plan(
             n, hist_slots(d, n_bins, layout), max_depth, n_estimators)
     keys = jax.random.split(jax.random.PRNGKey(seed), n_estimators)
-    margin = jnp.zeros(n, jnp.float32)
+    if base_score is None:
+        base_score = gbt_base_score(y, w, objective)
+    margin = jnp.full(n, base_score, jnp.float32)
     best = jnp.float32(jnp.inf)
     since = jnp.int32(0)
     chunks = []
@@ -1270,7 +1313,8 @@ def warm_refit_gbt(est, warm: Dict, X, y, w, ctx,
     lr = jnp.float32(warm.get("learning_rate", est.learning_rate))
     Xb = bin_features(jnp.asarray(X), edges)
     n = Xb.shape[0]
-    margin0 = predict_gbt_margin(old, Xb, lr)
+    margin0 = jnp.float32(warm.get("base_score", 0.0)) \
+        + predict_gbt_margin(old, Xb, lr)
     n_extra = int(warm.get("n_new") or 0)
     if n_extra <= 0:
         n_extra = max(1, est.n_estimators // 4)
@@ -1384,9 +1428,24 @@ class GBTClassificationModel(_TreeModelBase):
 
 
 class GBTRegressionModel(GBTClassificationModel):
+    """`base_score`: where the chain started (`gbt_base_score`: the
+    training target's weighted mean); a model saved before it existed
+    loads with 0, which is where its chain started."""
+
+    def __init__(self, edges=None, trees=None, learning_rate: float = 0.1,
+                 base_score: float = 0.0, uid: Optional[str] = None):
+        super().__init__(edges=edges, trees=trees,
+                         learning_rate=learning_rate, uid=uid)
+        self.base_score = float(base_score)
+
+    def get_params(self):
+        d = super().get_params()
+        d["base_score"] = self.base_score
+        return d
+
     def _apply_arrays(self, trees, Xb):
-        margin = predict_gbt_margin(trees, Xb,
-                                    jnp.float32(self.learning_rate))
+        margin = jnp.float32(self.base_score) + predict_gbt_margin(
+            trees, Xb, jnp.float32(self.learning_rate))
         return gbt_pred_from_margin(margin, "squared")
 
 
@@ -1640,9 +1699,10 @@ class OpGBTClassifier(_TreeEstimatorBase):
             else:
                 trees = warm_refit_gbt(self, warm, X, y, w, ctx,
                                        self._objective)
-                return self._model_cls(
+                return self._model(
                     np.asarray(warm["edges"], np.float32), trees,
-                    float(warm.get("learning_rate", self.learning_rate)))
+                    float(warm.get("learning_rate", self.learning_rate)),
+                    warm.get("base_score", 0.0))
         edges, Xb, layout = self._edges_binned(X, ctx)
         seed = ctx.seed if ctx is not None else 0
         if self._objective == "logistic" and k > 2:
@@ -1705,6 +1765,7 @@ class OpGBTClassifier(_TreeEstimatorBase):
             rpd_refit = None
         # Pass 2 (or the only pass) — the shipped model: full weights,
         # fixed round count, no holdout.
+        base = gbt_base_score(y, w, self._objective)
         trees, _ = fit_gbt_hosted(
             Xb, y, w, n_rounds, self.max_depth,
             self.max_bins, jnp.float32(self.learning_rate),
@@ -1715,9 +1776,18 @@ class OpGBTClassifier(_TreeEstimatorBase):
             subsample=jnp.float32(self.subsample),
             colsample=jnp.float32(self.colsample_bytree),
             seed=seed, rounds_per_dispatch=rpd_refit,
-            min_gain_norm=jnp.float32(self.min_info_gain), layout=layout)
-        return self._model_cls(edges, {k2: np.asarray(v) for k2, v in trees.items()},
-                               self.learning_rate)
+            min_gain_norm=jnp.float32(self.min_info_gain), layout=layout,
+            base_score=base)
+        return self._model(
+            edges, {k2: np.asarray(v) for k2, v in trees.items()},
+            self.learning_rate, base)
+
+    def _model(self, edges, trees, learning_rate, base_score):
+        """The fitted model; a squared-loss one keeps where its chain
+        started."""
+        started = ({"base_score": float(base_score)}
+                   if self._objective == "squared" else {})
+        return self._model_cls(edges, trees, learning_rate, **started)
 
 
 class OpGBTRegressor(OpGBTClassifier):

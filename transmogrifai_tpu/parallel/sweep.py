@@ -70,9 +70,9 @@ from transmogrifai_tpu.models.trees import (
     OpXGBoostClassifier, OpXGBoostRegressor,
     bin_features, dispatch_plan, fit_forest, fit_gbt, fit_gbt_multiclass,
     forest_classification_pred, forest_regression_pred,
-    gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
-    edges_site, hist_layout, hist_reads, hist_slots, indicator_columns,
-    quantile_bin_edges)
+    gbt_base_score, gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
+    gbt_train_summary, edges_site, hist_layout, hist_reads, hist_slots,
+    indicator_columns, quantile_bin_edges)
 from transmogrifai_tpu.runtime.faults import (
     SITE_RUN_BLOCK, fault_point, is_oom_error)
 
@@ -355,15 +355,17 @@ SWEEP_STATS = SweepStats()
 
 
 @contextlib.contextmanager
-def _dispatch_span(family: str, timed: bool = False):
-    """One device dispatch: holds a `sweep:dispatch:<family>` span open,
+def _dispatch_span(family: str, timed: bool = False, **attributes):
+    """One device dispatch: holds a `sweep:dispatch:<family>` span open
+    (`attributes`: what the dispatch holds, on the span),
     so the dispatch — and the XLA compile a first dispatch asks for, as
     the span's `compile:*` child — sits in the run's timeline
     (`instrumented_jit` drops a `recompile` event on it when the program
     is traced, `utils/compile_cache.py` a `compile_cache_hit`). `timed`
     (the tree families' host loops) also counts a dispatch that
     completed, and its wall, in `SWEEP_STATS`."""
-    with TRACER.span(f"sweep:dispatch:{family}", category="sweep_dispatch"):
+    with TRACER.span(f"sweep:dispatch:{family}", category="sweep_dispatch",
+                     **attributes):
         t0 = time.perf_counter()
         yield
         if timed:
@@ -1119,18 +1121,21 @@ def _gbt_rounds_program(static: Tuple, pad_depth: int, objective: str,
 @functools.lru_cache(maxsize=_HELD_PROGRAMS)
 def _gbt_score_program(static: Tuple, objective: str,
                        metric_key: Optional[Tuple]) -> Callable:
-    """`prog(y, margin, Vsel)`: the chunked boosted sweep's final margins
-    to fold metrics — or, without a device kernel (`metric_key` None), to
-    the prediction pytree the host evaluator reads."""
+    """`prog(y, margin, Vsel, Wsel)`: the chunked boosted sweep's final
+    margins to (fold metrics, `gbt_train_summary` under the folds'
+    training weights) — or, without a device kernel (`metric_key` None),
+    the prediction pytree the host evaluator reads in the metrics'
+    place."""
     from transmogrifai_tpu.analysis.retrace import instrumented_jit
     metric_fn = None if metric_key is None else device_metric(metric_key)
 
-    def score(y, margin, v):
+    def score(y, margin, v, w):
         pred = gbt_pred_from_margin(margin, objective)
-        return pred if metric_fn is None else metric_fn(y, pred, v)
+        return (pred if metric_fn is None else metric_fn(y, pred, v),
+                gbt_train_summary(margin, y, w, objective))
 
     return instrumented_jit(
-        jax.vmap(score, in_axes=(None, 0, 0)),
+        jax.vmap(score, in_axes=(None, 0, 0, 0)),
         label=f"sweep:gbt:{static!r}:"
               + ("pred" if metric_fn is None else "metric"))
 
@@ -1251,13 +1256,25 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             dchunk = {k: v_[jnp.asarray(gs)] for k, v_ in dyn.items()}
             Wsel = W[jnp.asarray(fs)]
             Vsel = V[jnp.asarray(fs)]
-            margin = jnp.zeros((width, n_rows), jnp.float32)
+            if objective == "squared":
+                # each pair's chain starts at its own fold's weighted mean
+                base = jax.vmap(lambda w: gbt_base_score(
+                    data["y"], w, objective))(Wsel)
+                margin = jnp.broadcast_to(base[:, None], (width, n_rows))
+            else:
+                margin = jnp.zeros((width, n_rows), jnp.float32)
             best = jnp.full((width,), jnp.inf, jnp.float32)
             since = jnp.zeros((width,), jnp.int32)
             done = 0
             while done < n_est:
                 ks = keys_all[done:done + rpd]
-                with _dispatch_span("gbt", timed=True):
+                # `pairs`: the dispatch's real pairs (a padded tail
+                # repeats the last one up to the compiled width)
+                with _dispatch_span("gbt", timed=True,
+                                    rounds=int(ks.shape[0]),
+                                    pairs=min(width, n_pairs - s),
+                                    pad_depth=pad_depth,
+                                    objective=objective):
                     margin, best, since = jax.block_until_ready(
                         prog(data, dchunk, Wsel, Vsel, margin, best, since,
                              ks))
@@ -1269,7 +1286,7 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                     break
             if host:
                 pred_np = jax.tree_util.tree_map(
-                    np.asarray, score_prog(data["y"], margin, Vsel))
+                    np.asarray, score_prog(data["y"], margin, Vsel, Wsel)[0])
                 row_metrics = [
                     _metric(metric_fn.evaluator, y_np,
                             jax.tree_util.tree_map(
@@ -1277,9 +1294,18 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                             V_np[fs[t]])
                     for t in range(width)]
             else:
-                with TRACER.span("sweep:fetch:gbt", category="sweep_fetch"):
-                    row_metrics = [float(m) for m in np.asarray(
-                        score_prog(data["y"], margin, Vsel))]
+                with TRACER.span("sweep:fetch:gbt",
+                                 category="sweep_fetch") as fetch:
+                    row_metrics, fitted = jax.device_get(
+                        score_prog(data["y"], margin, Vsel, Wsel))
+                    row_metrics = [float(m) for m in row_metrics]
+                    # the dispatch's real pairs, as (grid, fold), and
+                    # what each chain says of its training rows
+                    real = range(min(width, n_pairs - s))
+                    fetch.set(grids=[idxs[gs[t]] for t in real],
+                              folds=[fs[t] for t in real],
+                              **{k: [float(v[t]) for t in real]
+                                 for k, v in fitted.items()})
             for t in range(min(width, n_pairs - s)):
                 row_i, j = divmod(s + t, n_folds)
                 if metrics[idxs[row_i]] is None:

@@ -159,7 +159,8 @@ class ModelSelector(Estimator):
                            and self.checkpoint_dir is not None else None)
 
         with TRACER.span("selector:sweep", category="selector",
-                         classes=ctx.n_classes or 0):
+                         classes=ctx.n_classes or 0,
+                         problem=self.problem_type):
             results, failures = self._sweep(
                 ctx, X, y_dev, folds, train_idx, data_digest)
         # the sweep's padded/sharded data and binned matrices die with it:
@@ -571,16 +572,20 @@ class ModelSelector(Estimator):
                 X, y_dev, jnp.ones_like(y_dev), ctx)
 
         # -- evaluate train + holdout ------------------------------------ #
+        # on the device where the evaluator can and counts stay exact in
+        # float32: only a (K, K) table (a regressor's few sums) crosses
+        # to the host, not (n, K) probabilities or an (n,) prediction
+        on_device = getattr(self.evaluator, "evaluate_device", None)
+        if max(len(train_idx), len(test_idx)) >= (1 << 24):
+            on_device = None
+
         def _eval(idx: np.ndarray, rows=None, y=None) -> Dict[str, Any]:
             if len(idx) == 0:
                 return {}
             if rows is None:
                 rows = X_full[jnp.asarray(idx)]
             pred = model.predict_arrays(rows)
-            on_device = getattr(self.evaluator, "evaluate_device", None)
-            if on_device is not None and len(idx) < (1 << 24):
-                # counts stay exact in float32: only a (K, K) table
-                # crosses to the host, not (n, K) probabilities
+            if on_device is not None:
                 if y is None:
                     y = jnp.asarray(y_np[idx], jnp.float32)
                 m = on_device(y, pred, ctx.n_classes)
@@ -596,7 +601,8 @@ class ModelSelector(Estimator):
             return {k: v for k, v in m.to_json().items()
                     if k == "Confusion" or not isinstance(v, list)}
 
-        with TRACER.span("selector:evaluate", category="selector"):
+        with TRACER.span("selector:evaluate", category="selector",
+                         on_device=on_device is not None):
             # the prepared train rows are at hand: a second gather of them
             # is a table-sized buffer (and its scratch) for nothing
             train_metrics = _eval(train_idx, X, y_dev)
