@@ -219,6 +219,10 @@ def test_sweep_dispatches_are_spans_under_the_block(family):
     # logistic block is spanned and not counted there
     counted = SWEEP_STATS.dispatches - d0
     assert counted == (0 if family == "logistic" else len(dispatches))
+    # a tree dispatch says which form its trees' leaf sums take (the
+    # padded depth's leaf count through `trees.leaf_sums_form`)
+    assert {s.attributes.get("leaf_sums") for s in dispatches} == {
+        None if family == "logistic" else "product"}
     # a first dispatch's compile is a child of the dispatch
     compiles = [s for s in spans if s.name.startswith(
         f"compile:sweep:dispatch:{family}/")]
@@ -458,6 +462,28 @@ def test_an_estimators_own_binning_states_value_columns_and_reads(
               if s.name == "tree:edges"]
     assert edges.attributes["value_columns"] == columns
     assert edges.attributes["hist_reads"] == reads
+    assert edges.attributes["leaf_sums"] == "product"
+
+
+@pytest.mark.parametrize("depth,classes,form", [
+    (13, 0, "product"), (14, 0, "scatter"),
+    (12, 5, "product"), (13, 5, "scatter")])
+def test_an_estimators_own_binning_states_its_leaf_sums(depth, classes,
+                                                        form):
+    # the attribute alone (a depth-14 fit is not run here): what the
+    # span is given is the rule at the estimator's own depth and classes
+    est = (OpRandomForestClassifier(n_trees=1, max_depth=depth, max_bins=8,
+                                    n_classes=classes) if classes
+           else trees.OpRandomForestRegressor(n_trees=1, max_depth=depth,
+                                              max_bins=8))
+    X = jnp.asarray(np.random.default_rng(0).normal(size=(32, 2)),
+                    jnp.float32)
+    with TRACER.span("run:edges", new_trace=True) as root:
+        est._edges_binned(X, FitContext(n_rows=32, seed=1),
+                          n_classes=classes)
+    edges, = [s for s in TRACER.trace_spans(root.trace_id)
+              if s.name == "tree:edges"]
+    assert edges.attributes["leaf_sums"] == form
 
 
 # --------------------------------------------------------------------- #
@@ -476,6 +502,33 @@ def _lower_grow_tree():
     Xb, G, H = _tree_inputs()
     return jax.jit(lambda a, g, h: trees.grow_tree(a, g, h, 2, 4)) \
         .lower(Xb, G, H)
+
+
+def _lower_grow_tree_depth6():
+    """A depth-6 regressor's tree: a squared-loss boosted round's."""
+    Xb, G, H = _tree_inputs()
+    return jax.jit(lambda a, g, h: trees.grow_tree(a, g, h, 6, 4)) \
+        .lower(Xb, G, H)
+
+
+def _lower_grow_tree_depth14():
+    """16,384 leaves: past the crossover, the leaf sums scatter."""
+    Xb, G, H = _tree_inputs()
+    return jax.jit(lambda a, g, h: trees.grow_tree(a, g, h, 14, 4)) \
+        .lower(Xb, G, H)
+
+
+def _lower_gbt_chunk():
+    """Two boosted rounds as the sweep's round-chunked dispatch runs
+    them."""
+    Xb, G, H = _tree_inputs()
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    return trees.fit_gbt_chunk.lower(
+        Xb, G[:, 0], H, jnp.zeros_like(H), jnp.zeros_like(H),
+        jnp.float32(jnp.inf), jnp.int32(0), keys, n_rounds=2, max_depth=6,
+        n_bins=4, learning_rate=0.1, reg_lambda=1.0, objective="squared",
+        min_child_weight=1.0, active_depth=None, gamma=0.0, alpha=0.0,
+        subsample=1.0, colsample=1.0, early_stopping_rounds=0)
 
 
 def _lower_forest():
@@ -632,6 +685,14 @@ SCOPES = [
     ("linear:fista", _lower_linreg_block),
     ("metric:rmse", _lower_regression_metric_vmap),
     ("metric:rmse", _lower_selector_regression_metric),
+    ("tree:leaf", _lower_grow_tree_depth6),
+    ("tree:leaf:product", _lower_grow_tree_depth6),
+    ("tree:leaf", _lower_gbt_chunk),
+    ("tree:leaf:product", _lower_gbt_chunk),
+    ("tree:leaf", _lower_grow_tree_classes),
+    ("tree:leaf:product", _lower_grow_tree_classes),
+    ("tree:leaf:product", _lower_forest),
+    ("tree:leaf:scatter", _lower_grow_tree_depth14),
 ]
 
 
@@ -679,11 +740,34 @@ def test_multiclass_metric_program_is_a_product_and_no_scatter(batch):
 
 def test_a_classifiers_tree_program_reads_the_operand_once_a_level():
     # depth 2, one block: one histogram product a level (the per-column
-    # form would hold K + 1 = 4 a level), none for the leaves
+    # form would hold K + 1 = 4 a level) and ONE for the leaves (PR 33:
+    # all the tree's leaf sums are one one-hot product)
     text = _lower_grow_tree_classes().as_text()
-    assert len(re.findall(r"stablehlo\.dot_general", text)) == 2
+    assert len(re.findall(r"stablehlo\.dot_general", text)) == 2 + 1
     per_column = _lower_grow_tree().as_text()      # one target + weights
-    assert len(re.findall(r"stablehlo\.dot_general", per_column)) == 4
+    assert len(re.findall(r"stablehlo\.dot_general", per_column)) == 4 + 1
+
+
+@pytest.mark.parametrize("lower", [
+    _lower_grow_tree_depth6, _lower_gbt_chunk, _lower_grow_tree_classes],
+    ids=["regressor-depth6", "gbt-chunk", "classifier"])
+def test_a_tree_program_holds_no_scatter_where_the_rule_says_product(lower):
+    # a scatter-add of every row is a serial pass on the chip: 39 ms for
+    # 4.5 M rows, twice a tree, where the product takes 1.5 ms
+    # (the per-level `feat` and `bin` tables are set by scatters of a few
+    # nodes, under no leaf scope: the ones looked for are the leaves')
+    lowered = lower()
+    text = lowered.as_text(debug_info=True)
+    assert "tree:leaf:product" in text
+    assert not re.search(r'tree:leaf[^"\n]*scatter', text)
+    assert not [line for line in lowered.compile().as_text().splitlines()
+                if re.search(r"\bscatter\(", line) and "tree:leaf" in line]
+
+
+def test_a_tree_program_past_the_crossover_scatters_its_leaves():
+    text = _lower_grow_tree_depth14().as_text(debug_info=True)
+    assert re.search(r'tree:leaf/tree:leaf:scatter/scatter-add"', text)
+    assert "tree:leaf:product" not in text
 
 
 # --------------------------------------------------------------------- #

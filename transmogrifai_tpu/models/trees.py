@@ -290,15 +290,19 @@ def _histograms(B, node_idx, G, H, n_nodes: int, block: str = "wide"):
     is a regressor's one column and a boosted round's gradients (m = 1:
     the operand is read twice a level, `hist_reads` 2); a CLASSIFIER's
     K columns are one-hot times one weight and need no stacking at all:
-    `_class_histograms`. The regression shape, timed on one TPU v5e
-    (PR 32, from the traced pass's device operations): 4,500,000 rows ×
-    (6 wide + 63 two-valued columns, 318 slots), one value column. A
-    depth-6 squared-loss round is 0.144 s, of which the histograms,
-    splits and routing of its six levels are 0.065 s (10.8 ms a level)
-    and the round's two float32 leaf scatter-adds (`grow_tree`'s
-    `.at[node_idx].add`, 39 ms each) 0.079 s: at this height the leaf
-    sums, not the histograms, are over half of a boosted round. A
-    depth-12 regression tree with its bootstrap is 1.8 s.
+    `_class_histograms`. The regression shape, timed on one TPU v5e:
+    4,500,000 rows × (6 wide + 63 two-valued columns, 318 slots), one
+    value column. A depth-6 squared-loss round is 0.065 s (my chip runs,
+    PR 33: five rounds of `fit_gbt_chunk`, 0.3259 s): the histograms,
+    splits and routing of its six levels (10.8 ms a level) and the walk
+    of the finished tree; its leaf sums are 1.5 ms, a one-hot product
+    like the histograms (`_leaf_sums`). As two float32 scatter-adds of
+    4.5 M rows into 64 leaves (`.at[node_idx].add`, 39 ms each, until
+    PR 33) they were 0.079 s of a 0.144 s round (PR 32, from the traced
+    pass's device operations; 0.1428 s in PR 33's run): at this height
+    the leaf sums, not the histograms, were over half of a boosted
+    round. A depth-12 regression tree with its bootstrap is 1.8 s
+    (PR 32).
 
     Value precision is governed by HIST_PRECISION (see above)."""
     n, d, nb = B.shape
@@ -464,6 +468,110 @@ def _split_from_blocks(B, hists, n_bins: int, reg_lambda, min_child_weight,
                              min_gain_norm, level, active_depth)
 
 
+# How many 128 x 128 output tiles of the one-hot product a finished tree's
+# leaf sums may take for each scatter-add of the rows the product saves;
+# above it they are the scatter-adds (`leaf_sums_form`, where the chip
+# times behind the number are).
+_LEAF_PRODUCT_TILES_A_SCATTER = 32
+
+
+def leaf_sums_form(max_nodes: int, value_columns: int = 1,
+                   n_classes: int = 0) -> str:
+    """How `_leaf_sums` adds a tree's rows into its `max_nodes` leaves:
+    "product" or "scatter", from static shapes alone (the span
+    attribute `leaf_sums` and the scope `tree:leaf:<form>` say which).
+
+    The product's cost grows with its output tiles, leaves / 128 times
+    (3 x summed columns) / 128 rounded up, and the scatter's does not; a
+    classifier's composite sum is ONE scatter where value columns and
+    weights are two. Timed on one TPU v5e (my chip runs, PR 33,
+    `chiprun_out/leaf_bench2.jsonl`: skewed leaves, heavy-tailed values,
+    best of seven; product / scatter-adds, ms, BOTH sums of a tree):
+
+      rows x leaves, one value column + weights (regressor, boosted round)
+        4,500,000 x     64     1.54 / 80.1    (`airlines`' boosted round)
+        4,500,000 x    256     9.31 / 80.0
+        4,500,000 x  1,024    11.14 / 80.0
+        2,160,000 x  1,024     5.47 / 38.9    (`higgs`' boosted round)
+          900,000 x  1,024     2.86 / 16.7    (`criteo`'s boosted round)
+        4,500,000 x  4,096    22.22 / 61.6    (`airlines`' forest)
+        4,500,000 x  8,192    36.87 / 61.6
+        4,500,000 x 16,384    66.39 / 61.7    <- the scatter-adds
+      rows x leaves x classes (a classifier's composite form)
+        1,800,000 x 4,096 x 23   9.34 / 13.06 (`kddcup99`'s forest)
+        2,160,000 x 4,096 x  2   9.87 / 15.38 (`higgs`' forest)
+          900,000 x 4,096 x  2   5.18 /  7.02 (`criteo`'s forest)
+        1,800,000 x 8,192 x 23  15.68 / 13.06 <- the scatter-add
+        1,800,000 x 4,096 x 64  18.34 / 13.04 <- (3 x 64 columns: two tiles)
+        1,800,000 x 2,048 x 64  10.21 / 13.05
+
+    So: 32 tiles for each scatter saved, 64 where two are (8,192 leaves
+    of up to 42 summed columns), 32 where one is (4,096 leaves of up to
+    42 classes). A float32 product at `Precision.HIGHEST` is the same
+    arithmetic in twice the passes: 5.05 ms at 2,160,000 x 1,024 but
+    29.5 at 4,500,000 x 4,096 and 117 at 16,384, and its sums read 1.3 to
+    14 times further from float64's than the three pieces' (same run)."""
+    columns = n_classes or int(value_columns) + 1
+    tiles = -(-int(max_nodes) // 128) * -(-3 * columns // 128)
+    scatters = 1 if n_classes else 2
+    return ("product" if tiles <= scatters * _LEAF_PRODUCT_TILES_A_SCATTER
+            else "scatter")
+
+
+def _bf16_pieces(v: jnp.ndarray) -> List[jnp.ndarray]:
+    """A float32 array as three bfloat16 arrays whose float32 sum is the
+    array again, exactly: 8 + 8 + 8 mantissa bits, each piece the
+    rounding of what the ones before left over. The rounding is
+    `reduce_precision`: a convert to bfloat16 and back is one XLA's TPU
+    compiler may drop (excess precision allowed), which leaves one
+    bfloat16 piece and two of zeros (read so on the chip, PR 33)."""
+    pieces = []
+    for _ in range(3):
+        p = jax.lax.reduce_precision(v, exponent_bits=8, mantissa_bits=7)
+        pieces.append(p.astype(jnp.bfloat16))
+        v = v - p
+    return pieces
+
+
+def _onehot_sums(idx, V, width: int):
+    """(width, c) float32 sums of the rows of V (n, c) float32 by their
+    index: one (width, n) @ (n, 3c) product of an exact bfloat16
+    indicator and V's three exact bfloat16 pieces, float32
+    accumulation, the three partial sums added smallest first."""
+    c = V.shape[1]
+    P = jnp.concatenate(_bf16_pieces(V), axis=1)            # (n, 3c)
+    A = jax.nn.one_hot(idx, width, dtype=jnp.bfloat16)      # (n, width)
+    out = jnp.matmul(A.T, P, preferred_element_type=jnp.float32)
+    return out[:, 2 * c:] + out[:, c:2 * c] + out[:, :c]
+
+
+@jax.named_scope("tree:leaf")
+def _leaf_sums(node_idx, G, H, max_nodes: int, cls=None, n_classes: int = 0):
+    """(leaf_g (max_nodes, m), leaf_h (max_nodes,)) float32: the value
+    columns and the weights of a finished tree's rows summed into its
+    leaves, in the form `leaf_sums_form` names."""
+    classes = cls is not None       # one weight a row, (leaf, class) sums
+    m = n_classes if classes else G.shape[1]
+    form = leaf_sums_form(max_nodes, m, m if classes else 0)
+    with jax.named_scope(f"tree:leaf:{form}"):
+        if classes and form == "product":
+            leaf_g = _onehot_sums(
+                node_idx, jax.nn.one_hot(cls, m, dtype=H.dtype) * H[:, None],
+                max_nodes)
+        elif classes:
+            leaf_g = jnp.zeros((max_nodes * m,), H.dtype).at[
+                node_idx * m + cls].add(H).reshape(max_nodes, m)
+        elif form == "product":
+            sums = _onehot_sums(
+                node_idx, jnp.concatenate([G, H[:, None]], axis=1),
+                max_nodes)
+            return sums[:, :m], sums[:, m]
+        else:
+            return (jnp.zeros((max_nodes, m), G.dtype).at[node_idx].add(G),
+                    jnp.zeros((max_nodes,), H.dtype).at[node_idx].add(H))
+        return leaf_g, leaf_g.sum(1)
+
+
 # Depth at which sibling subtraction starts paying (see grow_tree doc)
 _SUBTRACT_MIN_DEPTH = 12
 
@@ -484,9 +592,15 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
 
     A CLASSIFIER passes its labels as a 1-D `G` with `n_classes`: the
     targets are then one_hot(G)·H and are never built as an (n, K)
-    array (`_class_histograms`; the leaf sums scatter one weight a row
-    into the composite (leaf, class) index). A label outside [0, K)
-    counts nowhere.
+    array (`_class_histograms`). A label outside [0, K) counts nowhere.
+
+    The finished tree's leaf sums are float32 sums of float32 values in
+    the form `leaf_sums_form` names from the leaf count and the summed
+    columns (`_leaf_sums`, scope `tree:leaf:<form>`): a one-hot product
+    whose values enter exactly as three bfloat16 pieces, a classifier's
+    as (leaves, n) @ (n, 3·K) of one weight a row; past the crossover
+    read on the chip, scatter-adds (a classifier's one weight a row into
+    the composite (leaf, class) index).
 
     `active_depth`: optional TRACED effective depth ≤ max_depth. Levels at or
     beyond it never split (every sample routes left, partition unchanged), so
@@ -573,13 +687,7 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
                     None if cls is not None else G * right[:, None],
                     H * right, n_nodes))]
 
-    if cls is not None:
-        leaf_g = jnp.zeros((max_nodes * m,), H.dtype).at[
-            node_idx * m + cls].add(H).reshape(max_nodes, m)
-        leaf_h = leaf_g.sum(1)
-    else:
-        leaf_g = jnp.zeros((max_nodes, m), G.dtype).at[node_idx].add(G)
-        leaf_h = jnp.zeros((max_nodes,), H.dtype).at[node_idx].add(H)
+    leaf_g, leaf_h = _leaf_sums(node_idx, G, H, max_nodes, cls, n_classes)
     # L1 (alpha) soft-thresholds the leaf numerator (XGBoost leaf formula)
     leaf_g = jnp.sign(leaf_g) * jnp.maximum(jnp.abs(leaf_g) - alpha, 0.0)
     leaf = leaf_g / (leaf_h + reg_lambda)[:, None]
@@ -1477,7 +1585,9 @@ class _TreeEstimatorBase(PredictorEstimator):
         with TRACER.span("tree:edges", category="tree",
                          max_bins=self.max_bins, edges=edges_site(X),
                          value_columns=n_classes or 1,
-                         hist_reads=hist_reads(n_classes)):
+                         hist_reads=hist_reads(n_classes),
+                         leaf_sums=leaf_sums_form(
+                             2 ** int(self.max_depth), 1, n_classes)):
             indicator = indicator_columns(X)
             edges = quantile_bin_edges(X, self.max_bins, indicator)
             out = (edges, bin_features(jnp.asarray(X), jnp.asarray(edges)),

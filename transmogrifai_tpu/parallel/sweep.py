@@ -72,6 +72,7 @@ from transmogrifai_tpu.models.trees import (
     forest_classification_pred, forest_regression_pred,
     gbt_base_score, gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
     gbt_train_summary, edges_site, hist_layout, hist_reads, hist_slots,
+    leaf_sums_form,
     indicator_columns, quantile_bin_edges)
 from transmogrifai_tpu.runtime.faults import (
     SITE_RUN_BLOCK, fault_point, is_oom_error)
@@ -403,17 +404,17 @@ def _shard_dyn(dyn: Dict[str, jnp.ndarray],
 
 
 def _run_block(prog: Callable, data, W, V, dyn: Dict[str, jnp.ndarray],
-               sharding, family: str = "generic"):
+               sharding, family: str = "generic", **attributes):
     """Execute one grid block: `prog(data, W, V, dyn)` is a
     `_block_program` over the grid axis of `dyn`; the dataset pytree and
     the fold masks are its non-mapped ARGUMENTS (under a mesh, the
     `NamedSharding`-placed arrays `_run_sweep` built — jit keeps their
     sharding). Returns the raw jax output (a (g, k) metric array, or a
     prediction pytree with leading (g, k) axes on the host-metric
-    fallback path).
+    fallback path). `attributes` go on the dispatch's span.
     """
     dyn, g = _shard_dyn(dyn, sharding)
-    with _dispatch_span(family):
+    with _dispatch_span(family, **attributes):
         out = jax.block_until_ready(prog(data, W, V, dyn))
     return jax.tree_util.tree_map(lambda a: a[:g], out)  # drop pad rows
 
@@ -479,6 +480,8 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
                   pair_width: Callable[[Tuple, List[int], int], int]
                   = lambda s, i, k: 1,
                   x_info: Optional[Tuple[int, int]] = None,
+                  dispatch_attrs: Callable[[Tuple, List[int]], Dict]
+                  = lambda s, i: {},
                   ) -> List[List[float]]:
     """Shared scaffold: group grids by static params; per group, stack the
     dynamic params into traced vectors and run fit→predict→metric as one
@@ -493,6 +496,8 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
     device kernel) keeps the batched fit+predict program but evaluates the
     wrapped evaluator over the materialized (g, k, n, …) prediction pytree
     on host — fits stay one XLA program per group either way.
+    `dispatch_attrs(static, idxs)`: what a group's dispatches say of
+    themselves on their `sweep:dispatch:<family>` spans.
 
     `host_dispatch` (tree families, single device only): compile ONE
     fit→predict→metric program per static group and dispatch it per
@@ -554,7 +559,8 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
                 gs = [p // n_folds for p in ps]
                 fs = [p % n_folds for p in ps]
                 dchunk = {k: v[jnp.asarray(gs)] for k, v in dyn.items()}
-                with _dispatch_span(family, timed=True):
+                with _dispatch_span(family, timed=True,
+                                    **dispatch_attrs(static, idxs)):
                     out = jax.block_until_ready(
                         prog(data, dchunk, W[jnp.asarray(fs)],
                              V[jnp.asarray(fs)]))
@@ -589,7 +595,8 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
         gk = _run_block(
             _block_program(family, static, shape, metric_key,
                            "vmap" if batched else "map"),
-            data, W, V, dyn, sharding, family=family)
+            data, W, V, dyn, sharding, family=family,
+            **dispatch_attrs(static, idxs))
         if host:
             pred_np = jax.tree_util.tree_map(np.asarray, gk)
             for row_i, grid_i in enumerate(idxs):
@@ -1088,7 +1095,10 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
         grid_vmap=lambda st, idxs: _pad_depth_of(est, grids, idxs) <= 6,
         host_dispatch=True,
         pair_width=lambda st, idxs, k: width_of(st, idxs),
-        x_info=_x_info(X))
+        x_info=_x_info(X),
+        dispatch_attrs=lambda st, idxs: {"leaf_sums": leaf_sums_form(
+            2 ** _pad_depth_of(est, grids, idxs), 1,
+            0 if regression else n_out)})
 
 
 @functools.lru_cache(maxsize=_HELD_PROGRAMS)
@@ -1205,7 +1215,9 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             grid_vmap=lambda st, idxs: _pad_depth_of(est, grids, idxs) <= 6,
             host_dispatch=sharding is None,
             pair_width=lambda st, idxs, k: width_of(st, idxs),
-            x_info=_x_info(X))
+            x_info=_x_info(X),
+            dispatch_attrs=lambda st, idxs: {"leaf_sums": leaf_sums_form(
+                2 ** _pad_depth_of(est, grids, idxs))})
 
     # ---- single-device binary/squared: ROUND-CHUNKED host dispatch ---- #
     # Each dispatch runs `rpd` boosting rounds for `width` vmapped
@@ -1274,7 +1286,9 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                                     rounds=int(ks.shape[0]),
                                     pairs=min(width, n_pairs - s),
                                     pad_depth=pad_depth,
-                                    objective=objective):
+                                    objective=objective,
+                                    leaf_sums=leaf_sums_form(
+                                        2 ** pad_depth)):
                     margin, best, since = jax.block_until_ready(
                         prog(data, dchunk, Wsel, Vsel, margin, best, since,
                              ks))
