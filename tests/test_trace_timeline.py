@@ -32,7 +32,9 @@ from transmogrifai_tpu.models import trees
 from transmogrifai_tpu.models.linear import fit_linreg_enet
 from transmogrifai_tpu.models.logistic import fit_logreg_enet
 from transmogrifai_tpu.obs import goodput as obsg
-from transmogrifai_tpu.obs.trace import TRACER, RequestTrace, Tracer, now_s
+from transmogrifai_tpu.obs.trace import (
+    TRACER, RequestTrace, Tracer, now_s, pull, train_passes, upload,
+    uploading)
 from transmogrifai_tpu.parallel.sweep import SWEEP_STATS, run_sweep
 from transmogrifai_tpu.selector import (
     BinaryClassificationModelSelector, DataSplitter)
@@ -78,6 +80,114 @@ def test_span_sits_in_the_profilers_host_plane(tmp_path):
             if ev.name == "timeline:probe"]
     assert len(host) == 1
     assert host[0].duration_ns >= 2e6
+
+
+def _new_spans(mark):
+    return [s for s in TRACER.spans() if s.span_id > mark]
+
+
+def _mark():
+    return max((s.span_id for s in TRACER.spans()), default=0)
+
+
+def test_pull_sits_in_the_profilers_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+    x = jnp.arange(4096, dtype=jnp.float32) * 2.0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        got = pull("timeline:probe", x)
+    finally:
+        jax.profiler.stop_trace()
+    np.testing.assert_array_equal(got, np.arange(4096) * 2.0)
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    host = [ev for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events
+            if ev.name == "pull:timeline:probe"]
+    assert len(host) == 1
+
+
+_PULL_RNG = np.random.default_rng(11)
+PULL_CASES = {
+    # value -> (device leaves' bytes, or None for no span)
+    "numpy": (lambda: _PULL_RNG.normal(size=(5, 3)), None),
+    "jax": (lambda: jnp.asarray(_PULL_RNG.normal(size=(7, 3)),
+                                jnp.float32), 7 * 3 * 4),
+    "scalar": (lambda: jnp.float32(2.5), 4),
+    "pytree": (lambda: {"a": jnp.arange(6, dtype=jnp.int32),
+                        "b": (np.arange(4.0), jnp.ones((2, 2), jnp.bfloat16)),
+                        "c": None}, 6 * 4 + 4 * 2),
+    "host-pytree": (lambda: {"a": np.arange(3), "b": [np.ones(2)]}, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PULL_CASES))
+def test_pull_is_np_asarray_under_a_span_with_the_device_bytes(case):
+    make, nbytes = PULL_CASES[case]
+    x = make()
+    want = jax.tree_util.tree_map(np.asarray, x)
+    mark = _mark()
+    with TRACER.span("timeline:owner") as owner:
+        got = pull("timeline:site", x)
+    spans = [s for s in _new_spans(mark) if s is not owner]
+    if nbytes is None:
+        assert spans == []      # already on the host: as it is, no span
+        assert got is x
+        return
+    sp, = spans
+    assert sp.name == "pull:timeline:site" and sp.category == "transfer"
+    assert sp.parent_id == owner.span_id
+    assert sp.attributes["bytes"] == nbytes
+    assert sp.attributes["wait_s"] >= 0 and sp.attributes["copy_s"] >= 0
+    assert sp.attributes["wait_s"] + sp.attributes["copy_s"] <= sp.duration_s
+    assert jax.tree_util.tree_structure(got) \
+        == jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert isinstance(g, np.ndarray) and g.dtype == w.dtype
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("dtype", [None, jnp.float32, jnp.int32,
+                                   jnp.bfloat16, bool],
+                         ids=["none", "float32", "int32", "bfloat16", "bool"])
+@pytest.mark.parametrize("host", [True, False], ids=["host", "device"])
+def test_upload_is_jnp_asarray_under_a_span_with_the_host_bytes(dtype, host):
+    x = np.arange(12, dtype=np.float64).reshape(4, 3) % 3
+    if not host:
+        x = jnp.asarray(x)
+    want = jnp.asarray(x, dtype)
+    mark = _mark()
+    with TRACER.span("timeline:owner") as owner:
+        got = upload("timeline:site", x, dtype)
+    assert isinstance(got, jax.Array) and got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    spans = [s for s in _new_spans(mark) if s is not owner]
+    if not host:
+        assert spans == []          # no crossing, no span
+        return
+    sp, = spans
+    assert sp.name == "upload:timeline:site" and sp.category == "transfer"
+    assert sp.parent_id == owner.span_id
+    assert sp.attributes == {"bytes": 4 * 3 * 8}
+
+
+def test_uploading_counts_the_numpy_operands_of_a_jnp_call():
+    cols = [[np.ones(8, np.float32), jnp.zeros(8)], [np.ones(8, np.float32)]]
+    mark = _mark()
+    with uploading("timeline:stack", cols) as sp:
+        out = jnp.stack([c for g in cols for c in g], axis=1)
+    assert out.shape == (8, 3)
+    assert sp.name == "upload:timeline:stack"
+    assert sp.attributes == {"bytes": 2 * 8 * 4}
+    def uploads():
+        return [s.name for s in _new_spans(mark)
+                if s.category == "transfer"]
+    assert uploads() == ["upload:timeline:stack"]
+    with uploading("timeline:stack", [jnp.zeros(8)]) as none:
+        pass
+    assert none is None and len(uploads()) == 1
 
 
 def test_span_at_backdates_a_finished_span_under_the_current_one():
@@ -160,12 +270,28 @@ def test_compile_outside_any_span_is_named_for_no_owner():
 
 
 def test_persistent_cache_hit_is_an_event_on_the_current_span():
+    # JAX times a backend compile around its look into the persistent
+    # cache: the `compile:*` span of a request the cache answered says
+    # so (`cache_hit`), and no event lands on the owner
     hits = compile_cache.COMPILE_STATS["cache_hits"]
+    use, hit, took = ("/jax/compilation_cache/compile_requests_use_cache",
+                      "/jax/compilation_cache/cache_hits",
+                      "/jax/core/compile/backend_compile_duration")
+    mark = _mark()
     with TRACER.span("timeline:hit") as sp:
-        compile_cache._on_event("/jax/compilation_cache/cache_hits")
+        compile_cache._on_event(use)
+        compile_cache._on_event(hit)
         compile_cache._on_event("/jax/compilation_cache/cache_misses")
-    assert [e[0] for e in sp.events] == ["compile_cache_hit"]
+        compile_cache._on_duration(took, 0.01, fun_name="jit(loaded)")
+        compile_cache._on_event(use)
+        compile_cache._on_duration(took, 0.02, fun_name="jit(built)")
+    assert sp.events == []
     assert compile_cache.COMPILE_STATS["cache_hits"] == hits + 1
+    compiles = {s.name: s.attributes for s in _new_spans(mark)
+                if s.category == "compile"}
+    assert compiles == {
+        "compile:timeline:hit/jit(loaded)": {"cache_hit": True},
+        "compile:timeline:hit/jit(built)": {"cache_hit": False}}
 
 
 def test_goodput_counts_compile_spans_as_recompile_seconds():
@@ -173,11 +299,16 @@ def test_goodput_counts_compile_spans_as_recompile_seconds():
     with tr.span("run", new_trace=True) as root:
         root.event("recompile", trace_s=0.003)
         t1 = now_s()
-        tr.span_at("compile:run/jit(f)", t1 - 0.01, t1, category="compile")
+        tr.span_at("compile:run/jit(f)", t1 - 0.01, t1, category="compile",
+                   cache_hit=False)
+        tr.span_at("compile:run/jit(g)", t1 - 0.002, t1, category="compile",
+                   cache_hit=True)
         time.sleep(0.03)
     report = obsg.build_report(root, tr.trace_spans(root.trace_id))
-    assert report.buckets["recompile_s"] == pytest.approx(0.013)
+    # the bucket holds compiles and cache loads alike; the loads counted
+    assert report.buckets["recompile_s"] == pytest.approx(0.015)
     assert report.counts["recompiles"] == 1
+    assert report.counts["compile_cache_loads"] == 1
     assert sum(report.buckets.values()) == pytest.approx(
         report.wall_s, rel=1e-6)
 
@@ -484,6 +615,121 @@ def test_an_estimators_own_binning_states_its_leaf_sums(depth, classes,
     edges, = [s for s in TRACER.trace_spans(root.trace_id)
               if s.name == "tree:edges"]
     assert edges.attributes["leaf_sums"] == form
+
+
+# --------------------------------------------------------------------- #
+# D3. the host-device boundary: every crossing a span under its phase   #
+# --------------------------------------------------------------------- #
+
+CROSSINGS = [
+    # (fixture, span, the phase it nests under)
+    ("train_spans", "upload:stage:RealVectorizer", "stage:fit:RealVectorizer"),
+    ("train_spans", "pull:impute:fills", "stage:fit:RealVectorizer"),
+    ("train_spans", "upload:stage:RealVectorizerModel",
+     "stage:transform:RealVectorizer"),
+    ("train_spans", "upload:selector:rows", "selector:prepare"),
+    ("train_spans", "upload:selector:label", "selector:prepare"),
+    ("train_spans", "upload:sweep:folds", "selector:sweep"),
+    ("train_spans", "pull:tree:indicator", "selector:sweep"),
+    ("train_spans", "pull:tree:edges", "sweep:bin"),
+    ("train_spans", "pull:sweep:logistic", "sweep:fetch:logistic"),
+    ("train_spans", "pull:sweep:forest", "sweep:fetch:forest"),
+    ("train_spans", "pull:sweep:gbt", "sweep:fetch:gbt"),
+    ("train_spans", "upload:evaluate:rows", "selector:evaluate"),
+    ("train_spans", "pull:evaluate:probability", "selector:evaluate"),
+    ("train_spans", "pull:evaluate:prediction", "selector:evaluate"),
+    ("typed_spans", "upload:stage:IntegralVectorizerModel",
+     "stage:transform:IntegralVectorizer"),
+    ("typed_spans", "upload:pivot", "stage:transform:OneHotVectorizer"),
+    ("typed_spans", "pull:sanity:matrix", "sanity:moments"),
+    ("typed_spans", "upload:sanity:sample", "sanity:moments"),
+    ("typed_spans", "pull:sanity:moments", "sanity:moments"),
+    ("typed_spans", "pull:sanity:corr", "sanity:corr"),
+    ("typed_spans", "pull:sweep:gbt", "sweep:fetch:gbt"),
+    ("typed_spans", "pull:fit:trees", "selector:refit"),
+    ("typed_spans", "pull:tree:edges", "tree:edges"),
+    ("multi_spans", "upload:stage:BinaryVectorizerModel",
+     "stage:transform:BinaryVectorizer"),
+    ("multi_spans", "pull:sanity:matrix", "sanity:moments"),
+    ("multi_spans", "pull:sweep:logistic", "sweep:fetch:logistic"),
+    ("multi_spans", "pull:sweep:forest", "sweep:fetch:forest"),
+    ("multi_spans", "upload:evaluate:label", "selector:evaluate"),
+    ("multi_spans", "pull:evaluate:confusion", "selector:evaluate"),
+]
+
+
+@pytest.mark.parametrize("fixture,name,phase", CROSSINGS, ids=[
+    f"{f.split('_')[0]}-{n}" for f, n, _ in CROSSINGS])
+def test_a_crossing_is_a_span_under_the_phase_that_makes_it(
+        request, fixture, name, phase):
+    spans = request.getfixturevalue(fixture)[1]
+    by_id = {s.span_id: s for s in spans}
+    mine = [s for s in spans if s.name == name]
+    chains = []
+    for sp in mine:
+        assert sp.category == "transfer" and sp.attributes["bytes"] > 0
+        if name.startswith("pull:"):
+            assert sp.attributes["wait_s"] + sp.attributes["copy_s"] \
+                <= sp.duration_s
+        chains.append([])
+        while sp.parent_id is not None:
+            sp = by_id[sp.parent_id]
+            chains[-1].append(sp.name)
+        assert "workflow:train" in chains[-1]
+    # a site several phases share (a tree's edges: the sweep's binning
+    # and the refit's own) is under this one at least once
+    assert any(phase in chain for chain in chains)
+    if phase.startswith("sweep:fetch:"):    # the fetch's own child
+        assert {by_id[s.parent_id].name for s in mine} == {phase}
+
+
+@pytest.mark.parametrize("fixture", ["train_spans", "typed_spans",
+                                     "multi_spans"])
+def test_a_model_stages_prediction_is_pulled_under_its_transform(
+        request, fixture):
+    spans = request.getfixturevalue(fixture)[1]
+    by_id = {s.span_id: s for s in spans}
+    stage, = [s for s in spans if s.name.startswith("stage:transform:")
+              and "ModelSelector" in s.name]
+    pulls = [s for s in spans if s.parent_id == stage.span_id
+             and s.name.startswith("pull:stage:")]
+    assert len(pulls) == 1 and pulls[0].attributes["bytes"] > 0
+    # no transfer span takes a name an older reader of the benchmark sums
+    assert not [s for s in spans if s.category == "transfer"
+                and not s.name.startswith(("pull:", "upload:"))]
+    assert by_id[stage.parent_id].name == "workflow:train"
+
+
+def test_train_passes_groups_the_ring_by_the_pass_a_span_descends_from():
+    tr = Tracer()
+    with tr.span("stray"):                  # under no pass: left out
+        pass
+    roots = []
+    for k in range(2):
+        with tr.span("run", new_trace=True):
+            with tr.span("workflow:train") as root:
+                roots.append(root)
+                with tr.span("selector:sweep") as sweep:
+                    def work(parent=sweep, k=k):
+                        with tr.span("sweep:family:X", parent=parent):
+                            with tr.span("pull:sweep:x", bytes=8 * (k + 1)):
+                                pass
+                    worker = threading.Thread(target=work)
+                    worker.start()
+                    worker.join(timeout=60)
+                t1 = now_s()
+                tr.span_at("compile:workflow:train/jit(f)", t1 - 0.001, t1,
+                           category="compile")
+    with tr.span("workflow:train"):         # still open: not a pass yet
+        passes = train_passes(tr)
+    assert [p["root"] for p in passes] == roots
+    for k, p in enumerate(passes):
+        assert [s.name for s in p["spans"]] == [
+            "selector:sweep", "sweep:family:X", "pull:sweep:x",
+            "compile:workflow:train/jit(f)"]
+        assert p["spans"][2].attributes == {"bytes": 8 * (k + 1)}
+        assert p["spans"][2].thread_name != p["root"].thread_name
+    assert train_passes(Tracer()) == []
 
 
 # --------------------------------------------------------------------- #
@@ -967,3 +1213,119 @@ def test_unspanned_reader_takes_the_traced_window_for_the_traced_pass():
     assert read(obs) == pytest.approx((0.5 + 1.0) / 2)
     assert read({"window": {"passes": [PASS_A]}, "trace": None}) \
         == pytest.approx(1.0)
+
+
+# the boundary's readers take the window's passes from the program's own
+# ring (`obs.trace.train_passes()`): hand-made passes go in at its end
+TRANSFER_PASSES = [
+    (12.0, [("pull:sanity:matrix", 3.0, {"bytes": 2000, "wait_s": 0.5,
+                                          "copy_s": 2.5}),
+            ("pull:sweep:gbt", 1.0, {"bytes": 48, "wait_s": 0.9,
+                                      "copy_s": 0.1}),
+            ("upload:pivot", 0.5, {"bytes": 400}),
+            ("sanity:moments", 4.0, {})]),
+    (8.0, [("pull:sanity:matrix", 1.0, {"bytes": 2000, "wait_s": 0.25,
+                                         "copy_s": 0.75}),
+           ("upload:pivot", 0.25, {"bytes": 400})]),
+    (9.0, [("pull:evaluate:probability", 2.0, {"bytes": 100, "wait_s": 0.5,
+                                                "copy_s": 1.5}),
+           ("upload:selector:rows", 0.125, {"bytes": 64})]),
+]
+TRANSFER_READINGS = {
+    # (the last pass alone, the mean of the three)
+    "train_pull_s": (2.0, 7.0 / 3),
+    "train_pull_wait_s": (0.5, 2.15 / 3),
+    "train_pull_bytes": (100, 4148 / 3),
+    "train_upload_s": (0.125, 0.875 / 3),
+    "train_upload_bytes": (64, 864 / 3),
+    # 12 less the median of 8 and 9; one pass has no later ones
+    "train_first_pass_extra_s": (None, 3.5),
+}
+
+
+def _hand_made_passes(passes):
+    for wall, spans in passes:
+        t1 = now_s()
+        root = TRACER.span_at("workflow:train", t1 - wall, t1)
+        for name, seconds, attrs in spans:
+            TRACER.span_at(name, t1 - wall, t1 - wall + seconds,
+                           parent=root, category="transfer", **attrs)
+
+
+@pytest.mark.parametrize("name", sorted(TRANSFER_READINGS))
+def test_transfer_layer_metric_reader_on_hand_made_passes(name, monkeypatch):
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "benchmark"))
+    read = _reader(name)
+    one, three = TRANSFER_READINGS[name]
+    _hand_made_passes(TRANSFER_PASSES)
+    window = {"passes": [PASS_A]}
+    assert read({"window": window}) == (
+        None if one is None else pytest.approx(one))
+    assert read({"window": {"passes": [PASS_A, PASS_B, PASS_A]}}) \
+        == pytest.approx(three)
+    assert read({"window": {"passes": []}}) is None
+    assert read({"window": {}}) is None
+    # passes of a program without these spans give nothing (the wall of
+    # a pass is there whatever the program spans)
+    _hand_made_passes([(5.0, [("sanity:moments", 1.0, {})]),
+                       (4.0, [("sanity:moments", 1.0, {})])])
+    got = read({"window": {"passes": [PASS_A, PASS_B]}})
+    assert got == (pytest.approx(1.0) if name == "train_first_pass_extra_s"
+                   else None)
+    # more passes asked for than the ring holds: nothing, and no raise
+    assert read({"window": {"passes": [PASS_A] * 10_000}}) is None
+
+
+NEW_READERS = set(TRANSFER_READINGS)
+TRANSFER_SPANS = [
+    ("pull:sanity:matrix", 3.0), ("upload:sanity:sample", 0.5),
+    ("pull:sanity:moments", 0.25), ("pull:sanity:corr", 0.25),
+    ("upload:pivot", 0.5), ("upload:stage:RealVectorizerModel", 0.5),
+    ("pull:impute:fills", 0.75), ("upload:selector:rows", 0.125),
+    ("pull:sweep:gbt", 1.0), ("pull:sweep:logistic", 0.5),
+    ("upload:sweep:folds", 0.125), ("pull:fit:trees", 0.25),
+    ("pull:evaluate:probability", 0.25), ("pull:stage:XGBModel", 0.5)]
+
+
+def _older_readers():
+    import json
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        return [m["name"] for m in json.load(fh)["per_layer"]
+                if m["name"] not in NEW_READERS]
+
+
+@pytest.mark.parametrize("name", _older_readers())
+def test_an_older_reader_reads_the_same_with_the_transfer_spans(
+        name, monkeypatch):
+    import json
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "benchmark"))
+    read = _reader(name)
+    cfg = ("criteo" if "typed" in name else "kddcup99" if "multi" in name
+           else "airlines" if "reg" in name else "higgs")
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           cfg + ".json")) as fh:
+        config = json.load(fh)
+    with open(os.path.join(ROOT, "benchmark", "peaks.json")) as fh:
+        peaks = json.load(fh)["TPU v5 lite"]
+
+    def obs(passes):
+        passes = [dict(p, sweep_dispatches=9, sweep_dispatch_s=6.0)
+                  for p in passes]
+        return {"window": {"passes": passes, "rows": 1_000_000,
+                           "compiles": {"requests": 3, "cache_hits": 1}},
+                "config": config, "peaks": peaks,
+                "trace": {"n_ops": 5, "busy_s": 12.0, "window_s": 19.5,
+                          "idle_share": 0.4}}
+
+    def with_transfers(p):
+        return dict(p, spans=p["spans"] + TRANSFER_SPANS)
+
+    read_any = False
+    boosted = dict(PASS_A, counters={"boost_rounds": 60, "hist_reads": 2,
+                                     "hist_slots": 318})
+    for passes in ([PASS_A], [PASS_A, PASS_B], [PASS_T], [PASS_T, PASS_U],
+                   [PASS_M, PASS_N], [boosted]):
+        plain = read(obs(passes))
+        assert read(obs([with_transfers(p) for p in passes])) == plain
+        read_any = read_any or plain is not None
+    assert read_any

@@ -1367,13 +1367,16 @@ _L017_FUNCS = ("record_event", "emit_event", "add_event")
 _L017_METHODS = ("span", "span_at", "event", "child", "child_at")
 # bounded-by-construction dynamic name families: the interpolated part
 # is a worker index, run type, retry/ingest site label, profile phase,
-# model family, or (compile:) another span's name plus the jitted
-# function's — closed sets fixed at build time, not wire-derived values.
+# model family, (compile:) another span's name plus the jitted
+# function's, or (pull:, upload:) a transfer site: fixed text at the
+# call plus a family, stage class or prediction field — closed sets
+# fixed at build time, not wire-derived values.
 # Everything NEW must either use a literal name (variability goes in
 # attributes) or extend this list with a justified prefix.
 _L017_ALLOW_PREFIXES = (
     "retry:", "sweep:worker:", "sweep:family:", "sweep:dispatch:",
     "sweep:fetch:", "compile:", "ingest:", "run:", "phase:", "stage:",
+    "pull:", "upload:",
 )
 
 

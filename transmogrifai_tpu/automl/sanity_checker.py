@@ -33,7 +33,7 @@ log = logging.getLogger(__name__)
 from transmogrifai_tpu import types as T
 from transmogrifai_tpu.data.columns import Column
 from transmogrifai_tpu.data.metadata import VectorMetadata
-from transmogrifai_tpu.obs.trace import TRACER
+from transmogrifai_tpu.obs.trace import TRACER, pull, upload
 from transmogrifai_tpu.stages.base import Estimator, FitContext, Transformer
 
 # reference defaults (SanityChecker.scala:561-578)
@@ -141,7 +141,7 @@ def _corr_matrix(Z: jnp.ndarray) -> np.ndarray:
     n = Z.shape[0]
     mean = Z.mean(0)
     Zc = Z - mean
-    cov = np.asarray(Zc.T @ Zc) / max(n - 1, 1)
+    cov = pull("sanity:corr", Zc.T @ Zc) / max(n - 1, 1)
     sd = np.sqrt(np.maximum(np.diag(cov), 0.0))
     denom = np.outer(sd, sd)
     with np.errstate(divide="ignore", invalid="ignore"):
@@ -171,7 +171,7 @@ def _corr_label_and_hits_blocked(Cx: jnp.ndarray, cy: jnp.ndarray,
     yc = cy - cy.mean()
     ysd = jnp.sqrt(jnp.maximum((yc * yc).sum(), 0.0))
     uy = jnp.where(ysd > 0, yc / ysd, 0.0)
-    corr_y = np.asarray(U.T @ uy, dtype=np.float64)
+    corr_y = np.asarray(pull("sanity:corr", U.T @ uy), dtype=np.float64)
 
     if block is None:  # ≤ ~128M-entry (512MB f32) block products
         block = max(128, min(d, (1 << 27) // max(d, 1)))
@@ -192,7 +192,7 @@ def _corr_label_and_hits_blocked(Cx: jnp.ndarray, cy: jnp.ndarray,
     for a in range(0, d, block):
         ri, ci, vals, total = block_hits(
             jax.lax.dynamic_slice_in_dim(Upad, a, block, 1), a)
-        ri, ci, vals = np.asarray(ri), np.asarray(ci), np.asarray(vals)
+        ri, ci, vals, total = pull("sanity:corr", (ri, ci, vals, total))
         k = int((ri >= 0).sum())
         if int(total) > cap:
             log.warning(
@@ -388,7 +388,7 @@ class SanityChecker(Estimator):
         label_col, vec_col = cols
         with TRACER.span("sanity:moments", category="sanity"):
             y_np = np.asarray(label_col.data["value"], dtype=np.float64)
-            X_np = np.asarray(vec_col.device_value())
+            X_np = pull("sanity:matrix", vec_col.device_value())
             n_total = X_np.shape[0]
 
             sample_idx = self._sample_rows(n_total)
@@ -403,24 +403,22 @@ class SanityChecker(Estimator):
             # stats either way
             spearman = self.correlation_type == "spearman"
             # unsampled, the vector is on the device already: no second copy
-            X_dev = jnp.asarray(vec_col.device_value()
-                                if sample_idx is None else X_np)
+            X_dev = upload("sanity:sample", vec_col.device_value()
+                           if sample_idx is None else X_np)
             if spearman:
-                Cx = jnp.asarray(_rank_transform(X_np))
-                cy = jnp.asarray(_rank_transform(y_np[:, None])[:, 0])
+                Cx = upload("sanity:sample", _rank_transform(X_np))
+                cy = upload("sanity:sample",
+                            _rank_transform(y_np[:, None])[:, 0])
             else:
                 Cx = X_dev
-                cy = jnp.asarray(y_np.astype(np.float32))
+                cy = upload("sanity:sample", y_np.astype(np.float32))
 
             need_ff = self.max_feature_corr < 1.0
             if need_ff:  # corr comes from the Gram pass; raw moments here
-                red = {k: np.asarray(v)
-                       for k, v in _column_reductions(X_dev).items()}
+                red = pull("sanity:moments", _column_reductions(X_dev))
             else:        # label terms ride the same single reduction pass
-                redc = {k: np.asarray(v)
-                        for k, v in _column_reductions(Cx, cy).items()}
-                red = ({k: np.asarray(v)
-                        for k, v in _column_reductions(X_dev).items()}
+                redc = pull("sanity:moments", _column_reductions(Cx, cy))
+                red = (pull("sanity:moments", _column_reductions(X_dev))
                        if spearman else redc)
             mean = red["sx"] / max(n, 1)
             var = (red["sxx"] - n * mean ** 2) / max(n - 1, 1)

@@ -16,6 +16,7 @@ import numpy as np
 from transmogrifai_tpu.data.columns import Column
 from transmogrifai_tpu.evaluators.metrics import (
     binary_metrics, multiclass_metrics, regression_metrics)
+from transmogrifai_tpu.obs.trace import pull, upload
 
 
 class Evaluator:
@@ -32,7 +33,16 @@ class Evaluator:
 
 
 def _label_array(label: Column) -> np.ndarray:
-    return np.asarray(label.data["value"], dtype=np.float64)
+    return np.asarray(pull("evaluate:label", label.data["value"]),
+                      dtype=np.float64)
+
+
+def _prediction_field(prediction: Column, field: str,
+                      dtype=None) -> np.ndarray:
+    """One field of a prediction column on the host: a span
+    `pull:evaluate:<field>` where it was still a device array."""
+    return np.asarray(pull(f"evaluate:{field}", prediction.data[field]),
+                      dtype=dtype)
 
 
 class BinaryClassificationEvaluator(Evaluator):
@@ -48,11 +58,12 @@ class BinaryClassificationEvaluator(Evaluator):
 
     def evaluate(self, label: Column, prediction: Column):
         y = _label_array(label)
-        prob = np.asarray(prediction.data["probability"])
+        prob = _prediction_field(prediction, "probability")
         if prob.ndim == 2 and prob.shape[1] >= 2:
             scores = prob[:, 1]
         else:
-            scores = np.asarray(prediction.data["prediction"], dtype=np.float64)
+            scores = _prediction_field(prediction, "prediction",
+                                       np.float64)
         return binary_metrics(y, scores, self.threshold)
 
 
@@ -68,7 +79,7 @@ class MultiClassificationEvaluator(Evaluator):
 
     def evaluate(self, label: Column, prediction: Column):
         y = _label_array(label)
-        pred = np.asarray(prediction.data["prediction"], dtype=np.float64)
+        pred = _prediction_field(prediction, "prediction", np.float64)
         return multiclass_metrics(y, pred)
 
     def evaluate_device(self, y, pred: dict, n_classes: int):
@@ -83,8 +94,9 @@ class MultiClassificationEvaluator(Evaluator):
         from transmogrifai_tpu.evaluators.metrics import (
             multiclass_from_confusion)
         p = pred["prediction"]
-        return multiclass_from_confusion(np.asarray(confusion_dev(
-            y, p, jnp.ones(p.shape[0], jnp.float32), int(n_classes))))
+        return multiclass_from_confusion(pull(
+            "evaluate:confusion", confusion_dev(
+                y, p, jnp.ones(p.shape[0], jnp.float32), int(n_classes))))
 
 
 class RegressionEvaluator(Evaluator):
@@ -100,7 +112,7 @@ class RegressionEvaluator(Evaluator):
 
     def evaluate(self, label: Column, prediction: Column):
         y = _label_array(label)
-        pred = np.asarray(prediction.data["prediction"], dtype=np.float64)
+        pred = _prediction_field(prediction, "prediction", np.float64)
         return regression_metrics(y, pred)
 
     def evaluate_device(self, y, pred: dict, n_classes=None):
@@ -116,8 +128,9 @@ class RegressionEvaluator(Evaluator):
         from transmogrifai_tpu.evaluators.device_metrics import (
             regression_metrics_dev)
         from transmogrifai_tpu.evaluators.metrics import RegressionMetrics
-        m = regression_metrics_dev(self.default_metric)(
-            jnp.asarray(y, jnp.float32), pred["prediction"])
+        m = pull("evaluate:metrics", regression_metrics_dev(
+            self.default_metric)(upload("evaluate:label", y, jnp.float32),
+                                 pred["prediction"]))
         return RegressionMetrics(
             rmse=float(m["RMSE"]), mse=float(m["MSE"]), mae=float(m["MAE"]),
             r2=float(m["R2"]))
@@ -136,9 +149,10 @@ class BinScoreEvaluator(Evaluator):
     def evaluate(self, label: Column, prediction: Column):
         from transmogrifai_tpu.evaluators.metrics import bin_score_metrics
         y = _label_array(label)
-        prob = np.asarray(prediction.data["probability"])
+        prob = _prediction_field(prediction, "probability")
         scores = (prob[:, 1] if prob.ndim == 2 and prob.shape[1] >= 2
-                  else np.asarray(prediction.data["prediction"], dtype=np.float64))
+                  else _prediction_field(prediction, "prediction",
+                                         np.float64))
         return bin_score_metrics(y, scores, self.num_bins)
 
 
@@ -155,7 +169,7 @@ class ForecastEvaluator(Evaluator):
     def evaluate(self, label: Column, prediction: Column):
         from transmogrifai_tpu.evaluators.metrics import forecast_metrics
         y = _label_array(label)
-        pred = np.asarray(prediction.data["prediction"], dtype=np.float64)
+        pred = _prediction_field(prediction, "prediction", np.float64)
         return forecast_metrics(y, pred, self.seasonal_window)
 
 

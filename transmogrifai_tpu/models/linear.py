@@ -19,6 +19,7 @@ import numpy as np
 
 from transmogrifai_tpu.models.base import (
     PredictionModel, PredictorEstimator, resolve_init_params)
+from transmogrifai_tpu.obs.trace import pull
 from transmogrifai_tpu.stages.base import FitContext
 
 
@@ -161,5 +162,7 @@ class OpLinearRegression(PredictorEstimator):
             # continual refitter treats every family uniformly) and
             # harmlessly ignored
             p = fit_linreg(X, y, w, jnp.float32(self.reg_param))
+        p = pull("fit:params", {"beta": p["beta"],
+                                "intercept": p["intercept"]})
         return LinearRegressionModel(np.asarray(p["beta"]),
                                      float(p["intercept"]))

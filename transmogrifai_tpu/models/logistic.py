@@ -30,6 +30,7 @@ import optax
 from transmogrifai_tpu.models.base import (
     PredictionModel, PredictorEstimator, n_classes_of,
     resolve_init_params)
+from transmogrifai_tpu.obs.trace import pull
 from transmogrifai_tpu.stages.base import FitContext
 
 
@@ -235,5 +236,6 @@ class OpLogisticRegression(PredictorEstimator):
         else:
             params = fit_logreg(X, y, w, jnp.float32(self.reg_param), k,
                                 self.max_iter, init_params=warm)
+        params = pull("fit:params", {"W": params["W"], "b": params["b"]})
         return LogisticRegressionModel(np.asarray(params["W"]),
                                        np.asarray(params["b"]))

@@ -42,7 +42,7 @@ import numpy as np
 
 from transmogrifai_tpu.models.base import (
     PredictionModel, PredictorEstimator, n_classes_of)
-from transmogrifai_tpu.obs.trace import TRACER
+from transmogrifai_tpu.obs.trace import TRACER, pull, upload
 from transmogrifai_tpu.stages.base import FitContext
 
 log = logging.getLogger(__name__)
@@ -65,7 +65,7 @@ def indicator_columns(X) -> np.ndarray:
     (one reduction, on the device when X lives there), never off
     metadata."""
     if isinstance(X, jax.Array):
-        return np.asarray(_all_zero_or_one(X))
+        return pull("tree:indicator", _all_zero_or_one(X))
     X = np.asarray(X)
     return np.all((X == 0) | (X == 1), axis=0)
 
@@ -111,8 +111,8 @@ def _device_quantiles(X, qs: np.ndarray, cols: np.ndarray) -> np.ndarray:
     lo[top] = hi[top] = -1
     t = virtual - lo
     idx = np.concatenate([lo, hi]).astype(np.int32) % n
-    vals, has_nan = jax.device_get(
-        _order_statistics(X, cols.astype(np.int32), idx))
+    vals, has_nan = pull(
+        "tree:edges", _order_statistics(X, cols.astype(np.int32), idx))
     vals = vals.astype(np.float64)
     a, b = vals[:, :m], vals[:, m:]
     diff = b - a
@@ -1404,7 +1404,7 @@ def warm_refit_forest(est, warm: Dict, X, y, w, ctx,
                      min_gain=jnp.float32(est.min_info_gain))
     combined = jax.tree.map(
         lambda o, nw: jnp.concatenate([o[n_new:], nw], axis=0), old, new)
-    return {k2: np.asarray(v) for k2, v in combined.items()}
+    return pull("fit:trees", combined)
 
 
 def warm_refit_gbt(est, warm: Dict, X, y, w, ctx,
@@ -1446,7 +1446,7 @@ def warm_refit_gbt(est, warm: Dict, X, y, w, ctx,
         jnp.float32(est.min_info_gain), est.eval_metric)
     combined = jax.tree.map(
         lambda o, nw: jnp.concatenate([o, nw], axis=0), old, new)
-    return {k2: np.asarray(v) for k2, v in combined.items()}
+    return pull("fit:trees", combined)
 
 
 # --------------------------------------------------------------------------- #
@@ -1590,7 +1590,8 @@ class _TreeEstimatorBase(PredictorEstimator):
                              2 ** int(self.max_depth), 1, n_classes)):
             indicator = indicator_columns(X)
             edges = quantile_bin_edges(X, self.max_bins, indicator)
-            out = (edges, bin_features(jnp.asarray(X), jnp.asarray(edges)),
+            out = (edges, bin_features(upload("tree:bin", X),
+                                       upload("tree:bin", edges)),
                    hist_layout(indicator))
         if cache is not None:
             cache[self.max_bins] = out
@@ -1645,8 +1646,7 @@ class OpRandomForestClassifier(_TreeEstimatorBase):
                            self.subsample_features, self._effective_mcw(),
                            min_gain=jnp.float32(self.min_info_gain),
                            layout=layout)
-        return ForestClassificationModel(edges, {k2: np.asarray(v)
-                                                 for k2, v in trees.items()})
+        return ForestClassificationModel(edges, pull("fit:trees", trees))
 
 
 class OpRandomForestRegressor(OpRandomForestClassifier):
@@ -1664,8 +1664,7 @@ class OpRandomForestRegressor(OpRandomForestClassifier):
                            self.subsample_features, self._effective_mcw(),
                            min_gain=jnp.float32(self.min_info_gain),
                            layout=layout)
-        return ForestRegressionModel(edges, {k: np.asarray(v)
-                                             for k, v in trees.items()})
+        return ForestRegressionModel(edges, pull("fit:trees", trees))
 
 
 class OpDecisionTreeClassifier(OpRandomForestClassifier):
@@ -1696,8 +1695,7 @@ class OpDecisionTreeClassifier(OpRandomForestClassifier):
                          min_gain_norm=jnp.float32(self.min_info_gain),
                          layout=layout, n_classes=k)
         trees = jax.tree.map(lambda a: a[None], tree)  # (1, ...) forest shape
-        return ForestClassificationModel(edges, {k2: np.asarray(v)
-                                                 for k2, v in trees.items()})
+        return ForestClassificationModel(edges, pull("fit:trees", trees))
 
 
 class OpDecisionTreeRegressor(OpRandomForestRegressor):
@@ -1723,8 +1721,7 @@ class OpDecisionTreeRegressor(OpRandomForestRegressor):
                          min_gain_norm=jnp.float32(self.min_info_gain),
                          layout=layout)
         trees = jax.tree.map(lambda a: a[None], tree)
-        return ForestRegressionModel(edges, {k: np.asarray(v)
-                                             for k, v in trees.items()})
+        return ForestRegressionModel(edges, pull("fit:trees", trees))
 
 
 class OpGBTClassifier(_TreeEstimatorBase):
@@ -1825,7 +1822,7 @@ class OpGBTClassifier(_TreeEstimatorBase):
                 colsample=jnp.float32(self.colsample_bytree), seed=seed,
                 min_gain_norm=jnp.float32(self.min_info_gain), layout=layout)
             return GBTMulticlassModel(
-                edges, {k2: np.asarray(v) for k2, v in trees.items()},
+                edges, pull("fit:trees", trees),
                 self.learning_rate)
         esr = int(self.early_stopping_rounds or 0)
         n_rounds = self.n_estimators
@@ -1889,7 +1886,7 @@ class OpGBTClassifier(_TreeEstimatorBase):
             min_gain_norm=jnp.float32(self.min_info_gain), layout=layout,
             base_score=base)
         return self._model(
-            edges, {k2: np.asarray(v) for k2, v in trees.items()},
+            edges, pull("fit:trees", trees),
             self.learning_rate, base)
 
     def _model(self, edges, trees, learning_rate, base_score):

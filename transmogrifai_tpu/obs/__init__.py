@@ -9,7 +9,10 @@ work, PAPERS.md):
 - `trace`   — thread-safe hierarchical `Span` tracer with contextvar
               propagation; `RunProfile` phases, per-stage DAG fits,
               ingest workers, sweep blocks, retry backoffs, and serving
-              batches all open spans on the global `TRACER`
+              batches all open spans on the global `TRACER`; `pull` /
+              `upload` / `uploading` span every crossing between host
+              and device with its bytes, `train_passes()` groups the
+              ring's spans by training pass
 - `export`  — Chrome-trace/Perfetto JSON exporter (+ validation) and a
               JSONL structured event log with run correlation ids
 - `goodput` — `GoodputReport`: spans + events rolled into productive /

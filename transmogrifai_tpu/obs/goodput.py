@@ -14,7 +14,10 @@ events the rest of the codebase already emits:
   (`analysis/retrace.py` emits a ``recompile`` event with the measured
   trace duration on every jit cache miss) plus the XLA backend compiles
   that followed (the ``compile`` spans `utils/compile_cache.py` records
-  from `jax.monitoring`, thread-seconds);
+  from `jax.monitoring`, thread-seconds). JAX times a backend compile
+  around its look into the persistent cache, so the bucket holds both
+  real compiles and loads of a cached program: the spans with
+  ``cache_hit`` set are counted under ``compile_cache_loads``;
 - ``ingest_wait_s``    — main-thread time blocked on device completion
   tokens during pipelined ingest (the `IngestStats.upload_wait_s`
   attribute on each ingest span);
@@ -228,7 +231,7 @@ def build_report(root: Span, spans: Iterable[Span]) -> GoodputReport:
               "resumed_blocks": 0, "faults_injected": 0,
               "cache_hits": 0, "cache_misses": 0,
               "steals": 0, "workers_retired": 0,
-              "block_resizes": 0}
+              "block_resizes": 0, "compile_cache_loads": 0}
     saved = 0.0
     cache_saved = 0.0
     compile_saved = 0.0
@@ -263,6 +266,8 @@ def build_report(root: Span, spans: Iterable[Span]) -> GoodputReport:
                     sp.attributes.get("upload_wait_s", 0.0) or 0.0)
             elif sp.category == "compile":
                 b["recompile_s"] += sp.duration_s
+                counts["compile_cache_loads"] += bool(
+                    sp.attributes.get("cache_hit"))
         # events count wherever they landed — INCLUDING the root (a
         # sweep invoked directly under the root attaches its
         # journal_resume / oom_redo events there)

@@ -36,6 +36,14 @@ Design constraints this module answers:
 - **bounded memory**: finished spans collect in a ring (default 64k);
   a long-lived serving process drops the oldest and counts the drops
   instead of growing without bound.
+- **the host-device boundary**: a read of a device array goes through
+  `pull(site, x)` and an upload of a host array through `upload(site,
+  x)` (or, where the copy happens inside a `jnp` call, under
+  `uploading(site, operands)`): spans `pull:<site>` / `upload:<site>`
+  of category `transfer` with the `bytes` that crossed, a pull also
+  with the seconds it waited for the producer (`wait_s`) and the
+  seconds of the read itself (`copy_s`). `train_passes()` hands a
+  reader the finished spans by training pass, attributes included.
 
 The tracer is always on: an un-exported span costs one object and two
 clock reads, which is noise next to anything worth tracing here (file
@@ -59,6 +67,7 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
 
 __all__ = ["Span", "Tracer", "TRACER", "get_tracer", "current_span",
            "add_event", "ambient_traceparent", "new_run_id", "now_s",
+           "pull", "upload", "uploading", "train_passes",
            "new_trace_id", "span_id_hex", "parse_traceparent",
            "format_traceparent", "TraceContext", "RequestTrace",
            "TracingParams", "TailSampler"]
@@ -399,6 +408,101 @@ def add_event(name: str, **attributes: Any) -> bool:
         return False
     sp.event(name, **attributes)
     return True
+
+
+# -- the host-device boundary ------------------------------------------------ #
+
+def _host_nbytes(x: Any) -> int:
+    """Bytes of the numpy leaves of `x` (an array or a pytree)."""
+    import jax
+    import numpy as np
+    return sum(int(leaf.nbytes) for leaf in jax.tree_util.tree_leaves(x)
+               if isinstance(leaf, np.ndarray))
+
+
+def pull(site: str, x: Any) -> Any:
+    """The host value of `x` (an array or a pytree of arrays): what
+    `np.asarray` gave at the site, leaf by leaf, under a span
+    `pull:<site>` of category `transfer` that carries `bytes` (of the
+    leaves that were device arrays), `wait_s` (until the producer had
+    finished: the chip was computing, or uploading what the producer
+    needs) and `copy_s` (the read itself: the chip idle unless another
+    thread holds it). Blocking first changes nothing: `np.asarray`
+    blocked anyway. A value already on the host is returned as it is,
+    with no span."""
+    import jax
+    import numpy as np
+    if isinstance(x, np.ndarray):
+        return x
+    leaves, treedef = jax.tree_util.tree_flatten(x)
+    dev = [leaf for leaf in leaves if isinstance(leaf, jax.Array)]
+    if not dev:
+        return x
+    with TRACER.span(f"pull:{site}", category="transfer",
+                     bytes=sum(int(leaf.nbytes) for leaf in dev)) as sp:
+        t0 = time.perf_counter()
+        jax.block_until_ready(dev)
+        t1 = time.perf_counter()
+        out = treedef.unflatten([np.asarray(leaf) for leaf in leaves])
+        sp.set(wait_s=t1 - t0, copy_s=time.perf_counter() - t1)
+    return out
+
+
+@contextlib.contextmanager
+def uploading(site: str, operands: Any) -> Iterator[Optional[Span]]:
+    """A span `upload:<site>` (category `transfer`) around a `jnp` call
+    inside which the numpy leaves of `operands` become device arrays;
+    `bytes` counts those leaves. Nothing is blocked on: the span's wall
+    is the host's seconds in the call, the bytes are the counter, and
+    the transfer's tail shows as the `wait_s` of the next pull. No host
+    leaf, no span."""
+    n = _host_nbytes(operands)
+    if not n:
+        yield None
+        return
+    with TRACER.span(f"upload:{site}", category="transfer", bytes=n) as sp:
+        yield sp
+
+
+def upload(site: str, x: Any, dtype: Any = None) -> Any:
+    """`jnp.asarray(x, dtype)` under a span `upload:<site>` (see
+    `uploading`); a device array passes through with no span."""
+    import jax.numpy as jnp
+    with uploading(site, x):
+        return jnp.asarray(x, dtype)
+
+
+def train_passes(tracer: Optional[Tracer] = None) -> List[Dict[str, Any]]:
+    """The ring's finished spans by training pass: one ``{"root": Span,
+    "spans": [Span, ...]}`` for every finished `workflow:train` span,
+    oldest pass first, `spans` being everything that descends from the
+    root (walking `parent_id`; the family threads' spans have explicit
+    parents), in the order they were opened. A span under no
+    `workflow:train` is left out. This is a reader's way to a span's
+    attributes (`bytes`, `wait_s`, whatever a layer sets): the names and
+    seconds alone never carried them."""
+    # by span id: the order the spans were opened in (the ring holds
+    # them in the order they finished, children before parents)
+    spans = sorted((tracer or TRACER).spans(), key=lambda sp: sp.span_id)
+    by_id = {sp.span_id: sp for sp in spans}
+    roots: Dict[int, Optional[Span]] = {}
+
+    def root_of(sp: Span) -> Optional[Span]:
+        if sp.span_id not in roots:
+            parent = by_id.get(sp.parent_id)  # type: ignore[arg-type]
+            roots[sp.span_id] = (
+                sp if sp.name == "workflow:train"
+                else root_of(parent) if parent is not None else None)
+        return roots[sp.span_id]
+
+    passes: Dict[int, Dict[str, Any]] = {}
+    for sp in spans:
+        root = root_of(sp)
+        if root is sp:
+            passes[sp.span_id] = {"root": sp, "spans": []}
+        elif root is not None:
+            passes[root.span_id]["spans"].append(sp)
+    return list(passes.values())
 
 
 def ambient_traceparent() -> Optional[str]:

@@ -28,7 +28,7 @@ from transmogrifai_tpu import types as T
 from transmogrifai_tpu.data.columns import Column, factorize_text
 from transmogrifai_tpu.data.metadata import (
     NULL_INDICATOR, OTHER_INDICATOR, VectorColumnMetadata, VectorMetadata)
-from transmogrifai_tpu.obs.trace import TRACER
+from transmogrifai_tpu.obs.trace import TRACER, upload, uploading
 from transmogrifai_tpu.stages.base import Estimator, FitContext, Transformer
 
 
@@ -132,14 +132,17 @@ class OneHotModel(Transformer):
 
     def device_apply(self, enc, dev):
         outs = []
-        for i, ids in enumerate(enc):
-            k = len(self.vocabs[i])
-            n_classes = k + 2  # levels + OTHER + NULL
-            oh = jax.nn.one_hot(ids, n_classes, dtype=jnp.float32)
-            if not self.track_nulls:
-                oh = oh[:, : k + 1]
-            outs.append(oh)
-        return jnp.concatenate(outs, axis=1) if outs else jnp.zeros((0, 0))
+        # the host ids become device arrays inside `one_hot`
+        with uploading("pivot", enc):
+            for i, ids in enumerate(enc):
+                k = len(self.vocabs[i])
+                n_classes = k + 2  # levels + OTHER + NULL
+                oh = jax.nn.one_hot(ids, n_classes, dtype=jnp.float32)
+                if not self.track_nulls:
+                    oh = oh[:, : k + 1]
+                outs.append(oh)
+            return (jnp.concatenate(outs, axis=1) if outs
+                    else jnp.zeros((0, 0)))
 
     def output_meta(self) -> VectorMetadata:
         cols: List[VectorColumnMetadata] = []
@@ -222,7 +225,7 @@ class MultiPickListModel(Transformer):
         return outs
 
     def device_apply(self, enc, dev):
-        return jnp.concatenate([jnp.asarray(a) for a in enc], axis=1)
+        return jnp.concatenate([upload("pivot", a) for a in enc], axis=1)
 
     def output_meta(self) -> VectorMetadata:
         cols: List[VectorColumnMetadata] = []

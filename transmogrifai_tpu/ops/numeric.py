@@ -22,6 +22,7 @@ from transmogrifai_tpu import types as T
 from transmogrifai_tpu.data.columns import Column
 from transmogrifai_tpu.data.metadata import (
     NULL_INDICATOR, VectorColumnMetadata, VectorMetadata)
+from transmogrifai_tpu.obs.trace import pull, uploading
 from transmogrifai_tpu.stages.base import Estimator, FitContext, Transformer
 
 
@@ -59,7 +60,9 @@ class _NumericModelBase(Transformer):
             if self.track_nulls:
                 cols.append(1.0 - m)
             groups.append(cols)
-        return _interleave(groups)
+        # the columns are numpy up to here: the stack is their upload
+        with uploading(f"stage:{self.operation_name}", groups):
+            return _interleave(groups)
 
     def output_meta(self) -> VectorMetadata:
         cols: List[VectorColumnMetadata] = []
@@ -99,10 +102,12 @@ class RealVectorizer(Estimator):
 
     def fit_model(self, cols: Sequence[Column], ctx: FitContext) -> Transformer:
         dev = [c.device_value() for c in cols]
-        value, mask = stack_scalar_dev(dev)
+        with uploading(f"stage:{self.operation_name}", dev):
+            value, mask = stack_scalar_dev(dev)
         if self.fill_value == "mean":
             denom = jnp.maximum(mask.sum(axis=0), 1.0)
-            fills = np.asarray((value * mask).sum(axis=0) / denom)
+            # `wait_s`: what is left of the table's upload, and the reduction
+            fills = pull("impute:fills", (value * mask).sum(axis=0) / denom)
         elif self.fill_value == "median":
             fills = []
             for c in cols:
@@ -136,7 +141,8 @@ class IntegralVectorizer(Estimator):
         fills = []
         for c in cols:
             if self.fill_value == "mode":
-                v = np.asarray(c.data["value"])[np.asarray(c.data["mask"])]
+                v = np.asarray(pull("impute:fills", c.data["value"]))[
+                    np.asarray(pull("impute:fills", c.data["mask"]))]
                 if v.size == 0:
                     fills.append(0.0)
                 else:

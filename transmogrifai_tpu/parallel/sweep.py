@@ -46,7 +46,7 @@ import numpy as np
 
 from transmogrifai_tpu import types as T
 from transmogrifai_tpu.obs import export as obs_export
-from transmogrifai_tpu.obs.trace import TRACER
+from transmogrifai_tpu.obs.trace import TRACER, pull, upload
 from transmogrifai_tpu.data.columns import Column
 from transmogrifai_tpu.evaluators.device_metrics import (
     device_metric, make_device_metric)
@@ -284,7 +284,7 @@ def _sweep_generic(est, grids: List[Dict], X, y, folds, evaluator,
     skipped, completed configs append as soon as their folds finish."""
     from transmogrifai_tpu.models.trees import _TreeEstimatorBase
     out: List[List[float]] = []
-    y_np = np.asarray(y)
+    y_np = np.asarray(pull(f"sweep:{type(est).__name__}", y))
     journal = _active_journal()
     best = getattr(_SWEEP_TL, "best", None)
     bin_cache: Dict = {}  # shared across the family: bin X once per max_bins
@@ -309,11 +309,12 @@ def _sweep_generic(est, grids: List[Dict], X, y, folds, evaluator,
             row = []
             for tr, va in folds:
                 with _dispatch_span(type(est).__name__):
-                    model = clone.fit_arrays(X, y, jnp.asarray(tr), ctx)
+                    model = clone.fit_arrays(
+                        X, y, upload("sweep:folds", tr), ctx)
                     pred = model.predict_arrays(X)
                 row.append(_metric(
                     evaluator, y_np,
-                    {k: np.asarray(v) for k, v in pred.items()}, va))
+                    pull(f"sweep:{type(est).__name__}", dict(pred)), va))
         out.append(row)
         if journal is not None:
             journal.append(grid, row,
@@ -362,7 +363,7 @@ def _dispatch_span(family: str, timed: bool = False, **attributes):
     so the dispatch — and the XLA compile a first dispatch asks for, as
     the span's `compile:*` child — sits in the run's timeline
     (`instrumented_jit` drops a `recompile` event on it when the program
-    is traced, `utils/compile_cache.py` a `compile_cache_hit`). `timed`
+    is traced). `timed`
     (the tree families' host loops) also counts a dispatch that
     completed, and its wall, in `SWEEP_STATS`."""
     with TRACER.span(f"sweep:dispatch:{family}", category="sweep_dispatch",
@@ -521,7 +522,7 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
     host = isinstance(metric_fn, HostMetricFallback)
     metric_key = None if host else metric_fn.key
     n_folds = int(W.shape[0])
-    V_np = np.asarray(V) if host else None
+    V_np = pull(f"sweep:{family}", V) if host else None
 
     def _run_group(static, idxs):
         dyn_dicts = [dyn_of(grids[i]) for i in idxs]
@@ -531,7 +532,7 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
                for k in dyn_dicts[0]}
         data = data_of(static)
         shape = shape_of(static, idxs)
-        y_np = np.asarray(data["y"]) if host else None
+        y_np = pull(f"sweep:{family}", data["y"]) if host else None
 
         if host_dispatch and sharding is None:
             n_pairs = len(idxs) * n_folds
@@ -565,7 +566,7 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
                         prog(data, dchunk, W[jnp.asarray(fs)],
                              V[jnp.asarray(fs)]))
                 if host:
-                    out_np = jax.tree_util.tree_map(np.asarray, out)
+                    out_np = pull(f"sweep:{family}", out)
                     for t in range(min(width, n_pairs - s)):
                         row_i, j = divmod(s + t, n_folds)
                         if metrics[idxs[row_i]] is None:
@@ -580,7 +581,7 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
             if pend:
                 with TRACER.span(f"sweep:fetch:{family}",
                                  category="sweep_fetch"):
-                    flat = np.asarray(jnp.concatenate(
+                    flat = pull(f"sweep:{family}", jnp.concatenate(
                         [jnp.asarray(o, jnp.float32) for _, o in pend]))
                 for c, (s0, _) in enumerate(pend):
                     for t in range(min(width, n_pairs - s0)):
@@ -598,7 +599,7 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
             data, W, V, dyn, sharding, family=family,
             **dispatch_attrs(static, idxs))
         if host:
-            pred_np = jax.tree_util.tree_map(np.asarray, gk)
+            pred_np = pull(f"sweep:{family}", gk)
             for row_i, grid_i in enumerate(idxs):
                 metrics[grid_i] = [
                     _metric(metric_fn.evaluator, y_np,
@@ -608,7 +609,7 @@ def _sweep_blocks(grids: List[Dict], W, V, metric_fn, sharding,
         else:
             with TRACER.span(f"sweep:fetch:{family}",
                              category="sweep_fetch"):
-                gk = np.asarray(gk)
+                gk = pull(f"sweep:{family}", gk)
             for row_i, grid_i in enumerate(idxs):
                 metrics[grid_i] = [float(m) for m in gk[row_i]]
 
@@ -993,12 +994,17 @@ def _binned_cache(est, grids, X, ctx, n_classes: int = 0) -> Tuple[
             with TRACER.span("sweep:bin", category="sweep",
                              max_bins=mb) as sp:
                 X_edges = X if n is None else X[:n]
+                # the waiters need exactly this binned matrix: they stall
+                # behind the two reads (`pull:tree:*`) on purpose
                 if "indicator" not in out:      # no shared context
+                    # conc-ok: C003 (waiters reuse the binned matrix)
                     out["indicator"] = indicator_columns(X_edges)
                 if "layout" not in out:
                     out["layout"] = hist_layout(out["indicator"])
+                # conc-ok: C003 (waiters reuse the binned matrix)
                 edges = quantile_bin_edges(X_edges, mb, out["indicator"])
-                out[mb] = bin_features(jnp.asarray(X), jnp.asarray(edges))
+                out[mb] = bin_features(upload("sweep:bin", X),
+                                       upload("sweep:bin", edges))
                 sp.set(hist_slots=hist_slots(int(X.shape[1]), mb,
                                              out["layout"]),
                        edges=edges_site(X_edges),
@@ -1040,11 +1046,11 @@ def _pad_depth_of(est, grids, idxs) -> int:
 def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
                   regression: bool):
     if regression:
-        Y = jnp.asarray(y)[:, None]
+        Y = upload("sweep:label", y)[:, None]
         n_out = 1
     else:       # the labels themselves: `grow_tree`'s class form
         n_out = n_classes_of(est, y, ctx)
-        Y = jnp.asarray(y).astype(jnp.int32)
+        Y = upload("sweep:label", y).astype(jnp.int32)
     xb_by_bins, layout, blocks = _binned_cache(
         est, grids, X, ctx, n_classes=0 if regression else n_out)
     seed = int(ctx.seed) if ctx is not None else 0
@@ -1233,8 +1239,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
         if metrics[i] is None:
             groups.setdefault(static_of(g), []).append(i)
     host = isinstance(metric_fn, HostMetricFallback)
-    y_np = np.asarray(y) if host else None
-    V_np = np.asarray(V) if host else None
+    y_np = np.asarray(pull("sweep:gbt", y)) if host else None
+    V_np = pull("sweep:gbt", V) if host else None
 
     def _run_gbt_group(static, idxs):
         n_est, max_bins, esr = static[:3]
@@ -1294,13 +1300,13 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                              ks))
                 done += int(ks.shape[0])
                 if (esr > 0 and done < n_est
-                        and bool(np.all(np.asarray(since) >= esr))):
+                        and bool(np.all(pull("sweep:gbt", since) >= esr))):
                     log.info("gbt sweep: early stop after %d/%d rounds "
                              "(%d pairs)", done, n_est, width)
                     break
             if host:
-                pred_np = jax.tree_util.tree_map(
-                    np.asarray, score_prog(data["y"], margin, Vsel, Wsel)[0])
+                pred_np = pull(
+                    "sweep:gbt", score_prog(data["y"], margin, Vsel, Wsel)[0])
                 row_metrics = [
                     _metric(metric_fn.evaluator, y_np,
                             jax.tree_util.tree_map(
@@ -1310,8 +1316,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             else:
                 with TRACER.span("sweep:fetch:gbt",
                                  category="sweep_fetch") as fetch:
-                    row_metrics, fitted = jax.device_get(
-                        score_prog(data["y"], margin, Vsel, Wsel))
+                    row_metrics, fitted = pull(
+                        "sweep:gbt", score_prog(data["y"], margin, Vsel, Wsel))
                     row_metrics = [float(m) for m in row_metrics]
                     # the dispatch's real pairs, as (grid, fold), and
                     # what each chain says of its training rows
@@ -1424,8 +1430,8 @@ def _run_sweep(est, grids: List[Dict], X, y, folds, evaluator, ctx,
             _, X, y, W, V = cached  # same selector fit: reuse padded/sharded set
         else:
             key_objs = (X, y, list(folds))
-            W = jnp.asarray(np.stack([tr for tr, _ in folds]))
-            V = jnp.asarray(np.stack([va for _, va in folds]))
+            W = upload("sweep:folds", np.stack([tr for tr, _ in folds]))
+            V = upload("sweep:folds", np.stack([va for _, va in folds]))
             if ctx is not None and ctx.mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -1467,5 +1473,6 @@ def _run_sweep(est, grids: List[Dict], X, y, folds, evaluator, ctx,
                 # device's queue: from a tree family's thread the reduction
                 # would wait behind the logistic block, and the bin edges
                 # behind it
+                # conc-ok: C003 (waiters reuse the layout read here)
                 ctx._sweep_bin_cache = {"indicator": indicator_columns(X)}
     return handler(est, grids, X, y, W, V, metric_fn, ctx, sharding)

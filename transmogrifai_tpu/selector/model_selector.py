@@ -27,7 +27,7 @@ from transmogrifai_tpu.evaluators import (
     BinaryClassificationEvaluator, MultiClassificationEvaluator,
     RegressionEvaluator)
 from transmogrifai_tpu.models import OpLinearRegression, OpLogisticRegression
-from transmogrifai_tpu.obs.trace import TRACER
+from transmogrifai_tpu.obs.trace import TRACER, pull, upload
 from transmogrifai_tpu.parallel.sweep import run_sweep
 from transmogrifai_tpu.selector.splitters import DataBalancer, DataCutter, DataSplitter
 from transmogrifai_tpu.selector.validators import OpCrossValidation
@@ -130,8 +130,9 @@ class ModelSelector(Estimator):
         # the fit's four phases are sibling spans under the caller's
         # `stage:fit:*`: prepare, sweep, refit, evaluate
         with TRACER.span("selector:prepare", category="selector"):
-            y_np = np.asarray(label_col.data["value"], dtype=np.float64)
-            X_full = jnp.asarray(vec_col.device_value())
+            y_np = np.asarray(pull("selector:label", label_col.data["value"]),
+                              dtype=np.float64)
+            X_full = upload("selector:matrix", vec_col.device_value())
 
             # -- data preparation (Splitter.split + preValidationPrepare) #
             split_summary: Dict[str, Any] = {}
@@ -150,9 +151,9 @@ class ModelSelector(Estimator):
                 ctx.n_classes = int(self.n_classes or max(
                     int(y_np.max(initial=0)) + 1, 2))
 
-            X = X_full[jnp.asarray(train_idx)]
+            X = X_full[upload("selector:rows", train_idx)]
             y_train = y_np[train_idx]
-            y_dev = jnp.asarray(y_train.astype(np.float32))
+            y_dev = upload("selector:label", y_train.astype(np.float32))
             folds = self.validator.splits(y_train)
             data_digest = (self._data_digest(X, y_dev)
                            if ctx.cv_refit is None
@@ -583,15 +584,16 @@ class ModelSelector(Estimator):
             if len(idx) == 0:
                 return {}
             if rows is None:
-                rows = X_full[jnp.asarray(idx)]
+                rows = X_full[upload("evaluate:rows", idx)]
             pred = model.predict_arrays(rows)
             if on_device is not None:
                 if y is None:
-                    y = jnp.asarray(y_np[idx], jnp.float32)
+                    y = upload("evaluate:label", y_np[idx], jnp.float32)
                 m = on_device(y, pred, ctx.n_classes)
             else:
                 pcol = Column(T.Prediction,
-                              {k: np.asarray(v) for k, v in pred.items()})
+                              {k: np.asarray(pull(f"evaluate:{k}", v))
+                               for k, v in pred.items()})
                 lcol = Column(T.RealNN, {
                     "value": y_np[idx],
                     "mask": np.ones(len(idx), dtype=bool)})

@@ -30,6 +30,7 @@ import numpy as np
 from transmogrifai_tpu import types as T
 from transmogrifai_tpu.data.columns import Column, kind_of, SCALAR, VECTOR, PREDICTION
 from transmogrifai_tpu.data.metadata import VectorMetadata
+from transmogrifai_tpu.obs.trace import pull
 from transmogrifai_tpu.utils.uid import UID
 
 
@@ -236,10 +237,14 @@ class Transformer(Stage):
             return Column.vector(dev, self.output_meta())
         if k == SCALAR:
             # normalize back to the host columnar contract (f64 value, bool mask)
+            dev = pull(f"stage:{self.operation_name}",
+                       {"value": dev["value"], "mask": dev["mask"]})
             return Column(out_t, {
                 "value": np.asarray(dev["value"], dtype=np.float64),
                 "mask": np.asarray(dev["mask"]).astype(bool)})
         if k == PREDICTION:
+            # a fitted model's prediction over the rows it was given
+            dev = pull(f"stage:{self.operation_name}", dict(dev))
             return Column(out_t, {key: np.asarray(a) for key, a in dev.items()})
         raise TypeError(
             f"{self.operation_name}: device output cannot have host kind {k}; "

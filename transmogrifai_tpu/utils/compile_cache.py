@@ -32,7 +32,10 @@ Every XLA compile is also a span where it happened
 on the thread that asked for it, so the listener backdates a
 `compile:<current span>/<fun_name>` span under that thread's current
 `obs.trace` span — a family's sweep dispatch, the winner's refit, a
-feature stage — and `COMPILE_STATS` keeps the process totals.
+feature stage — and `COMPILE_STATS` keeps the process totals. JAX times
+the backend compile AROUND its look into the persistent cache, so on a
+warm cache a `compile:*` span is a cache load, not a compile: the span
+says which in its `cache_hit` attribute.
 """
 
 from __future__ import annotations
@@ -57,18 +60,21 @@ _dir: str | None = None  # this process's cache directory, once chosen
 COMPILE_STATS = {"requests": 0, "cache_hits": 0, "backend_compile_s": 0.0}
 _stats_lock = threading.Lock()
 _listening = False
+# whether the persistent cache answered this thread's compile request:
+# the hit event fires on the compiling thread, inside the interval JAX
+# reports as the backend compile's duration
+_request = threading.local()
 
 
 def _on_event(event: str, **_) -> None:
     if event == "/jax/compilation_cache/compile_requests_use_cache":
+        _request.hit = False
         with _stats_lock:
             COMPILE_STATS["requests"] += 1
     elif event == "/jax/compilation_cache/cache_hits":
+        _request.hit = True
         with _stats_lock:
             COMPILE_STATS["cache_hits"] += 1
-        sp = TRACER.current()
-        if sp is not None:
-            sp.event("compile_cache_hit")
 
 
 def _on_duration(event: str, duration_secs: float, **kw) -> None:
@@ -82,10 +88,12 @@ def _on_duration(event: str, duration_secs: float, **kw) -> None:
     # benchmark's readers see (name, duration) pairs only.
     owner = TRACER.current()
     end = now_s()
+    hit, _request.hit = getattr(_request, "hit", False), False
     TRACER.span_at(
         f"compile:{owner.name if owner is not None else '-'}"
         f"/{kw.get('fun_name', '?')}",
-        end - duration_secs, end, parent=owner, category="compile")
+        end - duration_secs, end, parent=owner, category="compile",
+        cache_hit=hit)
 
 
 def register_compile_listeners() -> None:
