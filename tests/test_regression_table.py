@@ -460,7 +460,8 @@ def test_checker_without_contingency_against_its_reference(
     assert fitted.summary["categoricalStats"] == []
     cont, = [s for s in TRACER.trace_spans(root.trace_id)
              if s.name == "sanity:contingency"]
-    assert cont.attributes == {"categorical_label": False}
+    assert cont.attributes == {"categorical_label": False,
+                               "tables": "none"}
 
 
 def test_a_categorical_label_says_so_on_the_contingency_span():

@@ -1,5 +1,7 @@
 """SanityChecker / MinVarianceFilter tests (reference: SanityCheckerTest.scala)."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -34,6 +36,36 @@ def _fit(est, label, vec):
     vf = FeatureGeneratorStage(name="v", ftype=t.OPVector).get_output()
     est.set_input(lf, vf)
     return est.fit([label, vec], FitContext(len(label.data["value"])))
+
+
+def _typed_table(seed, n, n_labels, on_device=True):
+    """(label column, vector column, X, y): two numeric columns, a
+    single-pick group of six levels, a multi-pick group of four (several
+    1s a row), a two-level group and a two-level group the label's parity
+    decides (dropped), against a skewed label of `n_labels` whole
+    values."""
+    rng = np.random.default_rng(seed)
+    p = 1.0 / (1.0 + np.arange(n_labels))
+    y = rng.choice(n_labels, size=n, p=p / p.sum())
+    pick = (y + rng.integers(0, 3, n)) % 6
+    multi = rng.uniform(size=(n, 4)) < 0.2 + 0.3 * (y[:, None] % 4
+                                                   == np.arange(4))
+    coin = rng.uniform(size=n) < 0.5
+    X = np.concatenate([
+        (rng.normal(size=n) + 0.3 * y)[:, None], rng.uniform(size=(n, 1)),
+        np.eye(6)[pick], multi, np.stack([coin, ~coin], 1),
+        np.eye(2)[y % 2]], axis=1).astype(np.float32)
+    spec = ([("num", None, None), ("unif", None, None)]
+            + [("pick", "pick", f"p{i}") for i in range(6)]
+            + [("multi", "multi", f"m{i}") for i in range(4)]
+            + [("coin", "coin", v) for v in "ht"]
+            + [("parity", "parity", v) for v in "eo"])
+    meta = VectorMetadata("v", tuple(
+        VectorColumnMetadata(parent_name=name, parent_type="Real",
+                             grouping=group, indicator_value=value)
+        for name, group, value in spec)).with_indices()
+    vec = Column(t.OPVector, jnp.asarray(X) if on_device else X, meta=meta)
+    return _label(y), vec, X, y.astype(np.float64)
 
 
 def test_drops_low_variance_and_leakage():
@@ -232,3 +264,251 @@ class TestWideFeatureAxis:
         assert 4 in kept and 13 not in kept  # later duplicate dropped
         reasons = model.summary["stats"][13]["dropped"]
         assert any("corr" in r for r in reasons)
+
+
+# --------------------------------------------------------------------- #
+# the encoded matrix stays on the device: the sample a gather, every    #
+# group's table a slice of one product (PR 35)                          #
+# --------------------------------------------------------------------- #
+
+def _host_tables(X, y, meta):
+    """The tables as the host built them before PR 35: one float64
+    matmul a group over the (sampled) host matrix against a float32
+    one-hot of the rounded label's sorted levels."""
+    yi = np.round(y).astype(np.int64)
+    oh = (yi[:, None] == np.unique(yi)[None, :]).astype(np.float32)
+    groups = {}
+    for i, c in enumerate(meta.columns):
+        if c.indicator_value is not None:
+            groups.setdefault(c.grouping_key(), []).append(i)
+    return {key: (idxs, X[:, idxs].T.astype(np.float64) @ oh)
+            for key, idxs in groups.items()}
+
+
+def _place(vec, how):
+    """The vector column's matrix as a host array, on one device, or its
+    rows over four of the suite's virtual devices."""
+    if how == "sharded":
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec
+        mesh = Mesh(np.asarray(jax.devices()[:4]), ("data",))
+        vec.data = jax.device_put(np.asarray(vec.data), NamedSharding(
+            mesh, PartitionSpec("data", None)))
+    elif how == "host":
+        vec.data = np.asarray(vec.data)
+    return vec
+
+
+def _traced_fit(est, label, vec):
+    from transmogrifai_tpu.obs.trace import TRACER
+    with TRACER.span("run:check", new_trace=True) as root:
+        model = est.fit_model([label, vec], FitContext(n_rows=len(vec)))
+    return model, {s.name: s for s in TRACER.trace_spans(root.trace_id)}
+
+
+TABLE_CASES = [
+    # (seed, rows, labels, the checker's parameters, where the matrix
+    #  lives, `sample` on sanity:moments, `tables` on sanity:contingency)
+    (21, 704, 2, {}, "device", "whole", "device"),
+    (11, 3000, 2, {"sample_upper_limit": 1000}, "device", "device",
+     "device"),
+    (12, 1500, 23, {}, "device", "whole", "device"),
+    (22, 4000, 23, {"check_sample": 0.5}, "device", "device", "device"),
+    (23, 2000, 40, {"categorical_label": True}, "device", "whole",
+     "device"),
+    (23, 2000, 40, {}, "device", "whole", "none"),
+    (21, 704, 2, {"categorical_label": False}, "device", "whole", "none"),
+    (21, 704, 2, {"categorical_label": True,
+                  "categorical_label_max_card": 0}, "device", "whole",
+     "device"),
+    (11, 3000, 2, {"sample_upper_limit": 1000}, "host", "host", "device"),
+    (12, 1500, 23, {}, "host", "whole", "device"),
+    (21, 704, 2, {}, "sharded", "whole", "device"),
+    (22, 4000, 23, {"check_sample": 0.5}, "sharded", "device", "device"),
+]
+
+
+@pytest.mark.parametrize(
+    "seed,n,k,params,where,sample,tables", TABLE_CASES, ids=[
+        f"{k}labels-{n}rows-{where}-{sample}-{tables}"
+        + "".join(f"-{key}{value}" for key, value in params.items()
+                  if key.startswith("cat"))
+        for _, n, k, params, where, sample, tables in TABLE_CASES])
+def test_every_groups_table_is_the_host_formulas_to_the_bit(
+        seed, n, k, params, where, sample, tables):
+    from transmogrifai_tpu.automl.sanity_checker import (
+        CategoricalGroupStats, _contingency_counts, _label_codes,
+        contingency_stats)
+    label, vec, X, y = _typed_table(seed, n, k)
+    est = SanityChecker(**params)
+    model, spans = _traced_fit(est, label, _place(vec, where))
+    assert spans["sanity:moments"].attributes["sample"] == sample
+    assert spans["sanity:contingency"].attributes["tables"] == tables
+    # Pearson reads no row of the matrix on the host
+    assert "pull:sanity:matrix" not in spans
+    idx = est._sample_rows(n)
+    Xs, ys = (X, y) if idx is None else (X[idx], y[idx])
+    assert model.summary["n_rows"] == len(ys)
+    if tables == "none":
+        assert model.summary["categoricalStats"] == []
+        assert "pull:sanity:contingency" not in spans
+        assert "upload:sanity:label" not in spans
+        return
+    host = _host_tables(Xs, ys, vec.meta)
+    codes, levels = _label_codes(ys, est.categorical_label_max_card,
+                                 force=est.categorical_label)
+    assert levels == k and codes.dtype == np.int32
+    assert spans["sanity:contingency"].attributes["groups"] == len(host)
+    # as wide as the levels an unforced label may have (30, or the next
+    # multiple of it under a forced one), whatever the sample holds: a
+    # rare label out of it compiles nothing
+    step = max(est.categorical_label_max_card, 1)
+    assert spans["pull:sanity:contingency"].attributes["bytes"] == \
+        4 * X.shape[1] * -(-k // step) * step
+    assert spans["upload:sanity:label"].attributes["bytes"] == 4 * len(ys)
+    counts = _contingency_counts(jnp.asarray(Xs), codes, levels, step)
+    assert counts.dtype == np.float64 and counts.shape == (X.shape[1], k)
+    # several 1s a row in the multi-pick group: its table sums past n
+    assert host["multi_multi"][1].sum() > len(ys)
+    want = []
+    for key, (idxs, table) in host.items():
+        assert np.array_equal(counts[idxs], table), key
+        cs = contingency_stats(table)
+        want.append(CategoricalGroupStats(
+            group=key, cramers_v=cs["cramers_v"],
+            mutual_info=cs["mutual_info"], pointwise_mutual_info=cs["pmi"],
+            max_rule_confidences=cs["max_confidences"],
+            supports=cs["supports"]).to_json())
+    # and the fit's own tables were those: same numbers out of them
+    assert model.summary["categoricalStats"] == want
+
+
+def test_counts_past_a_float32s_whole_numbers_add_on_the_host(monkeypatch):
+    import transmogrifai_tpu.automl.sanity_checker as sc
+    label, vec, X, y = _typed_table(31, 1000, 5)
+    codes, levels = sc._label_codes(y, 30)
+    whole = sc._contingency_counts(jnp.asarray(X), codes, levels, 30)
+    # as if a float32 held whole numbers up to 256 only: four runs of rows
+    monkeypatch.setattr(sc, "_COUNT_EXACT_ROWS", 256)
+    runs = sc._contingency_counts(jnp.asarray(X), codes, levels, 30)
+    # the 0/1 columns' counts to the bit; the two numeric columns' sums
+    # (no table reads them) in another order
+    assert np.array_equal(runs[2:], whole[2:])
+    np.testing.assert_allclose(runs[:2], whole[:2], rtol=1e-5)
+    assert sc._label_counts(jnp.asarray(X), jnp.asarray(codes), 30,
+                            4).shape == (X.shape[1], 4 * 30)
+    for key, (idxs, table) in _host_tables(X, y, vec.meta).items():
+        assert np.array_equal(whole[idxs], table), key
+
+
+def test_a_label_level_out_of_the_sample_compiles_nothing():
+    import transmogrifai_tpu.automl.sanity_checker as sc
+    label, vec, _, y = _typed_table(12, 1500, 23)
+    full = SanityChecker().fit_model([label, vec], FitContext(n_rows=1500))
+    held = sc._label_counts._cache_size()
+    # the rarest label's rows fall out: 22 levels, the same program
+    fewer = SanityChecker().fit_model(
+        [_label(np.where(y == 22, 0, y)), vec], FitContext(n_rows=1500))
+    assert sc._label_counts._cache_size() == held
+    pmi = [len(m.summary["categoricalStats"][0]["pointwiseMutualInfo"])
+           for m in (full, fewer)]
+    assert pmi == [23, 22]
+
+
+def _same_json(got, want, tol, path="summary"):
+    """Equal but for a float's last bits (another CPU's vector width
+    orders a float32 sum otherwise; the tables' bit-equality is the test
+    above): a float within `tol` relative and `tol / 10` absolute."""
+    if isinstance(want, dict):
+        assert set(got) == set(want), path
+        for key in want:
+            _same_json(got[key], want[key], tol, f"{path}.{key}")
+    elif isinstance(want, list):
+        assert len(got) == len(want), path
+        for i, (g, w) in enumerate(zip(got, want)):
+            _same_json(g, w, tol, f"{path}[{i}]")
+    elif isinstance(want, float):
+        assert got == pytest.approx(want, rel=tol, abs=tol / 10), path
+    else:
+        assert got == want, path
+
+
+@pytest.mark.parametrize("name,seed,n,k,params", [
+    ("binary_sampled", 11, 3000, 2, {"sample_upper_limit": 1000}),
+    ("labels23_whole", 12, 1500, 23, {})])
+@pytest.mark.parametrize("where", ["device", "host", "sharded"])
+def test_summary_is_the_one_the_host_tables_gave(name, seed, n, k, params,
+                                                 where):
+    """`tests/fixtures/sanity/*.json`: `model.summary` of these two fits
+    at the commit before PR 35 (the matrix pulled, the sample a host
+    fancy index, the tables float64 host matmuls)."""
+    import json
+    import os
+    label, vec, _, _ = _typed_table(seed, n, k)
+    model = SanityChecker(**params).fit_model(
+        [label, _place(vec, where)], FitContext(n_rows=n))
+    with open(os.path.join(os.path.dirname(__file__), "fixtures", "sanity",
+                           f"{name}.json")) as f:
+        frozen = json.load(f)
+    assert model.summary["kept"] == frozen["kept"]
+    assert model.summary["dropped"] == frozen["dropped"]
+    # rows over several devices: the Gram's float32 sums in another order
+    _same_json(model.summary, frozen, 1e-4 if where == "sharded" else 1e-6)
+
+
+def test_spearman_reads_the_sampled_rows_only():
+    label, vec, X, y = _typed_table(11, 3000, 2)
+    est = SanityChecker(correlation_type="spearman", sample_upper_limit=1000)
+    model, spans = _traced_fit(est, label, vec)
+    assert spans["sanity:moments"].attributes["sample"] == "device"
+    assert spans["pull:sanity:matrix"].attributes["bytes"] == \
+        1000 * X.shape[1] * 4
+    assert spans["sanity:contingency"].attributes["tables"] == "device"
+    # a host array is ranked where it is: nothing to pull
+    hosted, spans = _traced_fit(est, label, _place(vec, "host"))
+    assert "pull:sanity:matrix" not in spans
+    assert hosted.summary == model.summary
+
+
+# --------------------------------------------------------------------- #
+# the table product as the chip's compiler leaves it (no chip: a        #
+# described v5e, `on-chip-measurement` guide section 2)                 #
+# --------------------------------------------------------------------- #
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("n,d,k", [(1_000_000, 548, 30),
+                                   (1_000_000, 116, 30)])
+def test_the_chips_product_keeps_float32_operands_and_sums(one_chip, n, d, k):
+    import re
+    from transmogrifai_tpu.automl.sanity_checker import _label_counts
+    shape = lambda s, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+    compiled = _label_counts.lower(
+        shape((n, d), jnp.float32), shape((n,), jnp.int32), width=k,
+        chunks=1).compile()
+    text = compiled.as_text()
+    product, = re.findall(r"^.* convolution\(.*$", text, re.M)
+    # float32 sums of operands the compiler was told to keep whole
+    assert re.search(rf"= f32\[{d},{k}\]\S* convolution\(", product)
+    assert "operand_precision={highest,highest}" in product
+    # nothing in the program is narrower than float32 but the one-hot's
+    # own 0/1 (a predicate): no bfloat16 copy of a column that a table
+    # reads
+    assert not re.search(r"\b(bf16|f16|f8\w*)\[", text)
+    operands = re.search(r"convolution\(([^)]*)\)", product).group(1)
+    for name in (o.strip().split(" ")[-1] for o in operands.split(",")):
+        kind, = re.findall(rf"^\s*(?:ROOT )?{re.escape(name)} = (\w+)\[",
+                           text, re.M)
+        assert kind in ("f32", "pred"), (name, kind)
+    # neither the (n, labels) one-hot nor a copy of X is a buffer
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

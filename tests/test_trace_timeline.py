@@ -641,7 +641,7 @@ CROSSINGS = [
     ("typed_spans", "upload:stage:IntegralVectorizerModel",
      "stage:transform:IntegralVectorizer"),
     ("typed_spans", "upload:pivot", "stage:transform:OneHotVectorizer"),
-    ("typed_spans", "pull:sanity:matrix", "sanity:moments"),
+    ("typed_spans", "pull:sanity:contingency", "sanity:contingency"),
     ("typed_spans", "upload:sanity:sample", "sanity:moments"),
     ("typed_spans", "pull:sanity:moments", "sanity:moments"),
     ("typed_spans", "pull:sanity:corr", "sanity:corr"),
@@ -650,7 +650,7 @@ CROSSINGS = [
     ("typed_spans", "pull:tree:edges", "tree:edges"),
     ("multi_spans", "upload:stage:BinaryVectorizerModel",
      "stage:transform:BinaryVectorizer"),
-    ("multi_spans", "pull:sanity:matrix", "sanity:moments"),
+    ("multi_spans", "pull:sanity:contingency", "sanity:contingency"),
     ("multi_spans", "pull:sweep:logistic", "sweep:fetch:logistic"),
     ("multi_spans", "pull:sweep:forest", "sweep:fetch:forest"),
     ("multi_spans", "upload:evaluate:label", "selector:evaluate"),
@@ -681,6 +681,56 @@ def test_a_crossing_is_a_span_under_the_phase_that_makes_it(
     assert any(phase in chain for chain in chains)
     if phase.startswith("sweep:fetch:"):    # the fetch's own child
         assert {by_id[s.parent_id].name for s in mine} == {phase}
+
+
+@pytest.mark.parametrize("fixture", ["typed_spans", "multi_spans"])
+def test_the_checker_reads_tables_and_moments_never_the_matrix(
+        request, fixture):
+    spans = request.getfixturevalue(fixture)[1]
+    moments, = [s for s in spans if s.name == "sanity:moments"]
+    tables, = [s for s in spans if s.name == "sanity:contingency"]
+    # 300 rows, under the sample's lower limit: the column's own array
+    assert moments.attributes["sample"] == "whole"
+    assert tables.attributes["tables"] == "device"
+    assert tables.attributes["categorical_label"] is True
+    assert not [s for s in spans if s.name == "pull:sanity:matrix"]
+    width, = [s.attributes["encoded_width"] for s in spans
+              if s.name == "sanity:decide"]
+    pulled, = [s for s in spans if s.name == "pull:sanity:contingency"]
+    # as wide as an unforced label's most levels, not as this sample's
+    assert pulled.attributes["bytes"] == 4 * width * 30
+    codes, = [s for s in spans if s.name == "upload:sanity:label"]
+    assert codes.parent_id == tables.span_id
+    assert codes.attributes["bytes"] == 4 * 300
+
+
+def test_a_spearman_fit_still_reads_its_rows_under_a_span():
+    from transmogrifai_tpu import types as T
+    from transmogrifai_tpu.automl.sanity_checker import SanityChecker
+    rng = np.random.default_rng(6)
+    n = 2400
+    y = (rng.uniform(size=n) < 0.4).astype(np.float64)
+    ds = Dataset({"x": rng.normal(size=n) + y, "level": np.asarray(
+        ["a", "b", "c"], object)[rng.integers(0, 3, n)], "y": y},
+        {"x": T.Real, "level": T.PickList, "y": T.Integral})
+    preds, label = FeatureBuilder.from_dataset(ds, response="y")
+    checked = SanityChecker(
+        correlation_type="spearman", sample_upper_limit=1200).set_input(
+        label, transmogrify(preds)).get_output()
+    with TRACER.span("run:check-spearman", new_trace=True) as root:
+        Workflow().set_result_features(checked, label) \
+            .set_input_dataset(ds).train()
+    spans = TRACER.trace_spans(root.trace_id)
+    moments, = [s for s in spans if s.name == "sanity:moments"]
+    assert moments.attributes["sample"] == "device"
+    width, = [s.attributes["encoded_width"] for s in spans
+              if s.name == "sanity:decide"]
+    # the ranks are host pandas: the SAMPLED rows come down, no more
+    matrix, = [s for s in spans if s.name == "pull:sanity:matrix"]
+    assert matrix.parent_id == moments.span_id
+    assert matrix.attributes["bytes"] == 4 * 1200 * width
+    tables, = [s for s in spans if s.name == "sanity:contingency"]
+    assert tables.attributes["tables"] == "device"
 
 
 @pytest.mark.parametrize("fixture", ["train_spans", "typed_spans",

@@ -12,15 +12,19 @@ drop rules from `DerivedFeatureFilterUtils.scala:355-385`, defaults
 TPU-first: moments, label correlation and the feature-feature Gram matrix
 are ONE fused device pass over (n, d+1) — `Z^T Z` rides the MXU and every
 term is a row-axis sum (`psum`-ready under a data-sharded mesh). Spearman
-reuses the same pass over host-ranked columns. Contingency tables are
-one-hot × one-hot matmuls. Drop decisions (data-dependent shapes) resolve
-on host at fit time; the fitted model is a static-index column gather.
+reuses the same pass over host-ranked columns. The row sample is a device
+gather, and every categorical group's contingency table is a slice of ONE
+whole-number-exact product `X^T onehot(label)` on the device: the encoded
+matrix stays there, only (d,) moments, the Gram and a (d, labels) table
+cross. Drop decisions (data-dependent shapes) resolve on host at fit
+time; the fitted model is a static-index column gather.
 """
 
 from __future__ import annotations
 
 import logging
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -214,9 +218,10 @@ def _rank_transform(A: np.ndarray) -> np.ndarray:
     return pd.DataFrame(A).rank(method="average").to_numpy(dtype=np.float32)
 
 
-def _label_onehot(y: np.ndarray, max_card: int,
-                  force: Optional[bool] = None) -> Optional[np.ndarray]:
-    """One-hot label for contingency tests, or None if not categorical.
+def _label_codes(y: np.ndarray, max_card: int,
+                 force: Optional[bool] = None) -> Optional[Tuple[np.ndarray, int]]:
+    """(int32 code of every row's label level, number of levels) for the
+    contingency tests, or None if the label is not categorical.
     `force=True` treats the (rounded) label as categorical regardless of
     the integrality/cardinality heuristics (categoricalLabel param)."""
     if force is False:
@@ -224,14 +229,66 @@ def _label_onehot(y: np.ndarray, max_card: int,
     yi = np.round(y).astype(np.int64)
     if force is not True and not np.allclose(y, yi, atol=1e-6):
         return None
-    levels = np.unique(yi)
+    levels, codes = np.unique(yi, return_inverse=True)
     if len(levels) < 2 or (force is not True and len(levels) > max_card):
         return None
-    lut = {v: i for i, v in enumerate(levels.tolist())}
-    idx = np.array([lut[v] for v in yi.tolist()])
-    oh = np.zeros((len(y), len(levels)), dtype=np.float32)
-    oh[np.arange(len(y)), idx] = 1.0
-    return oh
+    return codes.astype(np.int32), len(levels)
+
+
+@jax.jit
+def _take_rows(X: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """The sampled rows, gathered where the matrix lives (`idx` sorted,
+    distinct and in range: `_sample_rows` draws it so)."""
+    return X.at[idx].get(mode="promise_in_bounds", unique_indices=True,
+                         indices_are_sorted=True)
+
+
+# rows one float32 count can take exactly: every whole number up to 2^24
+_COUNT_EXACT_ROWS = 1 << 24
+
+
+@partial(jax.jit, static_argnames=("width", "chunks"))
+def _label_counts(X: jnp.ndarray, codes: jnp.ndarray, width: int,
+                  chunks: int) -> jnp.ndarray:
+    """(d, chunks * width) float32: every column of X summed by the
+    label code (below `width`) of its row, `X^T onehot(codes)` as one
+    product contracted over the row axis (rows sharded → one `psum`, like
+    the Gram). The float32 operands enter at `Precision.HIGHEST` (whole)
+    and a one-hot operand picks each value of X out as it is, so a 0/1
+    column's sums are counts, exact in the float32 accumulator while they
+    stay under 2^24: the rows are cut into `chunks` runs no longer than
+    that, each summed into label columns of its own. The product is bound
+    by its one read of X, so the rows of columns nobody reads cost
+    nothing worth a gather."""
+    n = X.shape[0]
+    run = -(-n // chunks)
+    oh = jax.nn.one_hot(codes + (jnp.arange(n) // run) * width,
+                        chunks * width, dtype=X.dtype)
+    return jax.lax.dot_general(
+        X, oh, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _contingency_counts(X: jnp.ndarray, codes: np.ndarray, n_levels: int,
+                        max_card: int) -> np.ndarray:
+    """(d, n_levels) float64 table of every column of the device matrix
+    against the label: what `X.T.astype(float64) @ onehot` gives on the
+    host, to the bit for 0/1 columns. The table that crosses is as wide
+    as the next multiple of `max_card` (the most levels an unforced label
+    may have), not as the levels this sample happens to hold: a rare
+    label in or out of the sample compiles nothing, and the label lanes
+    of the product are padded far past that anyway. One shape, one path:
+    a sample of more than 2^24 rows (a raised `sample_upper_limit`) is
+    the same program with its rows in several runs, whose tables add here
+    in float64."""
+    step = max(max_card, 1)     # a forced label's levels ignore the limit
+    width = -(-n_levels // step) * step
+    chunks = max(1, -(-X.shape[0] // _COUNT_EXACT_ROWS))
+    counts = pull("sanity:contingency", _label_counts(
+        X, upload("sanity:label", codes), width, chunks))
+    return counts.astype(np.float64).reshape(
+        X.shape[1], chunks, width).sum(axis=1)[:, :n_levels]
 
 
 def cramers_v(contingency: np.ndarray) -> float:
@@ -382,31 +439,41 @@ class SanityChecker(Estimator):
 
     def fit_model(self, cols: Sequence[Column], ctx: FitContext) -> Transformer:
         # four phases, each a span under the stage's `stage:fit:*`:
-        # moments (the host pull of the vector, the sample, raw moments),
-        # corr (the Gram pass), contingency (categorical groups against
-        # the label), decide (the drop rules)
+        # moments (the row sample, raw moments), corr (the Gram pass),
+        # contingency (categorical groups against the label), decide
+        # (the drop rules)
         label_col, vec_col = cols
-        with TRACER.span("sanity:moments", category="sanity"):
+        with TRACER.span("sanity:moments", category="sanity") as sp:
             y_np = np.asarray(label_col.data["value"], dtype=np.float64)
-            X_np = pull("sanity:matrix", vec_col.device_value())
-            n_total = X_np.shape[0]
+            X_all = vec_col.device_value()
+            n_total = X_all.shape[0]
 
+            # the sample is taken where the matrix lives: a device
+            # gather of the sorted index (4 B a row go up), or a host
+            # fancy index of a host array
             sample_idx = self._sample_rows(n_total)
+            if sample_idx is None:
+                X_rows, sampled = X_all, "whole"
+            elif isinstance(X_all, jax.Array):
+                X_rows, sampled = _take_rows(X_all, upload(
+                    "sanity:sample", sample_idx.astype(np.int32))), "device"
+            else:
+                X_rows, sampled = X_all[sample_idx], "host"
+            sp.set(sample=sampled)
             if sample_idx is not None:
-                X_np = X_np[sample_idx]
                 y_np = y_np[sample_idx]
-            n, d = X_np.shape
+            X_dev = upload("sanity:sample", X_rows)
+            n, d = X_dev.shape
 
             # Spearman = Pearson over average-tie ranks (host rank
-            # transform feeding the identical device passes); `Cx/cy` are
-            # the correlation inputs, raw-X moments are reported in the
-            # stats either way
+            # transform feeding the identical device passes, the one
+            # caller that reads the sampled rows on the host); `Cx/cy`
+            # are the correlation inputs, raw-X moments are reported in
+            # the stats either way
             spearman = self.correlation_type == "spearman"
-            # unsampled, the vector is on the device already: no second copy
-            X_dev = upload("sanity:sample", vec_col.device_value()
-                           if sample_idx is None else X_np)
             if spearman:
-                Cx = upload("sanity:sample", _rank_transform(X_np))
+                Cx = upload("sanity:sample", _rank_transform(
+                    pull("sanity:matrix", X_rows)))
                 cy = upload("sanity:sample",
                             _rank_transform(y_np[:, None])[:, 0])
             else:
@@ -459,22 +526,26 @@ class SanityChecker(Estimator):
         group_stats: Dict[int, Tuple[str, Dict]] = {}
         cat_groups: List[CategoricalGroupStats] = []
         with TRACER.span("sanity:contingency", category="sanity") as sp:
-            oh = None if meta is None else _label_onehot(
+            coded = None if meta is None else _label_codes(
                 y_np, self.categorical_label_max_card,
                 force=self.categorical_label)
-            # False: a label of too many (or fractional) values, every
-            # decision is from the moments and the label correlations
-            sp.set(categorical_label=oh is not None)
-            if oh is not None:
-                groups: Dict[str, List[int]] = {}
+            # no label levels: a label of too many (or fractional)
+            # values, every decision is from the moments and the label
+            # correlations
+            groups: Dict[str, List[int]] = {}
+            if coded is not None:
                 for i, c in enumerate(meta.columns):
                     if c.indicator_value is not None:
                         groups.setdefault(c.grouping_key(), []).append(i)
                 sp.set(groups=len(groups))
-                Xh = X_np  # the sampled host matrix (no device round-trip)
+            sp.set(categorical_label=coded is not None,
+                   tables="device" if groups else "none")
+            if groups:
+                # the rows the moments and the Gram saw, every column
+                counts = _contingency_counts(
+                    X_dev, *coded, self.categorical_label_max_card)
                 for key, idxs in groups.items():
-                    cont = Xh[:, idxs].T.astype(np.float64) @ oh
-                    cs = contingency_stats(cont)
+                    cs = contingency_stats(counts[idxs])
                     cat_groups.append(CategoricalGroupStats(
                         group=key, cramers_v=cs["cramers_v"],
                         mutual_info=cs["mutual_info"],
