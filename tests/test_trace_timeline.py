@@ -354,6 +354,10 @@ def test_sweep_dispatches_are_spans_under_the_block(family):
     # padded depth's leaf count through `trees.leaf_sums_form`)
     assert {s.attributes.get("leaf_sums") for s in dispatches} == {
         None if family == "logistic" else "product"}
+    # and how many levels take their histograms by sibling subtraction
+    # (`trees.hist_subtract_levels`: none in a depth-2 tree)
+    assert {s.attributes.get("hist_subtract") for s in dispatches} == {
+        None if family == "logistic" else 0}
     # a first dispatch's compile is a child of the dispatch
     compiles = [s for s in spans if s.name.startswith(
         f"compile:sweep:dispatch:{family}/")]
@@ -617,6 +621,32 @@ def test_an_estimators_own_binning_states_its_leaf_sums(depth, classes,
     assert edges.attributes["leaf_sums"] == form
 
 
+@pytest.mark.parametrize("est,classes,levels", [
+    (lambda: OpRandomForestClassifier(n_trees=1, max_depth=12, max_bins=8,
+                                      n_classes=23), 23, 11),
+    (lambda: OpRandomForestClassifier(n_trees=1, max_depth=12, max_bins=8,
+                                      n_classes=2), 2, 8),
+    (lambda: trees.OpRandomForestRegressor(n_trees=1, max_depth=12,
+                                           max_bins=8), 0, 0),
+    (lambda: OpXGBoostClassifier(n_estimators=2, max_depth=10, max_bins=8),
+     0, 0),
+], ids=["classifier-k23", "classifier-k2", "regressor", "boosted"])
+def test_an_estimators_own_binning_states_its_subtracted_levels(
+        est, classes, levels, monkeypatch):
+    # the count `hist_subtract_levels` gives at the estimator's own depth
+    # and classes: a regressor's and a boosted round's signed values stay
+    # direct in the default bf16 mode
+    monkeypatch.setattr(trees, "HIST_PRECISION", "bf16")
+    X = jnp.asarray(np.random.default_rng(0).normal(size=(32, 2)),
+                    jnp.float32)
+    with TRACER.span("run:edges", new_trace=True) as root:
+        est()._edges_binned(X, FitContext(n_rows=32, seed=1),
+                            n_classes=classes)
+    edges, = [s for s in TRACER.trace_spans(root.trace_id)
+              if s.name == "tree:edges"]
+    assert edges.attributes["hist_subtract"] == levels
+
+
 # --------------------------------------------------------------------- #
 # D3. the host-device boundary: every crossing a span under its phase   #
 # --------------------------------------------------------------------- #
@@ -856,6 +886,27 @@ def _lower_grow_tree_classes_two_blocks():
         trees.hist_layout(np.asarray([False, True, True])))
 
 
+def _lower_grow_tree_classes_deep(layout=None):
+    """A 3-class tree of depth 6: levels 4 and 5 by sibling subtraction
+    (`trees.hist_subtract_levels`)."""
+    Xb, _, H = _tree_inputs()
+    y = jnp.asarray(np.arange(64) % 3, jnp.int32)
+    return jax.jit(lambda a, g, h, lay: trees.grow_tree(
+        a, g, h, 6, 4, layout=lay, n_classes=3)).lower(Xb, y, H, layout)
+
+
+def _lower_grow_tree_classes_deep_two_blocks():
+    return _lower_grow_tree_classes_deep(
+        trees.hist_layout(np.asarray([False, True, True])))
+
+
+def _lower_forest_regressor_depth12():
+    """A regression forest padded to depth 12, as `airlines.train`'s."""
+    Xb, G, H = _tree_inputs()
+    return trees.fit_forest.lower(Xb, G, H, n_trees=1, max_depth=12,
+                                  n_bins=4, n_outputs=1, seed=0)
+
+
 def _lower_multiclass_metric(batch=None):
     """The weighted F1's program (the confusion product inside it); with
     `batch`, under the sweep's vmap over predictions and fold masks."""
@@ -989,6 +1040,9 @@ SCOPES = [
     ("tree:leaf:product", _lower_grow_tree_classes),
     ("tree:leaf:product", _lower_forest),
     ("tree:leaf:scatter", _lower_grow_tree_depth14),
+    ("tree:hist:subtract", _lower_grow_tree_classes_deep),
+    ("tree:hist:subtract", _lower_grow_tree_classes_deep_two_blocks),
+    ("tree:hist:classes", _lower_grow_tree_classes_deep),
 ]
 
 
@@ -1058,6 +1112,31 @@ def test_a_tree_program_holds_no_scatter_where_the_rule_says_product(lower):
     assert not re.search(r'tree:leaf[^"\n]*scatter', text)
     assert not [line for line in lowered.compile().as_text().splitlines()
                 if re.search(r"\bscatter\(", line) and "tree:leaf" in line]
+
+
+@pytest.mark.parametrize("lower", [
+    _lower_grow_tree_depth14, _lower_forest_regressor_depth12,
+    _lower_gbt_chunk, _lower_grow_tree_classes],
+    ids=["regressor-depth14", "regression-forest-depth12", "gbt-chunk",
+         "classifier-depth2"])
+def test_signed_values_and_shallow_trees_take_no_subtraction(lower,
+                                                             monkeypatch):
+    # in the default bf16 mode a regressor's and a boosted round's
+    # gradient histograms stay direct at every depth (a deep node's
+    # parent − right would cancel bf16-rounded terms), and a tree whose
+    # levels all sit under a tile has nothing to subtract
+    monkeypatch.setattr(trees, "HIST_PRECISION", "bf16")
+    assert "tree:hist:subtract" not in lower().as_text(debug_info=True)
+
+
+def test_a_deep_classifiers_program_multiplies_only_the_right_children():
+    # depth 6, K = 3, one block: levels 0-3 direct (3 · 2^level A-side
+    # rows), levels 4 and 5 a product over the right children (3 · 8 and
+    # 3 · 16 rows), then the leaves' one product
+    text = _lower_grow_tree_classes_deep().as_text()
+    rows = [int(r) for r in re.findall(
+        r"stablehlo\.dot_general[^\n]*-> tensor<(\d+)x", text)]
+    assert rows == [3, 6, 12, 24, 24, 48, 64], rows
 
 
 def test_a_tree_program_past_the_crossover_scatters_its_leaves():

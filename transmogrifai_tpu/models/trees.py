@@ -257,6 +257,11 @@ def hist_operand(Xb: jnp.ndarray, n_bins: int,
 #     true f32 even where the platform runs plain f32 matmuls at bf16) —
 #     the reference bar (MLlib/XGBoost exact f32/f64 scatter histograms)
 #     at roughly 1/4-1/8 the MXU throughput.
+# Sibling subtraction (`hist_subtract_levels`) keys on the values, not on
+# this switch alone: a classifier's class counts subtract in either mode
+# (whole numbers, exact in bf16 and in f32 sums), signed gradient
+# histograms only under "f32" (in bf16 a deep node's parent − right is a
+# cancellation of bf16-rounded terms).
 # Process-level switch: TRANSMOGRIFAI_HIST_PRECISION=f32, read ONCE at
 # import. jax.jit caches executables by shape/static-args only, so
 # mutating this global (or the env var) after fit functions have traced
@@ -572,8 +577,98 @@ def _leaf_sums(node_idx, G, H, max_nodes: int, cls=None, n_classes: int = 0):
         return leaf_g, leaf_g.sum(1)
 
 
-# Depth at which sibling subtraction starts paying (see grow_tree doc)
-_SUBTRACT_MIN_DEPTH = 12
+# How many A-side rows (classes x parent nodes) a level's right-children
+# product has to reach before taking the level as parent - right pays:
+# one bfloat16 tile of rows (`hist_subtract_levels`, where the chip times
+# behind the number are).
+_SUBTRACT_MIN_ROWS = 16
+
+
+def hist_subtract_levels(pad_depth: int, n_classes: int = 0
+                         ) -> Tuple[int, ...]:
+    """The levels of a `pad_depth` tree whose histograms `grow_tree`
+    takes by sibling subtraction, from static shapes alone (the span
+    attribute `hist_subtract` counts them; the scope is
+    `tree:hist:subtract`): each such level multiplies only the rows that
+    went RIGHT, grouped by parent, and a left child's histograms are its
+    parent's less its sibling's — XGBoost's and LightGBM's identity.
+
+    Which values allow it. A classifier's composite form (`n_classes` >
+    0) sums ONE non-negative weight a row; a bootstrap's Poisson count
+    under a 0/1 fold mask is a whole number, exact in bfloat16, so every
+    class-histogram cell is an integer below 2^24 in float32 and parent −
+    right is the direct histogram bit for bit, in either precision mode
+    (fractional weights: the same bfloat16 weights summed in another
+    float32 order, a few ulps of the parent's cell). A regressor's or a
+    boosted round's SIGNED gradients stay direct under the default
+    HIST_PRECISION "bf16": a deep node's subtracted gradient histogram is
+    a big-minus-big cancellation whose error scales with the parent's
+    bfloat16-rounded terms, not the node's own (the depth-12-padded
+    boosted sweep lost ~0.005 CV AuPR to it). Under "f32" any form
+    subtracts: the cancellation sits at float32 rounding.
+
+    Which shapes make it pay. A level's product is (rows, n) @ (n,
+    slots) with the bin one-hot generated inside it; the subtraction adds
+    one pass over the level's float32 histograms. Timed on one TPU v5e,
+    one level of 1,800,000 rows × 1,042 slots (30 columns of 32 bins, 41
+    of 2) at K = 23, ms, best of five (level 11: the product of a
+    depth-12 tree's deepest level, read off a traced training pass):
+
+      level   A-side rows, direct / right   direct   by subtraction
+        1            46 /     23             20.67        10.47
+        2            92 /     46             23.90        10.62
+        3           184 /     92             30.73        10.66
+        4           368 /    184             44.46        12.25
+        5           736 /    368             73.28        16.22
+        6         1,472 /    736             75.69        23.37
+       11        47,104 / 23,552            919          441
+
+    The right-children product wins at every level, the smallest
+    included, and takes under half the direct one's time where both
+    hold the same rows (the direct product's 46 rows 20.67 ms, the
+    right one's 46 rows 10.62). Under one bfloat16 tile of A-side rows
+    a product pads its rows to the tile and halving them saves nothing,
+    so a level subtracts where its right-children product holds at
+    least `_SUBTRACT_MIN_ROWS` rows: classes × 2^(level − 1), or
+    2^(level − 1) for each product of the per-value-column form (a
+    binary forest from level 4). A whole depth-12 tree, same chip, best
+    of three: 1,800,000 × 1,042 slots at K = 23 2.155 s direct, 1.152 s
+    by subtraction (levels 1–11); 2,160,000 × 896 slots at K = 2 0.239 s
+    and 0.156 s (levels 4–11); both forms grew the same trees bit for
+    bit. The binary shapes were not timed level by level, nor 900,000 ×
+    1,446 slots at all."""
+    if not n_classes and HIST_PRECISION != "f32":
+        return ()
+    rows = max(int(n_classes), 1)
+    return tuple(level for level in range(1, int(pad_depth))
+                 if rows * 2 ** (level - 1) >= _SUBTRACT_MIN_ROWS)
+
+
+def tree_span_attrs(pad_depth: int, n_classes: int = 0) -> Dict:
+    """What a program of `pad_depth`-level trees does, as span attributes
+    (`sweep:dispatch:forest`, `sweep:dispatch:gbt`, `tree:edges`):
+    `leaf_sums`, the form of its leaf sums (`leaf_sums_form`), and
+    `hist_subtract`, how many levels take their histograms by sibling
+    subtraction (`hist_subtract_levels`). `n_classes`: a classifier
+    forest's K, else 0."""
+    return {"leaf_sums": leaf_sums_form(2 ** int(pad_depth), 1, n_classes),
+            "hist_subtract": len(hist_subtract_levels(pad_depth, n_classes))}
+
+
+@jax.named_scope("tree:hist:subtract")
+def _subtract_siblings(parent, right, classes: bool):
+    """A level's (hg, hh) of one operand block from its parents' and its
+    right children's (grouped by parent): left 2k = parent k − right,
+    right 2k + 1, interleaved. A classifier's hh is the class sum of hg,
+    as `_class_histograms` returns it."""
+    (hg, hh), (hg_r, hh_r) = parent, right
+    m, n_parents = hg.shape[:2]
+    hg = jnp.stack([hg - hg_r, hg_r], axis=2).reshape(
+        m, 2 * n_parents, *hg.shape[2:])
+    if classes:
+        return hg, hg.sum(0)
+    return hg, jnp.stack([hh - hh_r, hh_r], axis=1).reshape(
+        2 * n_parents, *hh.shape[1:])
 
 
 def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
@@ -611,25 +706,13 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
     `B`: the histogram operand (`hist_operand`), built here from `layout`
     when the caller does not share one across trees or rounds.
 
-    Deep trees (max_depth ≥ `_SUBTRACT_MIN_DEPTH`) in EXACT-histogram
-    mode (TRANSMOGRIFAI_HIST_PRECISION=f32) use HISTOGRAM SUBTRACTION —
-    the standard XGBoost/LightGBM hist trick: per level, compute
-    histograms only for rows routed RIGHT (grouped by parent) and derive
-    the left child as parent − right. This halves the histogram-matmul
-    A-side columns and FLOPs; it can only pay off once per-level
-    matmuls span multiple MXU output tiles (shallower levels are bound
-    by streaming the bin one-hot operand, where fewer output columns
-    save nothing) — hence the depth gate; the crossover depth has not
-    been re-measured on a directly attached chip. It is DISABLED in the
-    default bf16 mode: a deep small node's subtracted histogram is a
-    big-minus-big cancellation whose absolute error scales with the
-    PARENT's magnitude, not the node's own — the depth-12-padded XGB
-    sweep lost ~0.005 CV AuPR to it (enough to flip the bench's model
-    selection), a genuine quality
-    regression rather than the benign per-node bf16 tie noise of direct
-    histograms. With f32 (HIGHEST) histograms the cancellation error
-    sits at f32 rounding and the trick is sound — which is exactly why
-    LightGBM subtracts in full precision.
+    Every level has its full histograms: the root and the levels before
+    `hist_subtract_levels` as one direct product, the levels it names by
+    sibling subtraction from the level before (a product over the rows
+    that went right, half the A-side rows, and `_subtract_siblings`).
+    A classifier's trees are then the direct form's bit for bit wherever
+    its weights are whole numbers; the rule and the chip times behind it
+    are in `hist_subtract_levels`.
     """
     n, d = Xb.shape
     cls = None
@@ -652,13 +735,10 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
         return [_histograms(Bk, node, Gv, Hv, n_nodes, name)
                 for name, _, Bk in B]
 
-    subtract = max_depth >= _SUBTRACT_MIN_DEPTH and HIST_PRECISION == "f32"
-    if subtract:
-        hists = histograms(node_idx, G, H, 1)
-
+    subtract = hist_subtract_levels(max_depth, 0 if cls is None else m)
     for level in range(max_depth):
         n_nodes = 2 ** level
-        if not subtract:
+        if level not in subtract:
             hists = histograms(node_idx, G, H, n_nodes)
         bf, bb = _split_from_blocks(
             B, hists, n_bins, reg_lambda, min_child_weight, min_gain,
@@ -673,19 +753,13 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
             sample_bin = _select_bin(Xb, sample_feat)
             go_right = sample_bin > split_bin
             node_idx = node_idx * 2 + go_right.astype(jnp.int32)
-        if subtract and level + 1 < max_depth:
+        if level + 1 in subtract:
             right = go_right.astype(jnp.float32)
-            # interleave children: node k → (left 2k = parent − right,
-            # right 2k+1)
-            hists = [
-                (jnp.stack([hg - hg_r, hg_r], axis=2).reshape(
-                    m, 2 * n_nodes, *hg.shape[2:]),
-                 jnp.stack([hh - hh_r, hh_r], axis=1).reshape(
-                     2 * n_nodes, *hh.shape[1:]))
-                for (hg, hh), (hg_r, hh_r) in zip(hists, histograms(
-                    node_idx >> 1,
-                    None if cls is not None else G * right[:, None],
-                    H * right, n_nodes))]
+            hists = [_subtract_siblings(parent, right_hists, cls is not None)
+                     for parent, right_hists in zip(hists, histograms(
+                         node_idx >> 1,
+                         None if cls is not None else G * right[:, None],
+                         H * right, n_nodes))]
 
     leaf_g, leaf_h = _leaf_sums(node_idx, G, H, max_nodes, cls, n_classes)
     # L1 (alpha) soft-thresholds the leaf numerator (XGBoost leaf formula)
@@ -1586,8 +1660,7 @@ class _TreeEstimatorBase(PredictorEstimator):
                          max_bins=self.max_bins, edges=edges_site(X),
                          value_columns=n_classes or 1,
                          hist_reads=hist_reads(n_classes),
-                         leaf_sums=leaf_sums_form(
-                             2 ** int(self.max_depth), 1, n_classes)):
+                         **tree_span_attrs(self.max_depth, n_classes)):
             indicator = indicator_columns(X)
             edges = quantile_bin_edges(X, self.max_bins, indicator)
             out = (edges, bin_features(upload("tree:bin", X),

@@ -72,8 +72,7 @@ from transmogrifai_tpu.models.trees import (
     forest_classification_pred, forest_regression_pred,
     gbt_base_score, gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
     gbt_train_summary, edges_site, hist_layout, hist_reads, hist_slots,
-    leaf_sums_form,
-    indicator_columns, quantile_bin_edges)
+    indicator_columns, quantile_bin_edges, tree_span_attrs)
 from transmogrifai_tpu.runtime.faults import (
     SITE_RUN_BLOCK, fault_point, is_oom_error)
 
@@ -1102,9 +1101,8 @@ def _sweep_forest(est, grids, X, y, W, V, metric_fn, ctx, sharding,
         host_dispatch=True,
         pair_width=lambda st, idxs, k: width_of(st, idxs),
         x_info=_x_info(X),
-        dispatch_attrs=lambda st, idxs: {"leaf_sums": leaf_sums_form(
-            2 ** _pad_depth_of(est, grids, idxs), 1,
-            0 if regression else n_out)})
+        dispatch_attrs=lambda st, idxs: tree_span_attrs(
+            _pad_depth_of(est, grids, idxs), 0 if regression else n_out))
 
 
 @functools.lru_cache(maxsize=_HELD_PROGRAMS)
@@ -1222,8 +1220,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             host_dispatch=sharding is None,
             pair_width=lambda st, idxs, k: width_of(st, idxs),
             x_info=_x_info(X),
-            dispatch_attrs=lambda st, idxs: {"leaf_sums": leaf_sums_form(
-                2 ** _pad_depth_of(est, grids, idxs))})
+            dispatch_attrs=lambda st, idxs: tree_span_attrs(
+                _pad_depth_of(est, grids, idxs)))
 
     # ---- single-device binary/squared: ROUND-CHUNKED host dispatch ---- #
     # Each dispatch runs `rpd` boosting rounds for `width` vmapped
@@ -1293,8 +1291,7 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                                     pairs=min(width, n_pairs - s),
                                     pad_depth=pad_depth,
                                     objective=objective,
-                                    leaf_sums=leaf_sums_form(
-                                        2 ** pad_depth)):
+                                    **tree_span_attrs(pad_depth)):
                     margin, best, since = jax.block_until_ready(
                         prog(data, dchunk, Wsel, Vsel, margin, best, since,
                              ks))
