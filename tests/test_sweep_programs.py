@@ -141,6 +141,10 @@ CASES = {
         lambda: OpXGBoostClassifier(n_estimators=2, max_bins=8,
                                     early_stopping_rounds=0),
         [{"max_depth": 2}, {"max_depth": 3}], "binary", BINARY),
+    "mesh-boosted-multiclass": (
+        lambda: OpXGBoostClassifier(n_estimators=2, max_bins=8,
+                                    early_stopping_rounds=0),
+        [{"max_depth": 2}, {"max_depth": 3}], "multiclass", MULTI),
     "mesh-boosted-binary-typed": (
         lambda: OpXGBoostClassifier(n_estimators=2, max_bins=8,
                                     early_stopping_rounds=0),

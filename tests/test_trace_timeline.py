@@ -647,6 +647,71 @@ def test_an_estimators_own_binning_states_its_subtracted_levels(
     assert edges.attributes["hist_subtract"] == levels
 
 
+@pytest.fixture(scope="module")
+def softmax_spans():
+    """One tiny multiclass train of a softmax boosted family (K = 4)
+    under a root span: (root, spans)."""
+    from transmogrifai_tpu import types as T
+    from transmogrifai_tpu.selector import MultiClassificationModelSelector
+    rng = np.random.default_rng(5)
+    n = 240
+    y = rng.integers(0, 4, n).astype(np.float64)
+    ds = Dataset({"a": rng.normal(size=n) + y, "b": rng.normal(size=n) - y,
+                  "y": y}, {"a": T.Real, "b": T.Real, "y": T.Integral})
+    preds, label = FeatureBuilder.from_dataset(ds, response="y")
+    sel = MultiClassificationModelSelector.with_cross_validation(
+        models=[(OpXGBoostClassifier(n_estimators=3, max_bins=8),
+                 [{"max_depth": 2}, {"max_depth": 3}])],
+        n_folds=2, n_classes=4)
+    pf = sel.set_input(label, transmogrify(preds)).get_output()
+    with TRACER.span("run:train-softmax", new_trace=True) as root:
+        Workflow().set_result_features(pf, label) \
+            .set_input_dataset(ds).train()
+    return root, TRACER.trace_spans(root.trace_id)
+
+
+def test_a_softmax_dispatch_states_its_chain(softmax_spans):
+    """The round-chunked loop's dispatches of a softmax chain: the
+    objective, the classes, the rounds and real pairs, the padded depth
+    and the trees' forms, as a binary chain's dispatches state theirs."""
+    _, spans = softmax_spans
+    disp = [s.attributes for s in spans if s.name == "sweep:dispatch:gbt"]
+    assert disp
+    for at in disp:
+        assert at["objective"] == "softmax" and at["classes"] == 4
+        assert at["pad_depth"] == 4 and at["rounds"] >= 1
+        assert at["leaf_sums"] == "product" and at["hist_subtract"] == 0
+    # every round of every (configuration, fold) chain, once
+    assert sum(at["rounds"] * at["pairs"] for at in disp) == 3 * 2 * 2
+
+
+def test_a_softmax_fetch_states_each_chains_cross_entropy(softmax_spans):
+    _, spans = softmax_spans
+    fetch = [s.attributes for s in spans if s.name == "sweep:fetch:gbt"]
+    pairs = sorted((g, f) for at in fetch
+                   for g, f in zip(at["grids"], at["folds"]))
+    assert pairs == [(0, 0), (0, 1), (1, 0), (1, 1)]
+    losses = [v for at in fetch for v in at["train_loss"]]
+    # 3 rounds from the uniform start: under log K, above 0
+    assert all(0 < v < np.log(4) for v in losses)
+    # a fold's training rows: the other fold's validation rows, both
+    # configurations' chains alike
+    weight = {(g, f): w for at in fetch for g, f, w in zip(
+        at["grids"], at["folds"], at["train_weight"])}
+    total = weight[0, 0] + weight[0, 1]
+    assert weight[1, 0] + weight[1, 1] == total
+    assert all(0.4 * total < w < 0.6 * total for w in weight.values())
+
+
+def test_a_softmax_chain_is_no_single_program_dispatch(softmax_spans):
+    """No dispatch of the boosted family's one-program form (the mesh's)
+    ran on one device: every one is a chunk of rounds."""
+    _, spans = softmax_spans
+    compiles = [s.name for s in spans
+                if s.name.startswith("compile:sweep:dispatch:gbt/")]
+    assert compiles and all("chunk_pair" in c for c in compiles)
+
+
 # --------------------------------------------------------------------- #
 # D3. the host-device boundary: every crossing a span under its phase   #
 # --------------------------------------------------------------------- #
@@ -1430,7 +1495,8 @@ def test_an_older_reader_reads_the_same_with_the_transfer_spans(
     monkeypatch.syspath_prepend(os.path.join(ROOT, "benchmark"))
     read = _reader(name)
     cfg = ("criteo" if "typed" in name else "kddcup99" if "multi" in name
-           else "airlines" if "reg" in name else "higgs")
+           else "airlines" if "reg" in name
+           else "dionis" if "softmax" in name else "higgs")
     with open(os.path.join(ROOT, "benchmark", "configs",
                            cfg + ".json")) as fh:
         config = json.load(fh)

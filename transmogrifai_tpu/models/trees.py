@@ -1038,8 +1038,17 @@ def _gbt_val_loss(margin, y, val_w, objective: str,
     (`DefaultSelectorParams.scala:71` BinaryClassXGBEvaluationMetric), so
     the stopping round matches reference semantics; an exact sorted AuPR
     would serialize on TPU every round, the binned histogram stays on
-    the MXU (90k × 512 bf16 ≈ 0.1 GFLOP/round)."""
+    the MXU (90k × 512 bf16 ≈ 0.1 GFLOP/round).
+
+    "softmax" (an (n, K) margin) is the weighted multiclass log-loss
+    whatever `eval_metric` says: XGBoost's `mlogloss`, the default eval
+    of `multi:softprob` (a binned AuPR has no K-class form)."""
     vs = jnp.maximum(val_w.sum(), 1.0)
+    if objective == "softmax":
+        logp = jax.nn.log_softmax(margin, axis=1)
+        ll = -(jax.nn.one_hot(y.astype(jnp.int32), margin.shape[1],
+                              dtype=logp.dtype) * logp).sum(1)
+        return (ll * val_w).sum() / vs
     if objective == "logistic" and eval_metric == "aupr":
         nb = 512
         p = jax.nn.sigmoid(margin)
@@ -1067,7 +1076,9 @@ def gbt_base_score(y, w, objective: str):
     """Where a boosted chain starts: for squared loss the weighted mean
     of the target (the constant that minimises it, so the 20 rounds of a
     `learning_rate` 0.1 chain are not spent walking from 0 to a target
-    whose mean is far from it), 0 for the logistic margin. One rule for
+    whose mean is far from it), 0 for the logistic margin and for every
+    class of a softmax one (XGBoost's `base_score` 0.5 is a probability:
+    its margin is 0 for `multi:softprob`). One rule for
     the sweep's chains, the refit and the model's prediction
     (`GBTRegressionModel.base_score`)."""
     if objective != "squared":
@@ -1077,14 +1088,31 @@ def gbt_base_score(y, w, objective: str):
 
 def gbt_train_summary(margin, y, w, objective: str) -> Dict:
     """What a finished chain says of the rows it was fitted on:
-    `train_loss`, the objective's own loss (mean squared error, or the
-    logistic loss) of its final margin under its training weights, and
+    `train_loss`, the objective's own loss (mean squared error, the
+    logistic loss, or a softmax chain's cross-entropy over its K classes)
+    of its final margin under its training weights, and
     `train_weight`, their sum. The sweep puts both on a fold chain's
     `sweep:fetch:gbt` span: beside the validation metric they show what
     the chain fitted and how far it got (two scalars a chain; no row
     crosses to the host)."""
     return {"train_loss": _gbt_val_loss(margin, y, w, objective),
             "train_weight": w.sum()}
+
+
+def gbt_grad_hess(margin, y, w, objective: str):
+    """A round's gradients and hessians of the objective at `margin`,
+    times the row weights: (n,) each, or (n, K) for "softmax"
+    (p − onehot(y) and max(p(1 − p), 1e-6) of p = softmax(margin))."""
+    if objective == "softmax":
+        p = jax.nn.softmax(margin, axis=1)
+        Y = jax.nn.one_hot(y.astype(jnp.int32), margin.shape[1],
+                           dtype=p.dtype)
+        return ((p - Y) * w[:, None],
+                jnp.maximum(p * (1.0 - p), 1e-6) * w[:, None])
+    if objective == "logistic":
+        p = jax.nn.sigmoid(margin)
+        return (p - y) * w, jnp.maximum(p * (1 - p), 1e-6) * w
+    return (margin - y) * w, w  # squared error
 
 
 def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
@@ -1100,16 +1128,17 @@ def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
     the scan returns is the early-stopped model even though the scan's
     length is static (XGBoost semantics: stop adding trees once the eval
     metric hasn't improved for N rounds,
-    `XGBoostParams.scala numEarlyStoppingRounds`)."""
+    `XGBoostParams.scala numEarlyStoppingRounds`).
+
+    `objective` "softmax" is XGBoost's `multi:softprob`: the margin is
+    (n, K) (K from its shape), and a round grows K trees, one a class,
+    from the multinomial gradients p − onehot(y) and hessians
+    max(p(1 − p), 1e-6) of p = softmax(margin), all K from the round's
+    one row sample and feature mask and the one histogram operand; the
+    round's trees are stacked (K, ...) and a chain's (rounds, K, ...)."""
     n, d = Xb.shape
     B = hist_operand(Xb, n_bins, layout)  # shared across all rounds
     esr = int(early_stopping_rounds)
-
-    def grads(margin):
-        if objective == "logistic":
-            p = jax.nn.sigmoid(margin)
-            return (p - y) * w, jnp.maximum(p * (1 - p), 1e-6) * w
-        return (margin - y) * w, w  # squared error
 
     def round_(carry, key):
         margin, best, since = carry
@@ -1117,18 +1146,28 @@ def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
         # uniform draws in [0,1): rate 1.0 keeps everything (no-op default)
         rows = (jax.random.uniform(k1, (n,)) < subsample).astype(jnp.float32)
         fmask = jax.random.uniform(k2, (d,)) < colsample
-        g, h = grads(margin)
-        tree = grow_tree(Xb, (-g * rows)[:, None], h * rows, max_depth,
-                         n_bins, reg_lambda=reg_lambda,
-                         min_child_weight=min_child_weight,
-                         min_gain=gamma, min_gain_norm=min_gain_norm,
-                         feature_mask=fmask,
-                         active_depth=active_depth, alpha=alpha, B=B)
+        g, h = gbt_grad_hess(margin, y, w, objective)
+
+        def one_tree(g, h):
+            return grow_tree(Xb, (-g * rows)[:, None], h * rows, max_depth,
+                             n_bins, reg_lambda=reg_lambda,
+                             min_child_weight=min_child_weight,
+                             min_gain=gamma, min_gain_norm=min_gain_norm,
+                             feature_mask=fmask,
+                             active_depth=active_depth, alpha=alpha, B=B)
+
+        def update(tree):
+            return _leaf_lookup(tree["leaf"][:, 0], _tree_walk(tree, Xb))
+
+        if objective == "softmax":      # one tree a class
+            tree = jax.vmap(one_tree, in_axes=(1, 1))(g, h)
+            step = lambda t: jax.vmap(update)(t).T  # noqa: E731
+        else:
+            tree, step = one_tree(g, h), update
         if esr > 0:
             live = (since < esr).astype(jnp.float32)
             tree["leaf"] = tree["leaf"] * live
-        margin = margin + learning_rate * _leaf_lookup(
-            tree["leaf"][:, 0], _tree_walk(tree, Xb))
+        margin = margin + learning_rate * step(tree)
         if esr > 0:
             m = _gbt_val_loss(margin, y, val_w, objective, eval_metric)
             improved = m < best - 1e-7
@@ -1140,18 +1179,29 @@ def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
     return jax.lax.scan(round_, (margin0, best0, since0), keys)
 
 
+def gbt_margin0(y, w, objective: str, n_classes: int = 0):
+    """Where a chain's margin starts: `gbt_base_score` at every row, an
+    (n, `n_classes`) margin for "softmax"."""
+    n = y.shape[0]
+    if objective == "softmax":
+        return jnp.zeros((n, n_classes), jnp.float32)
+    return jnp.full(n, gbt_base_score(y, w, objective), jnp.float32)
+
+
 @partial(jax.jit, static_argnames=("n_estimators", "max_depth", "n_bins",
                                    "objective", "early_stopping_rounds",
-                                   "eval_metric"))
+                                   "eval_metric", "n_classes"))
 def fit_gbt(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
             learning_rate, reg_lambda, objective: str = "logistic",
             min_child_weight: float = 1.0, active_depth=None,
             gamma=0.0, alpha=0.0, subsample=1.0, colsample=1.0, seed=0,
             val_w=None, early_stopping_rounds: int = 0, min_gain_norm=0.0,
-            eval_metric: str = "logloss", layout: Optional[Dict] = None):
+            eval_metric: str = "logloss", layout: Optional[Dict] = None,
+            n_classes: int = 0):
     """Returns (trees, final_margin): the scan carry already holds the full
     training-matrix margin, so sweep callers need not re-walk the forest.
-    The chain starts at `gbt_base_score` (the margin includes it).
+    The chain starts at `gbt_base_score` (the margin includes it);
+    "softmax" boosts `n_classes` margins (`_gbt_scan`).
 
     XGBoost param surface (OpXGBoostClassifier.scala / XGBoostParams.scala):
     `gamma` = min split gain, `alpha` = leaf L1, `subsample` = per-round
@@ -1164,8 +1214,7 @@ def fit_gbt(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
         early_stopping_rounds = 0
     keys = jax.random.split(jax.random.PRNGKey(seed), n_estimators)
     (margin, _, _), trees = _gbt_scan(
-        Xb, y, w, val_w,
-        jnp.full(n, gbt_base_score(y, w, objective), jnp.float32),
+        Xb, y, w, val_w, gbt_margin0(y, w, objective, n_classes),
         jnp.float32(jnp.inf),
         jnp.int32(0), keys, max_depth, n_bins, learning_rate, reg_lambda,
         objective, min_child_weight, active_depth, gamma, alpha, subsample,
@@ -1189,7 +1238,8 @@ def fit_gbt_chunk(Xb, y, w, val_w, margin, best, since, keys,
     `rounds_per_dispatch` slice of the key array and stops dispatching
     entirely once every vmapped pair reports `since >= early_stopping_
     rounds` — real compute savings on top of the in-scan masking, which
-    one 200-round program could only zero out, not skip.
+    one 200-round program could only zero out, not skip. A "softmax"
+    chunk carries the (n, K) margin.
     Returns ((margin, best, since), trees_chunk)."""
     return _gbt_scan(Xb, y, w, val_w, margin, best, since, keys,
                      max_depth, n_bins, learning_rate, reg_lambda, objective,
@@ -1214,6 +1264,9 @@ def _pick_rounds_per_dispatch(n_estimators: int, ideal: int) -> int:
 # chip run and have not been re-measured since (ROADMAP).
 _PAIR_MEM_BYTES = 4 << 30
 _DISPATCH_UNITS = 2.5e13
+# A softmax dispatch's own bytes, counted without the over-count above:
+# half of a v5e's 16 GiB, the rest left to the pass's other arrays.
+_SOFTMAX_DISPATCH_BYTES = 8 << 30
 
 
 def _pow2_floor(x: int) -> int:
@@ -1222,7 +1275,8 @@ def _pow2_floor(x: int) -> int:
 
 def dispatch_plan(n_rows: int, slots: int, pad_depth: int, learners: int,
                   n_pairs: int = 1, pad_tail: bool = False,
-                  value_columns: int = 1) -> Tuple[int, int]:
+                  value_columns: int = 1, classes: int = 0
+                  ) -> Tuple[int, int]:
     """(width, rounds) of one tree dispatch, from shapes alone: how many
     grid×fold pairs it vmaps and how many of a pair's `learners`
     (boosting rounds; a forest grows all its trees in one) it runs.
@@ -1240,23 +1294,45 @@ def dispatch_plan(n_rows: int, slots: int, pad_depth: int, learners: int,
     the width to 1 for any K; at 4,500,000 rows × 318 slots and depth 6
     (PR 32) the memory term alone does (the bin one-hots of one pair
     are 3.4 GB of the 4 GiB), and the work term lets a pair's whole
-    chain of 10 or 20 rounds into one dispatch, 1.4 or 2.9 s."""
+    chain of 10 or 20 rounds into one dispatch, 1.4 or 2.9 s.
+
+    `classes`: the trees a round of a SOFTMAX chain grows (its K; 0 for
+    every other learner). Its round is K trees in the work term that
+    sets the ROUNDS of a dispatch. Its memory term counts what a
+    softmax dispatch holds, in `_SOFTMAX_DISPATCH_BYTES`: the bin
+    one-hots ONCE (the pairs and a round's K trees share them), and for
+    each pair its (n, K) float32 margin, p, G and H (16 · n · K bytes),
+    its routing one-hot and its K trees' deepest histograms. Timed on
+    one TPU v5e, `dionis`' chain, 374,569 rows × 1,920 slots (60
+    columns of 32 bins), K = 355, depth 6, three folds × two
+    configurations: 1.44 GB shared and 2.70 GB a pair give two pairs
+    and one round a dispatch, a pair-round in 1.57 s (a pass of 49.2 s,
+    peak 4.90 GB; one pair a dispatch 2.11 s, a pass of 65.1 s). At
+    500,000 rows × 1,000 classes a pair's state alone is 8 GB: one pair
+    a dispatch."""
     nodes = 2 ** min(pad_depth, 14)
     k = max(int(value_columns), 1)
-    unit = max(n_rows * nodes * slots * k, 1)   # one learner of one pair
-    # bf16 bytes of the bin one-hots and the deepest level's routing
-    # one-hot, as if every pair held its own: the bin one-hots are built
-    # once a dispatch and shared by its pairs, so this over-counts them.
-    # A pair's own float32 histograms of the deepest level (nodes / 2),
-    # with their cumulative sums and the gain table: (k + 1) value
-    # columns × nodes / 2 × slots × 4 bytes, three times
-    w_mem = _PAIR_MEM_BYTES // max(
-        n_rows * (slots + nodes) * 2 + 6 * (k + 1) * nodes * slots, 1)
+    unit = max(n_rows * nodes * slots * k, 1)   # one tree of one pair
+    if classes:
+        trees_k = int(classes)
+        pair = (n_rows * nodes * 2 + 16 * n_rows * trees_k
+                + 6 * (k + 1) * nodes * slots * trees_k)
+        w_mem = max(_SOFTMAX_DISPATCH_BYTES - n_rows * slots * 2, 0) // pair
+    else:
+        # bf16 bytes of the bin one-hots and the deepest level's routing
+        # one-hot, as if every pair held its own: the bin one-hots are
+        # built once a dispatch and shared by its pairs, so this
+        # over-counts them. A pair's own float32 histograms of the
+        # deepest level (nodes / 2), with their cumulative sums and the
+        # gain table: (k + 1) value columns × nodes / 2 × slots × 4
+        # bytes, three times
+        w_mem = _PAIR_MEM_BYTES // max(
+            n_rows * (slots + nodes) * 2 + 6 * (k + 1) * nodes * slots, 1)
     w_work = int(_DISPATCH_UNITS // (learners * unit))
     width = min(_pow2_floor(max(1, min(w_mem, w_work))),
                 _pow2_floor(n_pairs) if pad_tail else n_pairs)
-    rounds = _pick_rounds_per_dispatch(
-        learners, max(1, int(_DISPATCH_UNITS // (width * unit))))
+    rounds = _pick_rounds_per_dispatch(learners, max(1, int(
+        _DISPATCH_UNITS // (width * unit * max(int(classes), 1)))))
     return width, rounds
 
 
@@ -1267,24 +1343,31 @@ def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
                    early_stopping_rounds: int = 0,
                    rounds_per_dispatch: Optional[int] = None,
                    min_gain_norm=0.0, eval_metric: str = "logloss",
-                   layout: Optional[Dict] = None, base_score=None):
+                   layout: Optional[Dict] = None, base_score=None,
+                   n_classes: int = 0):
     """Host-chunked boosting: bitwise-identical trees/margin to `fit_gbt`
     (same key stream, same scan body) but dispatched `rounds_per_dispatch`
     rounds at a time so early stopping SKIPS the remaining dispatches
     instead of masking them. Used for refits whose full scan would be
     tens of seconds (200-round depth-10 at 100k rows). `base_score`:
-    where the chain starts, `gbt_base_score` of (y, w) when None."""
+    where the chain starts, `gbt_base_score` of (y, w) when None (a
+    softmax chain of `n_classes` starts at 0)."""
     n, d = Xb.shape
     esr = int(early_stopping_rounds) if val_w is not None else 0
     if val_w is None:
         val_w = jnp.zeros(n, jnp.float32)
+    softmax = objective == "softmax"
     if rounds_per_dispatch is None:
         _, rounds_per_dispatch = dispatch_plan(
-            n, hist_slots(d, n_bins, layout), max_depth, n_estimators)
+            n, hist_slots(d, n_bins, layout), max_depth, n_estimators,
+            classes=n_classes if softmax else 0)
     keys = jax.random.split(jax.random.PRNGKey(seed), n_estimators)
-    if base_score is None:
-        base_score = gbt_base_score(y, w, objective)
-    margin = jnp.full(n, base_score, jnp.float32)
+    if softmax:
+        margin = gbt_margin0(y, w, objective, n_classes)
+    else:
+        if base_score is None:
+            base_score = gbt_base_score(y, w, objective)
+        margin = jnp.full(n, base_score, jnp.float32)
     best = jnp.float32(jnp.inf)
     since = jnp.int32(0)
     chunks = []
@@ -1301,49 +1384,6 @@ def fit_gbt_hosted(Xb, y, w, n_estimators: int, max_depth: int, n_bins: int,
         if esr and int(since) >= esr:
             break  # remaining rounds would all be zeroed no-op trees
     trees = jax.tree.map(lambda *a: jnp.concatenate(a, 0), *chunks)
-    return trees, margin
-
-
-@partial(jax.jit, static_argnames=("n_estimators", "max_depth", "n_bins",
-                                   "n_classes"))
-def fit_gbt_multiclass(Xb, y, w, n_estimators: int, max_depth: int,
-                       n_bins: int, n_classes: int, learning_rate,
-                       reg_lambda, min_child_weight: float = 1.0,
-                       active_depth=None, gamma=0.0, alpha=0.0,
-                       subsample=1.0, colsample=1.0, seed=0,
-                       min_gain_norm=0.0, layout: Optional[Dict] = None):
-    """Softmax boosting: K one-vs-rest trees per round grown from the
-    multinomial gradients (the reference's XGBoost multi:softprob —
-    OpXGBoostClassifier.scala:47 supports multiclass; the r1 facade was
-    binary-only). Returns (trees with (T, K, ...) leaves, (n, K) margin)."""
-    n, d = Xb.shape
-    Y = jax.nn.one_hot(y.astype(jnp.int32), n_classes)
-    B = hist_operand(Xb, n_bins, layout)  # shared across rounds and classes
-
-    def round_(margin, key):
-        k1, k2 = jax.random.split(key)
-        rows = (jax.random.uniform(k1, (n,)) < subsample).astype(jnp.float32)
-        fmask = jax.random.uniform(k2, (d,)) < colsample
-        p = jax.nn.softmax(margin, axis=1)
-        G = (p - Y) * w[:, None]
-        Hs = jnp.maximum(p * (1.0 - p), 1e-6) * w[:, None]
-
-        def per_class(g, h):
-            return grow_tree(Xb, (-g * rows)[:, None], h * rows, max_depth,
-                             n_bins, reg_lambda=reg_lambda,
-                             min_child_weight=min_child_weight,
-                             min_gain=gamma, min_gain_norm=min_gain_norm,
-                             feature_mask=fmask,
-                             active_depth=active_depth, alpha=alpha, B=B)
-
-        trees_k = jax.vmap(per_class, in_axes=(1, 1))(G, Hs)  # (K, ...)
-        upd = jax.vmap(lambda t: _leaf_lookup(
-            t["leaf"][:, 0], _tree_walk(t, Xb)))(trees_k)  # (K, n)
-        return margin + learning_rate * upd.T, trees_k
-
-    keys = jax.random.split(jax.random.PRNGKey(seed), n_estimators)
-    base = jnp.zeros((n, n_classes), jnp.float32)
-    margin, trees = jax.lax.scan(round_, base, keys)
     return trees, margin
 
 
@@ -1390,6 +1430,8 @@ def forest_regression_pred(trees: Dict, Xb: jnp.ndarray,
 
 
 def gbt_pred_from_margin(margin: jnp.ndarray, objective: str) -> Dict:
+    if objective == "softmax":
+        return gbt_multiclass_pred_from_margin(margin)
     if objective == "logistic":
         p1 = jax.nn.sigmoid(margin)
         return {"prediction": (margin > 0).astype(jnp.float32),
@@ -1486,17 +1528,19 @@ def warm_refit_gbt(est, warm: Dict, X, y, w, ctx,
     """GBT warm refit: CONTINUE boosting from the resident ensemble's
     margin instead of restarting from zero — the new rounds fit the
     residual the old trees leave on the refreshed data (appended rows
-    included), and the grown trees append to the ensemble. Binary /
-    regression objectives only (the multiclass stacked-round layout
-    falls back to a cold fit at the call site)."""
+    included), and the grown trees append to the ensemble. A "softmax"
+    ensemble's rounds are (rounds, K, ...) and its margin (n, K)."""
     edges = jnp.asarray(np.asarray(warm["edges"], np.float32))
     old = {k: jnp.asarray(v) for k, v in warm["trees"].items()}
     n_old = int(old["feat"].shape[0])
     lr = jnp.float32(warm.get("learning_rate", est.learning_rate))
     Xb = bin_features(jnp.asarray(X), edges)
     n = Xb.shape[0]
-    margin0 = jnp.float32(warm.get("base_score", 0.0)) \
-        + predict_gbt_margin(old, Xb, lr)
+    if objective == "softmax":
+        margin0 = predict_gbt_multiclass_margin(old, Xb, lr)
+    else:
+        margin0 = jnp.float32(warm.get("base_score", 0.0)) \
+            + predict_gbt_margin(old, Xb, lr)
     n_extra = int(warm.get("n_new") or 0)
     if n_extra <= 0:
         n_extra = max(1, est.n_estimators // 4)
@@ -1632,12 +1676,13 @@ class GBTRegressionModel(GBTClassificationModel):
 
 
 class GBTMulticlassModel(GBTClassificationModel):
-    """Softmax forest: trees stacked (rounds, classes, ...)."""
+    """Softmax chain: trees stacked (rounds, classes, ...), an (n, K)
+    margin scored by `gbt_pred_from_margin`."""
 
     def _apply_arrays(self, trees, Xb):
         margin = predict_gbt_multiclass_margin(
             trees, Xb, jnp.float32(self.learning_rate))
-        return gbt_multiclass_pred_from_margin(margin)
+        return gbt_pred_from_margin(margin, "softmax")
 
 
 class _TreeEstimatorBase(PredictorEstimator):
@@ -1857,17 +1902,28 @@ class OpGBTClassifier(_TreeEstimatorBase):
     # fold's validation rows; the refit has no fold)
     _ES_EVAL_FRACTION = 0.2
 
+    def objective_of(self, n_classes: int) -> str:
+        """The chain's objective: the estimator's, or "softmax" for a
+        logistic one over more than two classes."""
+        if self._objective == "logistic" and n_classes > 2:
+            return "softmax"
+        return self._objective
+
     def fit_arrays(self, X, y, w, ctx: FitContext):
         if self._objective == "logistic":
             k = n_classes_of(self, y, ctx)
         else:
             k = 2
+        objective = self.objective_of(k)
+        softmax = objective == "softmax"
         warm = self.init_params
         if warm is not None and "trees" in warm:
-            n_resident = int(np.asarray(warm["trees"]["feat"]).shape[0])
-            if self._objective == "logistic" and k > 2:
-                log.info("GBT warm refit: multiclass stacked-round layout "
-                         "has no margin-continuation path; fitting cold")
+            resident = np.asarray(warm["trees"]["leaf"])
+            n_resident = int(resident.shape[0])
+            if softmax != (resident.ndim == 4) or (
+                    softmax and int(resident.shape[1]) != k):
+                log.info("GBT warm refit: resident chain is not a %s one "
+                         "of %d classes; fitting cold", objective, k)
             elif n_resident >= 2 * self.n_estimators:
                 log.info("GBT warm refit: resident ensemble at the 2x "
                          "growth cap (%d rounds vs n_estimators=%d); "
@@ -1877,26 +1933,14 @@ class OpGBTClassifier(_TreeEstimatorBase):
                                           max_bins=self.max_bins):
                 pass  # logged: shape drift falls back to a cold fit
             else:
-                trees = warm_refit_gbt(self, warm, X, y, w, ctx,
-                                       self._objective)
+                trees = warm_refit_gbt(self, warm, X, y, w, ctx, objective)
                 return self._model(
                     np.asarray(warm["edges"], np.float32), trees,
                     float(warm.get("learning_rate", self.learning_rate)),
-                    warm.get("base_score", 0.0))
+                    warm.get("base_score", 0.0), objective)
         edges, Xb, layout = self._edges_binned(X, ctx)
         seed = ctx.seed if ctx is not None else 0
-        if self._objective == "logistic" and k > 2:
-            trees, _ = fit_gbt_multiclass(
-                Xb, y, w, self.n_estimators, self.max_depth, self.max_bins,
-                k, jnp.float32(self.learning_rate),
-                jnp.float32(self.reg_lambda), self._effective_mcw(),
-                gamma=jnp.float32(self.gamma), alpha=jnp.float32(self.alpha),
-                subsample=jnp.float32(self.subsample),
-                colsample=jnp.float32(self.colsample_bytree), seed=seed,
-                min_gain_norm=jnp.float32(self.min_info_gain), layout=layout)
-            return GBTMulticlassModel(
-                edges, pull("fit:trees", trees),
-                self.learning_rate)
+        classes = k if softmax else 0
         esr = int(self.early_stopping_rounds or 0)
         n_rounds = self.n_estimators
         if esr > 0:
@@ -1914,14 +1958,15 @@ class OpGBTClassifier(_TreeEstimatorBase):
             probe, _ = fit_gbt_hosted(
                 Xb, y, (1.0 - hold) * w, self.n_estimators, self.max_depth,
                 self.max_bins, jnp.float32(self.learning_rate),
-                jnp.float32(self.reg_lambda), self._objective,
+                jnp.float32(self.reg_lambda), objective,
                 self._effective_mcw(), gamma=jnp.float32(self.gamma),
                 alpha=jnp.float32(self.alpha),
                 subsample=jnp.float32(self.subsample),
                 colsample=jnp.float32(self.colsample_bytree),
                 seed=seed, val_w=hold * w, early_stopping_rounds=esr,
                 min_gain_norm=jnp.float32(self.min_info_gain),
-                eval_metric=self.eval_metric, layout=layout)
+                eval_metric=self.eval_metric, layout=layout,
+                n_classes=classes)
             # stopped rounds grow ZEROED trees, so the probe's stopping
             # round is the LAST live tree's index + 1 — counting live
             # trees instead would undercount when a mid-sequence tree is
@@ -1938,18 +1983,18 @@ class OpGBTClassifier(_TreeEstimatorBase):
             _, rpd = dispatch_plan(
                 Xb.shape[0],
                 hist_slots(Xb.shape[1], self.max_bins, layout),
-                self.max_depth, self.n_estimators)
+                self.max_depth, self.n_estimators, classes=classes)
             n_rounds = min(-(-n_live // rpd) * rpd, self.n_estimators)
             rpd_refit = rpd
         else:
             rpd_refit = None
         # Pass 2 (or the only pass) — the shipped model: full weights,
         # fixed round count, no holdout.
-        base = gbt_base_score(y, w, self._objective)
+        base = gbt_base_score(y, w, objective)
         trees, _ = fit_gbt_hosted(
             Xb, y, w, n_rounds, self.max_depth,
             self.max_bins, jnp.float32(self.learning_rate),
-            jnp.float32(self.reg_lambda), self._objective,
+            jnp.float32(self.reg_lambda), objective,
             self._effective_mcw(),
             gamma=jnp.float32(self.gamma),
             alpha=jnp.float32(self.alpha),
@@ -1957,16 +2002,18 @@ class OpGBTClassifier(_TreeEstimatorBase):
             colsample=jnp.float32(self.colsample_bytree),
             seed=seed, rounds_per_dispatch=rpd_refit,
             min_gain_norm=jnp.float32(self.min_info_gain), layout=layout,
-            base_score=base)
+            base_score=base, n_classes=classes)
         return self._model(
             edges, pull("fit:trees", trees),
-            self.learning_rate, base)
+            self.learning_rate, base, objective)
 
-    def _model(self, edges, trees, learning_rate, base_score):
+    def _model(self, edges, trees, learning_rate, base_score, objective):
         """The fitted model; a squared-loss one keeps where its chain
         started."""
+        if objective == "softmax":
+            return GBTMulticlassModel(edges, trees, learning_rate)
         started = ({"base_score": float(base_score)}
-                   if self._objective == "squared" else {})
+                   if objective == "squared" else {})
         return self._model_cls(edges, trees, learning_rate, **started)
 
 

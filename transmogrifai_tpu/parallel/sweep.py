@@ -68,9 +68,9 @@ from transmogrifai_tpu.models.trees import (
     OpDecisionTreeClassifier, OpDecisionTreeRegressor, OpGBTClassifier,
     OpGBTRegressor, OpRandomForestClassifier, OpRandomForestRegressor,
     OpXGBoostClassifier, OpXGBoostRegressor,
-    bin_features, dispatch_plan, fit_forest, fit_gbt, fit_gbt_multiclass,
+    bin_features, dispatch_plan, fit_forest, fit_gbt,
     forest_classification_pred, forest_regression_pred,
-    gbt_base_score, gbt_multiclass_pred_from_margin, gbt_pred_from_margin,
+    gbt_base_score, gbt_pred_from_margin,
     gbt_train_summary, edges_site, hist_layout, hist_reads, hist_slots,
     indicator_columns, quantile_bin_edges, tree_span_attrs)
 from transmogrifai_tpu.runtime.faults import (
@@ -841,30 +841,23 @@ def _fp_forest(static, pad_depth, divisor, n_out, seed, bootstrap,
 
 def _fp_gbt(static, pad_depth, n_classes, seed, objective, eval_metric,
             blocks):
-    """The boosted family's single-program path (a mesh, or multiclass):
-    the whole fit, with in-scan early-stop masking for binary/squared.
-    `blocks` as in `_fp_forest`."""
+    """The boosted family's single-program path (a mesh): the whole fit,
+    with in-scan early-stop masking; a "softmax" chain boosts
+    `n_classes` margins. `blocks` as in `_fp_forest`."""
     n_estimators, max_bins, esr = static[:3]
 
     def fit_predict(data, d, w, v):
-        Xb, y = data["Xb"], data["y"]
-        common = dict(min_child_weight=d["mcw"], active_depth=d["depth"],
-                      gamma=d["gamma"], alpha=d["alpha"],
-                      subsample=d["subsample"], colsample=d["colsample"],
-                      seed=seed, layout=data["layout"] if blocks else None)
-        if objective == "logistic" and n_classes > 2:
-            _, margin = fit_gbt_multiclass(
-                Xb, y, w, n_estimators, pad_depth, max_bins, n_classes,
-                d["lr"], d["lam"], min_gain_norm=d["min_gain_norm"],
-                **common)
-            return gbt_multiclass_pred_from_margin(margin)
         # the scan carry is the final training-matrix margin — no
         # post-fit forest re-walk needed
-        _, margin = fit_gbt(Xb, y, w, n_estimators, pad_depth, max_bins,
-                            d["lr"], d["lam"], objective, val_w=v,
-                            early_stopping_rounds=esr,
-                            min_gain_norm=d["min_gain_norm"],
-                            eval_metric=eval_metric, **common)
+        _, margin = fit_gbt(
+            data["Xb"], data["y"], w, n_estimators, pad_depth, max_bins,
+            d["lr"], d["lam"], objective, min_child_weight=d["mcw"],
+            active_depth=d["depth"], gamma=d["gamma"], alpha=d["alpha"],
+            subsample=d["subsample"], colsample=d["colsample"], seed=seed,
+            val_w=v, early_stopping_rounds=esr,
+            min_gain_norm=d["min_gain_norm"], eval_metric=eval_metric,
+            layout=data["layout"] if blocks else None,
+            n_classes=n_classes if objective == "softmax" else 0)
         return gbt_pred_from_margin(margin, objective)
     return fit_predict
 
@@ -1156,12 +1149,14 @@ def _gbt_score_program(static: Tuple, objective: str,
 
 def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
     xb_by_bins, layout, blocks = _binned_cache(est, grids, X, ctx)
-    objective = est._objective
     n_classes = 2
-    if objective == "logistic":
+    if est._objective == "logistic":
         n_classes = n_classes_of(est, y, ctx)
+    objective = est.objective_of(n_classes)
     seed = int(ctx.seed) if ctx is not None else 0
-    multiclass = objective == "logistic" and n_classes > 2
+    # a softmax round grows a tree a class: K in the plan, on the spans
+    classes = n_classes if objective == "softmax" else 0
+    chain_attrs = {"classes": classes} if classes else {}
 
     def lr_of(grid) -> float:
         v = grid.get("eta", grid.get("learning_rate"))
@@ -1197,18 +1192,17 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             "min_gain_norm": float(
                 _grid_param(est, g, "min_info_gain") or 0.0)}
 
-    if sharding is not None or multiclass:
-        # mesh-sharded grids (dryrun/pod shapes) and multiclass keep the
-        # single-program path (`_fp_gbt`): the whole fit (with in-scan
-        # early-stop masking for binary/squared — same key stream and
-        # state transitions as the chunked loop, so metrics agree) vmaps
-        # over the grid axis
+    if sharding is not None:
+        # mesh-sharded grids (dryrun/pod shapes) keep the single-program
+        # path (`_fp_gbt`): the whole fit (with in-scan early-stop
+        # masking — same key stream and state transitions as the chunked
+        # loop, so metrics agree) vmaps over the grid axis
         def width_of(st, idxs):
             n_estimators, max_bins = st[0], st[1]
             return dispatch_plan(
                 n_rows, hist_slots(d_feat, max_bins, layout),
                 _pad_depth_of(est, grids, idxs), n_estimators,
-                len(idxs) * n_folds)[0]
+                len(idxs) * n_folds, classes=classes)[0]
 
         return _sweep_blocks(
             grids, W, V, metric_fn, sharding, "gbt",
@@ -1217,13 +1211,13 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                 _pad_depth_of(est, grids, idxs), n_classes, seed,
                 objective, eval_metric, blocks),
             grid_vmap=lambda st, idxs: _pad_depth_of(est, grids, idxs) <= 6,
-            host_dispatch=sharding is None,
             pair_width=lambda st, idxs, k: width_of(st, idxs),
             x_info=_x_info(X),
-            dispatch_attrs=lambda st, idxs: tree_span_attrs(
-                _pad_depth_of(est, grids, idxs)))
+            dispatch_attrs=lambda st, idxs: dict(tree_span_attrs(
+                _pad_depth_of(est, grids, idxs)), objective=objective,
+                **chain_attrs))
 
-    # ---- single-device binary/squared: ROUND-CHUNKED host dispatch ---- #
+    # ---- single device: ROUND-CHUNKED host dispatch ---- #
     # Each dispatch runs `rpd` boosting rounds for `width` vmapped
     # grid×fold pairs, carrying (margin, best_val, since) across
     # dispatches instead of one 200-round execution: once EVERY pair in
@@ -1256,7 +1250,7 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
         # second compile; `rpd` divides the rounds, so one chunk shape
         width, rpd = dispatch_plan(
             n_rows, hist_slots(d_feat, max_bins, layout), pad_depth, n_est,
-            n_pairs, pad_tail=True)
+            n_pairs, pad_tail=True, classes=classes)
 
         prog = _gbt_rounds_program(static, pad_depth, objective, eval_metric,
                                    blocks)
@@ -1277,6 +1271,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                 base = jax.vmap(lambda w: gbt_base_score(
                     data["y"], w, objective))(Wsel)
                 margin = jnp.broadcast_to(base[:, None], (width, n_rows))
+            elif classes:
+                margin = jnp.zeros((width, n_rows, classes), jnp.float32)
             else:
                 margin = jnp.zeros((width, n_rows), jnp.float32)
             best = jnp.full((width,), jnp.inf, jnp.float32)
@@ -1291,7 +1287,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                                     pairs=min(width, n_pairs - s),
                                     pad_depth=pad_depth,
                                     objective=objective,
-                                    **tree_span_attrs(pad_depth)):
+                                    **tree_span_attrs(pad_depth),
+                                    **chain_attrs):
                     margin, best, since = jax.block_until_ready(
                         prog(data, dchunk, Wsel, Vsel, margin, best, since,
                              ks))
