@@ -182,6 +182,48 @@ def test_chunked_sweep_spans_and_one_round_a_dispatch(monkeypatch):
     assert len(losses) == len(folds) and 0 < min(losses) < np.log(k)
 
 
+def test_a_softmax_dispatch_subtracts_and_a_binary_one_does_not():
+    """At depth 6 a softmax round's K = 5 trees batch each histogram
+    product, so levels 3-5 come by sibling subtraction; a binary chain's
+    one tree a round keeps every level direct in the default bf16 mode."""
+    from transmogrifai_tpu.evaluators import BinaryClassificationEvaluator
+    from transmogrifai_tpu.obs.trace import TRACER
+    assert trees.HIST_PRECISION == "bf16"
+    said = {}
+    for k in (5, 2):
+        X, y, folds = _sweep_table(k)
+        est = OpXGBoostClassifier(n_estimators=2, max_bins=8, max_depth=6,
+                                  early_stopping_rounds=0, n_classes=k)
+        ev = (MultiClassificationEvaluator() if k > 2
+              else BinaryClassificationEvaluator())
+        mark = max((sp.span_id for sp in TRACER.spans()), default=0)
+        S.run_sweep(est, [{}], X, y, folds, ev,
+                    FitContext(n_rows=len(y), seed=7, n_classes=k))
+        said[k] = {(sp.attributes["objective"],
+                    sp.attributes["hist_subtract"])
+                   for sp in TRACER.spans()
+                   if sp.span_id > mark and sp.name == "sweep:dispatch:gbt"}
+    assert said == {5: {("softmax", 3)}, 2: {("logistic", 0)}}
+    assert trees.hist_subtract_levels(6, 0, 5) == (3, 4, 5)
+
+
+def test_a_softmax_refits_own_binning_states_its_subtracted_levels():
+    """The estimator's refit grows its K trees a round by the same rule
+    as the sweep, and its `tree:edges` span says so."""
+    from transmogrifai_tpu.obs.trace import TRACER
+    k = 5
+    X, y = _table(k, n=300)
+    est = OpXGBoostClassifier(n_estimators=2, max_depth=6, max_bins=8,
+                              n_classes=k)
+    with TRACER.span("run:refit", new_trace=True) as root:
+        est.fit_arrays(jnp.asarray(X), jnp.asarray(y),
+                       jnp.ones(len(y), jnp.float32),
+                       FitContext(n_rows=len(y), seed=3, n_classes=k))
+    edges, = [sp for sp in TRACER.trace_spans(root.trace_id)
+              if sp.name == "tree:edges"]
+    assert edges.attributes["hist_subtract"] == 3
+
+
 def test_refit_is_the_softmax_chain():
     """The estimator's refit grows the same chain as `fit_gbt` and its
     model scores the (n, K) margin as probabilities."""

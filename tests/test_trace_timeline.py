@@ -680,7 +680,9 @@ def test_a_softmax_dispatch_states_its_chain(softmax_spans):
     for at in disp:
         assert at["objective"] == "softmax" and at["classes"] == 4
         assert at["pad_depth"] == 4 and at["rounds"] >= 1
-        assert at["leaf_sums"] == "product" and at["hist_subtract"] == 0
+        # the round's 4 trees batch each product: level 3's right
+        # children are 4 · 4 = 16 A-side rows, one tile, by subtraction
+        assert at["leaf_sums"] == "product" and at["hist_subtract"] == 1
     # every round of every (configuration, fold) chain, once
     assert sum(at["rounds"] * at["pairs"] for at in disp) == 3 * 2 * 2
 
@@ -922,6 +924,20 @@ def _lower_gbt_chunk():
         subsample=1.0, colsample=1.0, early_stopping_rounds=0)
 
 
+def _lower_gbt_chunk_softmax():
+    """Two softmax rounds of 5 classes at depth 6, as the sweep's
+    round-chunked dispatch runs them: levels 3-5 by subtraction."""
+    Xb, _, H = _tree_inputs()
+    y = jnp.asarray(np.arange(64) % 5, jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    return trees.fit_gbt_chunk.lower(
+        Xb, y, H, jnp.zeros_like(H), jnp.zeros((64, 5), jnp.float32),
+        jnp.float32(jnp.inf), jnp.int32(0), keys, n_rounds=2, max_depth=6,
+        n_bins=4, learning_rate=0.1, reg_lambda=1.0, objective="softmax",
+        min_child_weight=1.0, active_depth=None, gamma=0.0, alpha=0.0,
+        subsample=1.0, colsample=1.0, early_stopping_rounds=0)
+
+
 def _lower_forest():
     Xb, G, H = _tree_inputs()
     return trees.fit_forest.lower(Xb, G, H, n_trees=2, max_depth=2,
@@ -1108,6 +1124,7 @@ SCOPES = [
     ("tree:hist:subtract", _lower_grow_tree_classes_deep),
     ("tree:hist:subtract", _lower_grow_tree_classes_deep_two_blocks),
     ("tree:hist:classes", _lower_grow_tree_classes_deep),
+    ("tree:hist:subtract", _lower_gbt_chunk_softmax),
 ]
 
 
@@ -1202,6 +1219,17 @@ def test_a_deep_classifiers_program_multiplies_only_the_right_children():
     rows = [int(r) for r in re.findall(
         r"stablehlo\.dot_general[^\n]*-> tensor<(\d+)x", text)]
     assert rows == [3, 6, 12, 24, 24, 48, 64], rows
+
+
+def test_a_softmax_rounds_program_multiplies_only_the_right_children():
+    # depth 6, K = 5 trees a product: levels 0-2 direct (5 · 2^level
+    # A-side rows), levels 3-5 over the right children (5 · 4, 5 · 8 and
+    # 5 · 16 rows), a gradient and a hessian product each, then the
+    # leaves' one product
+    text = _lower_gbt_chunk_softmax().as_text()
+    nodes = [int(r) for r in re.findall(
+        r"stablehlo\.dot_general[^\n]*-> tensor<5x(\d+)x12xf32>", text)]
+    assert nodes == [1, 1, 2, 2, 4, 4, 4, 4, 8, 8, 16, 16], nodes
 
 
 def test_a_tree_program_past_the_crossover_scatters_its_leaves():

@@ -257,11 +257,15 @@ def hist_operand(Xb: jnp.ndarray, n_bins: int,
 #     true f32 even where the platform runs plain f32 matmuls at bf16) —
 #     the reference bar (MLlib/XGBoost exact f32/f64 scatter histograms)
 #     at roughly 1/4-1/8 the MXU throughput.
-# Sibling subtraction (`hist_subtract_levels`) keys on the values, not on
-# this switch alone: a classifier's class counts subtract in either mode
-# (whole numbers, exact in bf16 and in f32 sums), signed gradient
-# histograms only under "f32" (in bf16 a deep node's parent − right is a
-# cancellation of bf16-rounded terms).
+# Sibling subtraction (`hist_subtract_levels`) keys on the values and the
+# shapes, not on this switch alone. A classifier's class counts subtract
+# in either mode (whole numbers, exact in bf16 and in f32 sums). Signed
+# gradients subtract under "f32", and under "bf16" where one product
+# batches K >= 2 trees (a softmax round's class trees): the right
+# children's product rounds each row's g and h to bf16 exactly as the
+# parent's does, so parent − right differs from the direct left child by
+# float32 summation order alone, far under the bf16 rounding the direct
+# form already has. A single tree's bf16 gradients stay direct.
 # Process-level switch: TRANSMOGRIFAI_HIST_PRECISION=f32, read ONCE at
 # import. jax.jit caches executables by shape/static-args only, so
 # mutating this global (or the env var) after fit functions have traced
@@ -584,8 +588,8 @@ def _leaf_sums(node_idx, G, H, max_nodes: int, cls=None, n_classes: int = 0):
 _SUBTRACT_MIN_ROWS = 16
 
 
-def hist_subtract_levels(pad_depth: int, n_classes: int = 0
-                         ) -> Tuple[int, ...]:
+def hist_subtract_levels(pad_depth: int, n_classes: int = 0,
+                         batched_trees: int = 1) -> Tuple[int, ...]:
     """The levels of a `pad_depth` tree whose histograms `grow_tree`
     takes by sibling subtraction, from static shapes alone (the span
     attribute `hist_subtract` counts them; the scope is
@@ -599,13 +603,22 @@ def hist_subtract_levels(pad_depth: int, n_classes: int = 0
     class-histogram cell is an integer below 2^24 in float32 and parent −
     right is the direct histogram bit for bit, in either precision mode
     (fractional weights: the same bfloat16 weights summed in another
-    float32 order, a few ulps of the parent's cell). A regressor's or a
-    boosted round's SIGNED gradients stay direct under the default
-    HIST_PRECISION "bf16": a deep node's subtracted gradient histogram is
-    a big-minus-big cancellation whose error scales with the parent's
-    bfloat16-rounded terms, not the node's own (the depth-12-padded
-    boosted sweep lost ~0.005 CV AuPR to it). Under "f32" any form
-    subtracts: the cancellation sits at float32 rounding.
+    float32 order, a few ulps of the parent's cell). SIGNED gradients
+    subtract under HIST_PRECISION "f32" (the cancellation sits at
+    float32 rounding) and, under the default "bf16", where one product
+    batches `batched_trees` ≥ 2 trees: a softmax round's K class trees,
+    vmapped over one shared operand, so their (K · nodes, n) A side is
+    one product. There the right children's product rounds each row's g
+    and h to bfloat16 exactly as the parent's did, both sums accumulate
+    in float32, and parent − right differs from the direct left child by
+    float32 summation order alone: a few ulps of the cell at the last
+    level taken direct (each subtracted level is taken from the one
+    before), orders of magnitude under the bfloat16 rounding of the
+    values both forms share (the hessians, ≥ 1e-6 a row, do not cancel
+    at all). A single tree's bf16 gradients (a binary or squared-loss
+    round, a regressor) stay direct: their padded depth-12 sweep lost
+    ~0.005 CV AuPR to subtraction once and was not read again, and their
+    products are not those cells' bottleneck.
 
     Which shapes make it pay. A level's product is (rows, n) @ (n,
     slots) with the bin one-hot generated inside it; the subtraction adds
@@ -630,29 +643,43 @@ def hist_subtract_levels(pad_depth: int, n_classes: int = 0
     a product pads its rows to the tile and halving them saves nothing,
     so a level subtracts where its right-children product holds at
     least `_SUBTRACT_MIN_ROWS` rows: classes × 2^(level − 1), or
-    2^(level − 1) for each product of the per-value-column form (a
-    binary forest from level 4). A whole depth-12 tree, same chip, best
+    trees × 2^(level − 1) for each product of the per-value-column form
+    (a binary forest from level 4, a softmax round of 355 trees from
+    level 1). A whole depth-12 tree, same chip, best
     of three: 1,800,000 × 1,042 slots at K = 23 2.155 s direct, 1.152 s
     by subtraction (levels 1–11); 2,160,000 × 896 slots at K = 2 0.239 s
     and 0.156 s (levels 4–11); both forms grew the same trees bit for
     bit. The binary shapes were not timed level by level, nor 900,000 ×
-    1,446 slots at all."""
-    if not n_classes and HIST_PRECISION != "f32":
+    1,446 slots at all. A softmax round of K = 355 trees, 374,569 rows ×
+    1,920 slots, two (configuration, fold) pairs a product, same chip,
+    seconds over a training pass's 12 dispatches (the gradient product,
+    then the hessian's), read off one traced pass of each form:
+
+      level   A-side rows, direct / right   direct          by subtraction
+        4      2 · 355 · 16 / 2 · 355 · 8   2.609, 2.609    1.520, 1.325
+        5      2 · 355 · 32 / 2 · 355 · 16  5.219, 5.219    2.917, 2.917
+
+    and the whole training pass 49.4–50.7 s direct, 39.2–39.9 s by
+    subtraction (three seeds each)."""
+    if not n_classes and batched_trees < 2 and HIST_PRECISION != "f32":
         return ()
-    rows = max(int(n_classes), 1)
+    rows = int(n_classes) or int(batched_trees)
     return tuple(level for level in range(1, int(pad_depth))
                  if rows * 2 ** (level - 1) >= _SUBTRACT_MIN_ROWS)
 
 
-def tree_span_attrs(pad_depth: int, n_classes: int = 0) -> Dict:
+def tree_span_attrs(pad_depth: int, n_classes: int = 0,
+                    batched_trees: int = 1) -> Dict:
     """What a program of `pad_depth`-level trees does, as span attributes
     (`sweep:dispatch:forest`, `sweep:dispatch:gbt`, `tree:edges`):
     `leaf_sums`, the form of its leaf sums (`leaf_sums_form`), and
     `hist_subtract`, how many levels take their histograms by sibling
     subtraction (`hist_subtract_levels`). `n_classes`: a classifier
-    forest's K, else 0."""
+    forest's K, else 0; `batched_trees`: the trees one histogram product
+    batches (a softmax round's K, else 1)."""
     return {"leaf_sums": leaf_sums_form(2 ** int(pad_depth), 1, n_classes),
-            "hist_subtract": len(hist_subtract_levels(pad_depth, n_classes))}
+            "hist_subtract": len(hist_subtract_levels(
+                pad_depth, n_classes, batched_trees))}
 
 
 @jax.named_scope("tree:hist:subtract")
@@ -678,7 +705,7 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
               active_depth=None, alpha: float = 0.0,
               B: Optional[List[Tuple]] = None,
               min_gain_norm=0.0, layout: Optional[Dict] = None,
-              n_classes: int = 0) -> Dict:
+              n_classes: int = 0, batched_trees: int = 1) -> Dict:
     """Grow one fixed-depth tree. Returns dense arrays:
 
     {"feat": (depth, 2^depth) int32, "bin": (depth, 2^depth) int32,
@@ -712,7 +739,9 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
     that went right, half the A-side rows, and `_subtract_siblings`).
     A classifier's trees are then the direct form's bit for bit wherever
     its weights are whole numbers; the rule and the chip times behind it
-    are in `hist_subtract_levels`.
+    are in `hist_subtract_levels`. `batched_trees`: how many trees the
+    caller vmaps over one shared operand, so that each histogram product
+    batches them (a softmax round's K class trees; 1 otherwise).
     """
     n, d = Xb.shape
     cls = None
@@ -735,7 +764,8 @@ def grow_tree(Xb: jnp.ndarray, G: jnp.ndarray, H: jnp.ndarray,
         return [_histograms(Bk, node, Gv, Hv, n_nodes, name)
                 for name, _, Bk in B]
 
-    subtract = hist_subtract_levels(max_depth, 0 if cls is None else m)
+    subtract = hist_subtract_levels(max_depth, 0 if cls is None else m,
+                                    batched_trees)
     for level in range(max_depth):
         n_nodes = 2 ** level
         if level not in subtract:
@@ -1135,7 +1165,9 @@ def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
     from the multinomial gradients p − onehot(y) and hessians
     max(p(1 − p), 1e-6) of p = softmax(margin), all K from the round's
     one row sample and feature mask and the one histogram operand; the
-    round's trees are stacked (K, ...) and a chain's (rounds, K, ...)."""
+    round's trees are stacked (K, ...) and a chain's (rounds, K, ...).
+    Their histogram products batch the K trees, so their deep levels
+    come by sibling subtraction (`hist_subtract_levels` of K)."""
     n, d = Xb.shape
     B = hist_operand(Xb, n_bins, layout)  # shared across all rounds
     esr = int(early_stopping_rounds)
@@ -1147,6 +1179,8 @@ def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
         rows = (jax.random.uniform(k1, (n,)) < subsample).astype(jnp.float32)
         fmask = jax.random.uniform(k2, (d,)) < colsample
         g, h = gbt_grad_hess(margin, y, w, objective)
+        # a softmax round's K trees share each histogram product
+        batched = margin.shape[1] if objective == "softmax" else 1
 
         def one_tree(g, h):
             return grow_tree(Xb, (-g * rows)[:, None], h * rows, max_depth,
@@ -1154,7 +1188,8 @@ def _gbt_scan(Xb, y, w, val_w, margin0, best0, since0, keys,
                              min_child_weight=min_child_weight,
                              min_gain=gamma, min_gain_norm=min_gain_norm,
                              feature_mask=fmask,
-                             active_depth=active_depth, alpha=alpha, B=B)
+                             active_depth=active_depth, alpha=alpha, B=B,
+                             batched_trees=batched)
 
         def update(tree):
             return _leaf_lookup(tree["leaf"][:, 0], _tree_walk(tree, Xb))
@@ -1692,12 +1727,14 @@ class _TreeEstimatorBase(PredictorEstimator):
     # sweep path keeps its own per-family cache (`parallel/sweep.py:_binned`).
     _bin_cache: Optional[Dict] = None
 
-    def _edges_binned(self, X, ctx, n_classes: int = 0):
+    def _edges_binned(self, X, ctx, n_classes: int = 0,
+                      batched_trees: int = 1):
         """(edges, binned matrix, histogram layout) of a training matrix;
         the matrix stays where it is (on the device in a workflow's
         refit: the span `tree:edges` says so). `n_classes`: a classifier
         forest's K; the span carries the fit's value columns (K, else 1)
-        and the passes over the histogram operand a tree level."""
+        and the passes over the histogram operand a tree level.
+        `batched_trees`: a softmax chain's K (`tree_span_attrs`)."""
         cache = self._bin_cache
         if cache is not None and self.max_bins in cache:
             return cache[self.max_bins]
@@ -1705,7 +1742,8 @@ class _TreeEstimatorBase(PredictorEstimator):
                          max_bins=self.max_bins, edges=edges_site(X),
                          value_columns=n_classes or 1,
                          hist_reads=hist_reads(n_classes),
-                         **tree_span_attrs(self.max_depth, n_classes)):
+                         **tree_span_attrs(self.max_depth, n_classes,
+                                           batched_trees)):
             indicator = indicator_columns(X)
             edges = quantile_bin_edges(X, self.max_bins, indicator)
             out = (edges, bin_features(upload("tree:bin", X),
@@ -1938,9 +1976,10 @@ class OpGBTClassifier(_TreeEstimatorBase):
                     np.asarray(warm["edges"], np.float32), trees,
                     float(warm.get("learning_rate", self.learning_rate)),
                     warm.get("base_score", 0.0), objective)
-        edges, Xb, layout = self._edges_binned(X, ctx)
-        seed = ctx.seed if ctx is not None else 0
         classes = k if softmax else 0
+        edges, Xb, layout = self._edges_binned(
+            X, ctx, batched_trees=classes or 1)
+        seed = ctx.seed if ctx is not None else 0
         esr = int(self.early_stopping_rounds or 0)
         n_rounds = self.n_estimators
         if esr > 0:

@@ -1154,7 +1154,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
         n_classes = n_classes_of(est, y, ctx)
     objective = est.objective_of(n_classes)
     seed = int(ctx.seed) if ctx is not None else 0
-    # a softmax round grows a tree a class: K in the plan, on the spans
+    # a softmax round grows a tree a class: K in the plan, on the spans,
+    # and in the trees one histogram product batches
     classes = n_classes if objective == "softmax" else 0
     chain_attrs = {"classes": classes} if classes else {}
 
@@ -1214,8 +1215,8 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
             pair_width=lambda st, idxs, k: width_of(st, idxs),
             x_info=_x_info(X),
             dispatch_attrs=lambda st, idxs: dict(tree_span_attrs(
-                _pad_depth_of(est, grids, idxs)), objective=objective,
-                **chain_attrs))
+                _pad_depth_of(est, grids, idxs), batched_trees=classes or 1),
+                objective=objective, **chain_attrs))
 
     # ---- single device: ROUND-CHUNKED host dispatch ---- #
     # Each dispatch runs `rpd` boosting rounds for `width` vmapped
@@ -1287,7 +1288,9 @@ def _sweep_gbt(est, grids, X, y, W, V, metric_fn, ctx, sharding):
                                     pairs=min(width, n_pairs - s),
                                     pad_depth=pad_depth,
                                     objective=objective,
-                                    **tree_span_attrs(pad_depth),
+                                    **tree_span_attrs(
+                                        pad_depth,
+                                        batched_trees=classes or 1),
                                     **chain_attrs):
                     margin, best, since = jax.block_until_ready(
                         prog(data, dchunk, Wsel, Vsel, margin, best, since,
